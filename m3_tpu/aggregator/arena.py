@@ -68,45 +68,46 @@ def raw(jitted):
 #   scatter — XLA scatter ops (default; fastest on XLA-CPU).
 #   pallas  — binned segment reduction kernel (parallel/pallas_ingest.py):
 #             built for TPU, where scatter measured ~1us/element at C=1M
-#             (TPU_RESULTS_r05.json window #3); also wins on CPU when
-#             slot collisions serialize the scatter AND the flat arena
-#             (W*C) is moderate.
+#             (round 5, window 3), but its formulation does not compile
+#             for one (PR 22), so on a TPU the name is REFUSED with a
+#             clear error; off the chip it runs in interpret mode and
+#             wins on CPU when slot collisions serialize the scatter AND
+#             the flat arena (W*C) is moderate.
 # (A third sort/scan/gather impl — parallel/sorted_ingest.py — was
-# deleted in round 6: BENCH_r05 measured it at 0.45-0.50x of scatter on
+# deleted in round 6: round 5 measured it at 0.45-0.50x of scatter on
 # CPU and it was never validated faster on real TPU hardware.  Its
 # generic segmented-scan helpers live on in parallel/segmented.py.)
-# The bench's rollup/timer stages time the candidates side by side.
 # The choice binds at TRACE time, so set_ingest_impl clears the arena
 # jit caches — jits composed elsewhere via raw() keep whatever impl
 # they traced with.
 # ---------------------------------------------------------------------------
 
-INGEST_IMPLS = ("scatter", "pallas", "auto")
-_INGEST_IMPLS = INGEST_IMPLS  # back-compat alias
+INGEST_IMPLS = ("scatter", "pallas")
 _INGEST_IMPL = (os.environ.get("M3_ARENA_INGEST", "").strip().lower()
                 or "scatter")
-if _INGEST_IMPL not in _INGEST_IMPLS:
+if _INGEST_IMPL not in INGEST_IMPLS:
     raise ValueError(
-        f"M3_ARENA_INGEST={_INGEST_IMPL!r}: must be one of {_INGEST_IMPLS} "
+        f"M3_ARENA_INGEST={_INGEST_IMPL!r}: must be one of {INGEST_IMPLS} "
         "(a typo silently running scatter would invalidate the very "
         "measurement the flag exists to apply)")
 
 
+def _usable(impl: str) -> str:
+    """`pallas` is refused on a TPU: Mosaic rejects the kernel's (1, N)
+    blocks and has no f64, so there it could only fail or be stepped
+    down from silently."""
+    if impl == "pallas" and jax.default_backend() == "tpu":
+        raise ValueError(
+            "arena ingest impl 'pallas' does not compile for a TPU "
+            "(parallel/pallas_ingest.py: (1, N) blocks, f64 operands); "
+            "use M3_ARENA_INGEST=scatter / coordinator.arena_ingest: "
+            "scatter")
+    return impl
+
+
 def ingest_impl() -> str:
-    """The CONFIGURED impl (may be 'auto'); see resolved_ingest_impl."""
-    return _INGEST_IMPL
-
-
-def resolved_ingest_impl() -> str:
-    """'auto' resolves per backend: scatter where XLA's scatter is fast
-    (CPU), the Pallas kernel where scatter measured ~1us/element (TPU —
-    TPU_RESULTS_r05.json window #3).  Resolution happens at trace
-    time, so a backend can't change under an already-compiled arena."""
-    if _INGEST_IMPL != "auto":
-        return _INGEST_IMPL
-    import jax
-
-    return "pallas" if jax.default_backend() == "tpu" else "scatter"
+    """The selected impl (the env's choice is checked here, at use)."""
+    return _usable(_INGEST_IMPL)
 
 
 # Jitted programs that COMPOSE raw(ingest) ops and must be re-traced
@@ -121,14 +122,14 @@ def register_ingest_consumer(jitted) -> None:
 
 def set_ingest_impl(impl: str) -> None:
     global _INGEST_IMPL
-    if impl not in _INGEST_IMPLS:
+    if impl not in INGEST_IMPLS:
         raise ValueError(f"unknown ingest impl {impl!r}")
-    _INGEST_IMPL = impl
+    _INGEST_IMPL = _usable(impl)
     for f in (counter_ingest, gauge_ingest, timer_ingest,
               *_INGEST_CONSUMERS):
         try:
             f.clear_cache()
-        except AttributeError:  # raw function or older jax
+        except AttributeError:  # a raw (un-jitted) function
             pass
 
 
@@ -228,7 +229,7 @@ def _seg3(sum_col, sq_col, cnt_col, idx, values, impl: str | None = None):
     jit argument so the device guard's fallback — pallas → scatter —
     needs no cache clearing and never retraces); None keeps the
     trace-time resolved seam for raw() composition (sharded_agg)."""
-    if (impl or resolved_ingest_impl()) == "pallas":
+    if (impl or ingest_impl()) == "pallas":
         from m3_tpu.parallel import pallas_ingest as pi
 
         n_out = sum_col.shape[0]
@@ -875,13 +876,13 @@ class _TimerLanesMixin:
 def _guarded_ingest(call):
     """Run one arena ingest behind the device guard.  The fallback
     re-issues the call with the scatter (jnp) ingest impl as a STATIC
-    argument — on TPU that steps down from the Pallas kernel with no
-    cache clearing and no retrace of the primary; on CPU primary and
-    fallback coincide and the re-run simply skips the device
-    faultpoints (the injected-fault contract).  A failure that
+    argument — under the pallas impl (off the chip only) that steps
+    down from the kernel with no cache clearing and no retrace of the
+    primary; under scatter primary and fallback coincide and the re-run
+    simply skips the device faultpoints (the injected-fault contract).  A failure that
     persists through the fallback raises typed to the engine."""
     return devguard.run_guarded(
-        "arena.ingest", lambda: call(resolved_ingest_impl()),
+        "arena.ingest", lambda: call(ingest_impl()),
         lambda: call("scatter"))
 
 
@@ -920,8 +921,8 @@ class CounterArena(_ScalarLanesMixin):
     def ingest(self, windows, slots, values, times):
         idx = flat_window_index(windows, slots, self.num_windows, self.capacity)
         self.state = _guarded_ingest(lambda impl: counter_ingest(
-            self.state, idx, slots, values.astype(jnp.int64), times,
-            impl=impl))
+            self.state, idx, slots, jnp.asarray(values).astype(jnp.int64),
+            times, impl=impl))
 
     def consume(self, window: int):
         return _guarded_consume(lambda: counter_consume(
@@ -952,8 +953,8 @@ class GaugeArena(_ScalarLanesMixin):
     def ingest(self, windows, slots, values, times):
         idx = flat_window_index(windows, slots, self.num_windows, self.capacity)
         self.state = _guarded_ingest(lambda impl: gauge_ingest(
-            self.state, idx, slots, values.astype(jnp.float64), times,
-            impl=impl))
+            self.state, idx, slots, jnp.asarray(values).astype(jnp.float64),
+            times, impl=impl))
 
     def consume(self, window: int):
         return _guarded_consume(lambda: gauge_consume(
@@ -1023,7 +1024,7 @@ class TimerArena(_TimerLanesMixin):
             self.state,
             jnp.asarray(windows_np.astype(np.int32)),
             slots,
-            values.astype(jnp.float64),
+            jnp.asarray(values).astype(jnp.float64),
             times,
             self.capacity,
             impl=impl,

@@ -32,7 +32,6 @@ import dataclasses
 import functools
 from typing import Callable, Dict, List, Sequence
 
-import jax.numpy as jnp
 import numpy as np
 
 from m3_tpu.aggregator.arena import make_arenas
@@ -151,21 +150,13 @@ class MetricMap:
         # same reason).  The Python path remains as oracle + fallback.
         self._native = None
         if use_native is not False:
-            try:
-                from m3_tpu.native.idmap import NativeIdMap, available
+            # Built from source (native/_build.py); a failed build
+            # raises — a silent ~5x-slower Python path would corrupt
+            # every number measured on this node.
+            from m3_tpu.native.idmap import NativeIdMap
 
-                if available():
-                    self._native = NativeIdMap(capacity)
-                    self._native_ids: List[bytes | None] = [None] * capacity
-                elif use_native is True:
-                    raise RuntimeError("native idmap unavailable")
-            except Exception:
-                # Opportunistic mode (None) degrades silently to the
-                # Python path; an EXPLICIT use_native=True must not —
-                # silent 5x-slower fallback would corrupt perf numbers.
-                if use_native is True:
-                    raise
-                self._native = None
+            self._native = NativeIdMap(capacity)
+            self._native_ids: List[bytes | None] = [None] * capacity
 
     def __len__(self) -> int:
         return (len(self._native) if self._native is not None
@@ -568,9 +559,9 @@ class MetricList:
             return
         windows, too_early, too_future = self._route_windows(times)
         self.drops += int(too_early.sum()) + int(too_future.sum())
-        self._arena(mt).ingest(
-            jnp.asarray(windows), jnp.asarray(slots), jnp.asarray(values), jnp.asarray(times)
-        )
+        # host arrays: the arenas upload them (the packed gauge arena
+        # keys min/max/last off the host bits — packed.orderable_f64)
+        self._arena(mt).ingest(windows, slots, values, times)
 
     def seed_windows(self, now_nanos: int) -> None:
         """Anchor an un-seeded window ring to the caller's clock: the
@@ -643,10 +634,7 @@ class MetricList:
             slots = slots[~rej]
             if sel.size == 0:
                 return accepted
-        self._arena(mt).ingest(
-            jnp.asarray(windows[sel]), jnp.asarray(slots),
-            jnp.asarray(values[sel]), jnp.asarray(times[sel])
-        )
+        self._arena(mt).ingest(windows[sel], slots, values[sel], times[sel])
         return accepted
 
     def open_windows(self, now_nanos: int) -> List[int]:

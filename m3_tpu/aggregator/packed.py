@@ -3,7 +3,7 @@
 The scatter arenas (``arena.py``) pay one XLA scatter per statistic lane
 — ~11 random-access passes plus a 3-key lex sort per ingest batch.  On
 XLA-CPU a scatter has a ~40-60ns/element floor regardless of dtype, and
-on TPU it measured ~1us/element (TPU_RESULTS_r05.json window #3).  This
+on TPU it measured ~1us/element (round 5, window 3).  This
 module reformulates the whole hot path around ONE u64 key sort per
 batch and otherwise touches memory only with the primitives XLA runs at
 streaming speed (gather ~4.5ns/elt, cumsum ~6ns, dense ~4ns on the r07
@@ -43,9 +43,10 @@ are EXACT: count/sum/sum_sq accumulate in (wrapping) i64 exactly like
 the scatter path, min/max are int16-exact in the base and i64-exact
 once promoted.
 
-Gauge state keeps f64 sum/sum_sq/min/max/last (the parity contract
-pins count/min/max/last bit-exact); the packed win for gauges is the
-formulation: batch sums ride the segmented scan as tree-order f64 adds
+Gauge state keeps f64 sum/sum_sq and carries min/max/last as
+order-preserving i64 keys of the f64 bits (the parity contract pins
+count/min/max/last bit-exact, on any backend: see the gauge section);
+the packed win for gauges is the formulation: batch sums ride the segmented scan as tree-order f64 adds
 — rounding stays at ~log2(N) ulps of each segment's OWN magnitude (a
 cumsum-diff form was tried and rejected: its quantum scales with the
 batch max, which blows the relative bound for tiny segments) and
@@ -182,7 +183,9 @@ def _segment_view(idx: jnp.ndarray, n_flat: int) -> _Segments:
         jnp.arange(n, dtype=jnp.int32), mode="drop",
         indices_are_sorted=True)
     start = bpos[:n_flat]
-    end = jax.lax.cummin(bpos[1:], reverse=True)
+    # (an associative scan, not lax.cummin: the TPU compiler takes 67 s
+    # over a 327K-element cummin and 12 s over this, same values)
+    end = jax.lax.associative_scan(jnp.minimum, bpos[1:], reverse=True)
     cnt = jnp.maximum(end - start, 0).astype(jnp.int64)
     return _Segments(perm, sslot, head, start, end, cnt, cnt > 0, ab)
 
@@ -606,18 +609,41 @@ def counter_clear_slots(state: PackedCounterState, slots: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# Packed gauge arena (sort-formulation ingest; f64 lanes stay bit-exact
-# for count/min/max/last, fixed-point batch sums for sum/sum_sq)
+# Packed gauge arena (sort-formulation ingest).  sum/sum_sq are computed
+# in the device's f64; min/max/last are SELECTED, never computed on, and
+# ride as order-preserving i64 keys of the f64 bit pattern so that they
+# leave the arena with the bits they came in with on any backend (an
+# accelerator's f64 is not IEEE: a TPU carries it as an f32 pair — ~48
+# mantissa bits, f32 exponent range — and has no f64<->i64 bitcast, so
+# the keys are made and unmade on the host).
 # ---------------------------------------------------------------------------
+
+_KEY_FLIP = np.int64(0x7FFFFFFFFFFFFFFF)
+KEY_PINF = 0x7FF0000000000000    # orderable_f64(+inf): min identity
+KEY_NINF = -0x7FF0000000000001   # orderable_f64(-inf): max identity
+KEY_NAN = 0x7FF8000000000000     # orderable_f64(nan)
+
+
+def orderable_f64(values) -> np.ndarray:
+    """Host: f64 -> i64 keys whose integer order is the float order
+    (-0.0 < +0.0; NaNs sort outside [-inf, +inf])."""
+    b = np.ascontiguousarray(values, np.float64).view(np.int64)
+    return b ^ ((b >> 63) & _KEY_FLIP)
+
+
+def decode_orderable_f64(keys) -> np.ndarray:
+    """Host: the inverse of orderable_f64 (the map is an involution)."""
+    k = np.ascontiguousarray(keys, np.int64)
+    return (k ^ ((k >> 63) & _KEY_FLIP)).view(np.float64)
 
 
 class PackedGaugeState(NamedTuple):
     sum: jnp.ndarray        # f64 (W*C,)
     sum_sq: jnp.ndarray     # f64
     count: jnp.ndarray      # i64
-    min: jnp.ndarray        # f64, identity +inf
-    max: jnp.ndarray        # f64, identity -inf
-    last_bits: jnp.ndarray  # i64 (W*C,) f64 bit pattern of `last`
+    min_key: jnp.ndarray    # i64 orderable_f64, identity KEY_PINF
+    max_key: jnp.ndarray    # i64 orderable_f64, identity KEY_NINF
+    last_key: jnp.ndarray   # i64 orderable_f64 of the value at last_time
     last_time: jnp.ndarray  # i64
     last_at: jnp.ndarray    # i64 (C,)
 
@@ -628,29 +654,29 @@ def gauge_init(num_windows: int, capacity: int) -> PackedGaugeState:
         sum=jnp.zeros(n, jnp.float64),
         sum_sq=jnp.zeros(n, jnp.float64),
         count=jnp.zeros(n, jnp.int64),
-        min=jnp.full(n, jnp.inf, jnp.float64),
-        max=jnp.full(n, -jnp.inf, jnp.float64),
-        last_bits=jnp.zeros(n, jnp.int64),
+        min_key=jnp.full(n, KEY_PINF, jnp.int64),
+        max_key=jnp.full(n, KEY_NINF, jnp.int64),
+        last_key=jnp.zeros(n, jnp.int64),
         last_time=jnp.zeros(n, jnp.int64),
         last_at=jnp.zeros(capacity, jnp.int64),
     )
 
 
-def _gauge_scan_lanes(v: jnp.ndarray, t: jnp.ndarray):
-    """Scan input lanes for a gauge value column: (sum, sum_sq, min,
-    max, tmax, last-bits).  Sum lanes exclude NaN (count still carries
-    it) but pass +/-inf through — tree-order f64 addition reproduces
-    the scatter path's inf/NaN semantics natively and keeps the
-    within-segment rounding at ~log2(N) ulps of the segment's own
+def _gauge_scan_lanes(v: jnp.ndarray, k: jnp.ndarray, t: jnp.ndarray):
+    """Scan input lanes for a gauge value column and its keys: (sum,
+    sum_sq, min, max, tmax, last).  Sum lanes exclude NaN (count still
+    carries it) but pass +/-inf through — tree-order f64 addition
+    reproduces the scatter path's inf/NaN semantics natively and keeps
+    the within-segment rounding at ~log2(N) ulps of the segment's own
     magnitude (no cross-segment prefix cancellation)."""
-    nan = jnp.isnan(v)
+    nan = (k > KEY_PINF) | (k < KEY_NINF)
     safe = jnp.where(nan, 0.0, v)
-    return (safe, safe * safe, jnp.where(nan, jnp.inf, v),
-            jnp.where(nan, -jnp.inf, v), t, v.view(jnp.int64))
+    return (safe, safe * safe, jnp.where(nan, KEY_PINF, k),
+            jnp.where(nan, KEY_NINF, k), t, k)
 
 
 def _gauge_scan_combine(a, b):
-    """(sum, sum_sq, min, max, tmax, last-bits) segmented combine; last
+    """(sum, sum_sq, min, max, tmax, last) segmented combine; last
     is the value of the strictly-greatest time (sorted ties = first
     arrival wins)."""
     return (
@@ -665,38 +691,39 @@ def _gauge_scan_combine(a, b):
 
 def _gauge_gather(sview: _Segments, seg: _Segments, scanned: tuple):
     """Per-slot gauge aggregates from the raw scanned lanes."""
-    s_sum, s_sq, s_min, s_max, s_t, s_lastb = scanned
+    s_sum, s_sq, s_min, s_max, s_t, s_lastk = scanned
     d_sum = jnp.where(sview.has, _at_ends(sview.end, s_sum), 0.0)
     d_sq = jnp.where(sview.has, _at_ends(sview.end, s_sq), 0.0)
-    d_min = jnp.where(sview.has, _at_ends(sview.end, s_min), jnp.inf)
-    d_max = jnp.where(sview.has, _at_ends(sview.end, s_max), -jnp.inf)
+    d_min = jnp.where(sview.has, _at_ends(sview.end, s_min), KEY_PINF)
+    d_max = jnp.where(sview.has, _at_ends(sview.end, s_max), KEY_NINF)
     d_t = jnp.where(sview.has, _at_ends(sview.end, s_t), I64_MIN)
-    d_lastb = _at_ends(sview.end, s_lastb)
+    d_lastk = _at_ends(sview.end, s_lastk)
     d_tmax = jnp.where(seg.has, _at_ends(seg.end, s_t), I64_MIN)
-    return (sview.cnt, d_sum, d_sq, d_min, d_max, d_t, d_lastb), d_tmax
+    return (sview.cnt, d_sum, d_sq, d_min, d_max, d_t, d_lastk), d_tmax
 
 
 def _gauge_batch_segments(sview: _Segments, seg: _Segments,
-                          values: jnp.ndarray, times: jnp.ndarray):
-    v = values[seg.perm]
-    t = times[seg.perm]
-    scanned = _seg_scan(seg, _gauge_scan_lanes(v, t),
-                        _gauge_scan_combine)
+                          values: jnp.ndarray, keys: jnp.ndarray,
+                          times: jnp.ndarray):
+    scanned = _seg_scan(
+        seg, _gauge_scan_lanes(values[seg.perm], keys[seg.perm],
+                               times[seg.perm]),
+        _gauge_scan_combine)
     return _gauge_gather(sview, seg, scanned)
 
 
 def _gauge_merge(state: PackedGaugeState, segs, last_at,
                  num_windows: int, capacity: int) -> PackedGaugeState:
-    d_cnt, d_sum, d_sq, d_min, d_max, d_t, d_lastb = segs
+    d_cnt, d_sum, d_sq, d_min, d_max, d_t, d_lastk = segs
     has = d_cnt > 0
     replace = has & (d_t > state.last_time)
     return PackedGaugeState(
         sum=jnp.where(has, state.sum + d_sum, state.sum),
         sum_sq=jnp.where(has, state.sum_sq + d_sq, state.sum_sq),
         count=state.count + d_cnt,
-        min=jnp.minimum(state.min, d_min),
-        max=jnp.maximum(state.max, d_max),
-        last_bits=jnp.where(replace, d_lastb, state.last_bits),
+        min_key=jnp.minimum(state.min_key, d_min),
+        max_key=jnp.maximum(state.max_key, d_max),
+        last_key=jnp.where(replace, d_lastk, state.last_key),
         last_time=jnp.where(replace, d_t, state.last_time),
         last_at=last_at,
     )
@@ -709,6 +736,7 @@ def gauge_ingest(
     state: PackedGaugeState,
     idx: jnp.ndarray,     # i64 (N,) flat; == W*C drops
     values: jnp.ndarray,  # f64 (N,)
+    keys: jnp.ndarray,    # i64 (N,) orderable_f64(values), host-made
     times: jnp.ndarray,   # i64 (N,)
     num_windows: int,
     capacity: int,
@@ -716,7 +744,7 @@ def gauge_ingest(
     wc = num_windows * capacity
     seg = _segment_view(idx, wc + capacity)
     d, d_tmax = _gauge_batch_segments(_stats_view(seg, wc), seg,
-                                      values, times)
+                                      values, keys, times)
     last_at = _merge_last_at(state.last_at, d_tmax, num_windows, capacity)
     return _gauge_merge(state, d, last_at, num_windows, capacity)
 
@@ -724,26 +752,35 @@ def gauge_ingest(
 @functools.partial(jax.jit, static_argnames=("capacity",))
 def gauge_consume(state: PackedGaugeState, window: jnp.ndarray,
                   capacity: int):
+    """(computed (C, 5) f64, exact (C, 4) i64): the lanes the device
+    computed — SCALAR_LANES[3:], mean / count / sum / sum_sq / stdev —
+    and the ones it only counted or selected — count, then the
+    orderable_f64 keys of last / min / max.  ``gauge_lanes`` joins them
+    on the host into the arenas' common (lanes (C, 8), counts (C,))."""
     off = window * capacity
     sl = lambda a: jax.lax.dynamic_slice_in_dim(a, off, capacity)
     s, ssq, cnt = sl(state.sum), sl(state.sum_sq), sl(state.count)
     cntf = cnt.astype(jnp.float64)
-    mx, mn = sl(state.max), sl(state.min)
     mean = jnp.where(cnt == 0, 0.0, s / jnp.where(cnt == 0, 1, cnt))
-    lanes = jnp.stack(
-        [
-            sl(state.last_bits).view(jnp.float64),
-            jnp.where(jnp.isinf(mn), jnp.nan, mn),
-            jnp.where(jnp.isinf(mx), jnp.nan, mx),
-            mean,
-            cntf,
-            s,
-            ssq,
-            _stdev(cntf, ssq, s),
-        ],
-        axis=1,
-    )
-    return lanes, cnt
+    computed = jnp.stack([mean, cntf, s, ssq, _stdev(cntf, ssq, s)], axis=1)
+    # an empty slot's min/max (and, as in the scatter arenas, an
+    # infinite one) reads NaN
+    inf_to_nan = lambda k: jnp.where(
+        (k == KEY_PINF) | (k == KEY_NINF), KEY_NAN, k)
+    exact = jnp.stack([cnt, sl(state.last_key),
+                       inf_to_nan(sl(state.min_key)),
+                       inf_to_nan(sl(state.max_key))], axis=1)
+    return computed, exact
+
+
+def gauge_lanes(computed, exact):
+    """Host: a gauge_consume result (leading axes allowed) as the
+    arenas' common (lanes (..., C, 8) f64 in SCALAR_LANES order, counts
+    (..., C) i64), LAST / MIN / MAX decoded from their keys."""
+    exact = np.asarray(exact)
+    lanes = np.concatenate(
+        [decode_orderable_f64(exact[..., 1:]), np.asarray(computed)], axis=-1)
+    return lanes, exact[..., 0]
 
 
 @functools.partial(jax.jit, donate_argnums=0, static_argnames=("capacity",))
@@ -756,9 +793,9 @@ def gauge_reset_window(state: PackedGaugeState, window: jnp.ndarray,
         sum=upd(state.sum, 0.0),
         sum_sq=upd(state.sum_sq, 0.0),
         count=upd(state.count, 0),
-        min=upd(state.min, jnp.inf),
-        max=upd(state.max, -jnp.inf),
-        last_bits=upd(state.last_bits, 0),
+        min_key=upd(state.min_key, KEY_PINF),
+        max_key=upd(state.max_key, KEY_NINF),
+        last_key=upd(state.last_key, 0),
         last_time=upd(state.last_time, 0),
     )
 
@@ -777,9 +814,9 @@ def gauge_clear_slots(state: PackedGaugeState, slots: jnp.ndarray,
         sum=state.sum.at[idx].set(0.0, mode="drop"),
         sum_sq=state.sum_sq.at[idx].set(0.0, mode="drop"),
         count=state.count.at[idx].set(0, mode="drop"),
-        min=state.min.at[idx].set(jnp.inf, mode="drop"),
-        max=state.max.at[idx].set(-jnp.inf, mode="drop"),
-        last_bits=state.last_bits.at[idx].set(0, mode="drop"),
+        min_key=state.min_key.at[idx].set(KEY_PINF, mode="drop"),
+        max_key=state.max_key.at[idx].set(KEY_NINF, mode="drop"),
+        last_key=state.last_key.at[idx].set(0, mode="drop"),
         last_time=state.last_time.at[idx].set(0, mode="drop"),
         last_at=state.last_at.at[slots].set(0, mode="drop"),
     )
@@ -800,6 +837,7 @@ def rollup_ingest(
     idx: jnp.ndarray,      # i64 (N,) flat; == W*C drops
     cvalues: jnp.ndarray,  # i64 (N,)
     gvalues: jnp.ndarray,  # f64 (N,)
+    gkeys: jnp.ndarray,    # i64 (N,) orderable_f64(gvalues), host-made
     times: jnp.ndarray,    # i64 (N,)
     num_windows: int,
     capacity: int,
@@ -811,6 +849,7 @@ def rollup_ingest(
     sview = _stats_view(seg, wc)
     cv = cvalues[seg.perm]
     gv = gvalues[seg.perm]
+    gk = gkeys[seg.perm]
     t = times[seg.perm]
     c_sum, c_sq, wide = _counter_sums(sview, cv)
     (d_wide,) = _seg_flag_counts(sview, (wide,))
@@ -821,7 +860,7 @@ def rollup_ingest(
         return (jnp.minimum(a[0], b[0]), jnp.maximum(a[1], b[1])) \
             + _gauge_scan_combine(a[2:], b[2:])
 
-    scanned = _seg_scan(seg, (cv, cv) + _gauge_scan_lanes(gv, t),
+    scanned = _seg_scan(seg, (cv, cv) + _gauge_scan_lanes(gv, gk, t),
                         combine)
     c_min = jnp.where(sview.has, _at_ends(sview.end, scanned[0]),
                       I64_MAX)
@@ -1095,13 +1134,18 @@ class PackedGaugeArena(_ScalarLanesMixin):
     def ingest(self, windows, slots, values, times):
         idx = packed_flat_index(jnp.asarray(windows), jnp.asarray(slots),
                                 self.num_windows, self.capacity)
+        # host f64 in, so that the keys carry the written bits (pass
+        # numpy: a value that has been on an accelerator already is its
+        # device image)
+        values = np.asarray(values, np.float64)
+        keys = jnp.asarray(orderable_f64(values))
         self.state = _guarded_ingest(lambda impl: gauge_ingest(
-            self.state, idx, jnp.asarray(values).astype(jnp.float64),
+            self.state, idx, jnp.asarray(values), keys,
             jnp.asarray(times), self.num_windows, self.capacity))
 
     def consume(self, window: int):
-        return _guarded_consume(lambda: gauge_consume(
-            self.state, jnp.int32(window), self.capacity))
+        return gauge_lanes(*_guarded_consume(lambda: gauge_consume(
+            self.state, jnp.int32(window), self.capacity)))
 
     def reset_window(self, window: int):
         self.state = _guarded_state_op(lambda: gauge_reset_window(self.state, jnp.int32(window),

@@ -455,8 +455,8 @@ class CoordinatorConfig:
     tracing: bool = False
     # Aggregation-arena ingest implementation for this process:
     # "" = leave the global default (M3_ARENA_INGEST env / scatter);
-    # scatter | pallas | auto select explicitly (auto resolves scatter
-    # on CPU, pallas on TPU — see aggregator/arena.py).
+    # scatter | pallas select explicitly (pallas is refused on a TPU —
+    # see aggregator/arena.py).
     arena_ingest: str = ""
     # Aggregation-arena state layout for this process:
     # "" = leave the global default (M3_ARENA_LAYOUT env / auto);

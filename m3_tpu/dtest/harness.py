@@ -27,10 +27,17 @@ from pathlib import Path
 
 
 class NodeProcess:
-    def __init__(self, config_path: str, root: str, env: dict | None = None):
+    def __init__(self, config_path: str, root: str, env: dict | None = None,
+                 owns_chip: bool = False):
         self.config_path = str(config_path)
         self.root = Path(root)
         self.env = dict(os.environ, **(env or {}))
+        # One process per chip.  A launcher that starts several nodes on
+        # one host holds every child to the CPU unless told which single
+        # child owns the accelerator (and the launcher itself must not
+        # have initialised a backend before starting that child).
+        if not owns_chip:
+            self.env["JAX_PLATFORMS"] = "cpu"
         self.proc: subprocess.Popen | None = None
         self.port: int | None = None
 

@@ -749,7 +749,7 @@ def _encode_batch_device(timestamps, value_bits, start, valid, unit: int = 1,
     # per-series word indices gw / gw+1 (disjoint bit ranges make add
     # equivalent to or).  Three formulations behind the static seam:
     #   scatter — two scatter-adds over the (F, S) fragments; the
-    #             XLA-CPU scatter floor (~43ns/elt, BENCH_r07) makes it
+    #             XLA-CPU scatter floor (~43ns/elt, round 7) makes it
     #             the SLOW tail at corpus scale but the cheapest
     #             compile.
     #   gather  — scatter-free: the stream-order fragment keys are

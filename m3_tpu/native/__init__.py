@@ -7,57 +7,32 @@ for single-series encode/decode (the role of the reference's Go codec in
 fallback for stream features the native path rejects (annotations,
 mid-stream time-unit changes).
 
-The shared object builds on demand with g++ into native/build/ and is
-cached; `available()` gates callers so a missing toolchain degrades to
-the Python path, never an error.
+The shared object is built from source on first load in a process
+tree (native/_build.py); a failed build is an error.
 """
 
 from __future__ import annotations
 
 import ctypes
-import subprocess
-from pathlib import Path
 
 import numpy as np
 
-_ROOT = Path(__file__).resolve().parent.parent.parent
-_SRC = _ROOT / "native" / "m3tsz.cc"
-_SO = _ROOT / "native" / "build" / "libm3tsz.so"
+from m3_tpu.native._build import load_native
 
 _lib = None
-_tried = False
-
-
-def _build() -> bool:
-    _SO.parent.mkdir(parents=True, exist_ok=True)
-    try:
-        subprocess.run(
-            # -ffp-contract=off: FMA contraction would change the rounding
-            # of the decoder's int_val accumulation vs strict IEEE.
-            # -O3 measures ~5-10% faster than -O2 on the decode hot loop;
-            # -march=native measured SLOWER (worse layout for this
-            # branchy code) and would break portability of the .so.
-            ["g++", "-O3", "-ffp-contract=off", "-pthread", "-shared",
-             "-fPIC", "-o", str(_SO), str(_SRC)],
-            check=True, capture_output=True, timeout=120,
-        )
-        return True
-    except (subprocess.SubprocessError, FileNotFoundError):
-        return False
 
 
 def _load():
-    global _lib, _tried
-    if _lib is not None or _tried:
+    global _lib
+    if _lib is not None:
         return _lib
-    _tried = True
-    if not _SO.exists() or _SO.stat().st_mtime < _SRC.stat().st_mtime:
-        if not _build():
-            return None
-    try:
-        lib = ctypes.CDLL(str(_SO))
-    except OSError:
-        return None
+    # -ffp-contract=off: FMA contraction would change the rounding of
+    # the decoder's int_val accumulation vs strict IEEE.  -O3 measures
+    # ~5-10% faster than -O2 on the decode hot loop; -march=native
+    # measured SLOWER (worse layout for this branchy code) and would
+    # break portability of the .so.
+    lib = load_native("m3tsz.cc", "libm3tsz.so",
+                      ("-O3", "-ffp-contract=off", "-pthread"))
     lib.m3tsz_encode.restype = ctypes.c_long
     lib.m3tsz_encode.argtypes = [
         ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_double),
@@ -103,17 +78,11 @@ def _encode_cap(n: int) -> int:
     return max(64, n * 20 + 16)
 
 
-def available() -> bool:
-    return _load() is not None
-
-
 def encode_series(timestamps: np.ndarray, values: np.ndarray, start: int,
                   unit: int = 1) -> bytes | None:
     """Encode one series; None means unsupported input (use the Python
     codec)."""
     lib = _load()
-    if lib is None:
-        return None
     ts = np.ascontiguousarray(timestamps, np.int64)
     vals = np.ascontiguousarray(values, np.float64)
     n = len(ts)
@@ -140,8 +109,6 @@ def decode_series(data: bytes, default_unit: int = 1,
     stream feature (use the Python codec).  Raises ValueError on
     corruption."""
     lib = _load()
-    if lib is None:
-        return None
     if not data:
         return np.empty(0, np.int64), np.empty(0)
     buf = np.frombuffer(data, np.uint8)
@@ -177,8 +144,6 @@ def decode_batch(streams: list[bytes], max_points: int, default_unit: int = 1,
     slots are zero-filled.
     """
     lib = _load()
-    if lib is None:
-        return None
     B = len(streams)
     offsets = np.zeros(B + 1, np.int64)
     for i, s in enumerate(streams):
@@ -212,8 +177,6 @@ def encode_batch(timestamps, values, starts, counts=None, unit: int = 1,
     through the scalar codec.
     """
     lib = _load()
-    if lib is None:
-        return None
     ts = np.ascontiguousarray(timestamps, np.int64)
     vals = np.ascontiguousarray(values, np.float64)
     B, T = ts.shape

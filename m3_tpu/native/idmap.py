@@ -3,9 +3,8 @@
 The aggregator ingest hot path's host half (reference metricMap
 find-or-create, `map.go:149`): batches of metric IDs resolve to dense
 arena slots in one native call instead of one Python dict probe per
-sample.  Same build-on-demand pattern as the other native modules;
-``available()`` gates callers so a missing toolchain falls back to the
-pure-Python MetricMap path.
+sample.  Built from source like the other native modules
+(native/_build.py): a failed build raises, it does not fall back.
 """
 
 from __future__ import annotations
@@ -17,17 +16,13 @@ import numpy as np
 from m3_tpu.native._build import load_native
 
 _lib = None
-_tried = False
 
 
 def _load():
-    global _lib, _tried
-    if _lib is not None or _tried:
+    global _lib
+    if _lib is not None:
         return _lib
-    _tried = True
     lib = load_native("idmap.cc", "libidmap.so", ("-std=c++20",))
-    if lib is None:
-        return None
     u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
     u64p = np.ctypeslib.ndpointer(np.uint64, flags="C_CONTIGUOUS")
     i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
@@ -51,17 +46,11 @@ def _load():
     return lib
 
 
-def available() -> bool:
-    return _load() is not None
-
-
 class NativeIdMap:
     """Find-or-create slot resolution over packed ID batches."""
 
     def __init__(self, capacity: int):
         self._lib = _load()
-        if self._lib is None:
-            raise RuntimeError("native idmap unavailable")
         self._h = self._lib.idmap_new(capacity)
         self.capacity = capacity
 

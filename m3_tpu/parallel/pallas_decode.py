@@ -19,15 +19,16 @@ the hand-scheduled alternative, mirroring that file's seam:
   backend, everywhere else jnp — identical semantics, so CPU-only
   hosts fall back cleanly, which tier-1 pins in
   tests/test_pallas_decode.py).
-* The kernel walks a 2-D grid over (series, word tiles) — all-uint32,
-  Mosaic-shaped like the proven ingest kernel: the hit masks are 2-D
+* The kernel walks a grid over (128-series blocks, point tiles, word
+  tiles) on (8, 128)-tiled blocks — 32-bit only: the hit masks are 2-D
   (points down sublanes, word lanes across), the three gathered words
-  accumulate into revisited (1, P) output blocks, and the 64-bit
-  funnel shift happens OUTSIDE the kernel as plain elementwise XLA
-  (no 64-bit integer ops inside Mosaic).
+  accumulate into revisited scan-major (PT, 128) output blocks, and
+  the 64-bit funnel shift happens OUTSIDE the kernel as plain
+  elementwise XLA (no 64-bit integer ops inside Mosaic).  Compiled by
+  Mosaic for a v5e since PR 22 (tests/test_chip_compile.py).
 
 The word representation is int32-packed on purpose (ISSUE 6 / the
-packed32 timer-drain precedent, BENCH_r05: fixed-width 32-bit lanes
+packed32 timer-drain precedent, round 5: fixed-width 32-bit lanes
 are the decode-friendly layout DeXOR-class codecs standardize on):
 u32 word ``k`` holds stream bits ``[32k, 32k+32)`` MSB-first, i.e. the
 big-endian halves of the encoder's u64 words in order.
@@ -41,21 +42,16 @@ import os
 import jax
 import jax.numpy as jnp
 
-try:  # pallas ships with jax, but guard anyway: this module is optional
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover - environment without pallas
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
 
 U32 = jnp.uint32
 U64 = jnp.uint64
 I32 = jnp.int32
 
-PT = 512   # datapoint lanes per grid row: one (1, PT) output block
+PT = 512   # datapoints per grid step (sublane axis of the hit mask)
 WT = 512   # stream words per grid step: the (PT, WT) hit mask is the
-           # kernel's VMEM high-water mark (3 x 1MB u32 compares)
+           # kernel's VMEM high-water mark (3 x 1MB i32 compares)
+SB = 128   # series per grid step: the lane axis of the (PT, SB) blocks
 
 
 def _shr64(v, s):
@@ -93,67 +89,77 @@ def _gather3_jnp(words32, offs):
 
 
 def _gather_kernel(offs_ref, words_ref, w0_ref, w1_ref, w2_ref):
-    """One (s, j) grid step: accumulate word-tile j's contribution to
-    series s's three gathered-word lanes.  Each datapoint's word index
-    lands in exactly one tile, so accumulation across j is exact; the
-    (PT, WT) hit masks put points down the sublane axis and word lanes
-    across — partial sums land lane-shaped like the (1, PT) outputs."""
+    """One (s, p, j) grid step: accumulate word-tile j's contribution
+    to the three gathered-word lanes of SB series x PT datapoints.
+    Blocks are Mosaic-tiled — offsets and outputs (PT, SB) scan-major
+    (series on lanes), words (SB, WT) — and all-i32 (callers bitcast
+    the u32 words; exactly one lane hits, so i32 sums are exact).  The
+    series walk is a fori_loop: series r's word indices come out of the
+    block as a (PT, 1) column by a one-hot lane reduction (no dynamic
+    lane slicing, no relayout), its words as a (1, WT) sublane row;
+    the (PT, WT) hit mask reduces over lanes back to a (PT, 1) column
+    that lands in lane r of the revisited output block."""
     j = pl.program_id(2)
-    base = j * WT
-    lane_ids = base + jax.lax.broadcasted_iota(I32, (1, WT), 1)
-    widx = (offs_ref[0, :] >> jnp.asarray(5, I32))[:, None]   # (PT, 1)
-    row = words_ref[0, :][None, :]                            # (1, WT)
-    zero = jnp.zeros((), U32)
+    lane_ids = j * WT + jax.lax.broadcasted_iota(I32, (1, WT), 1)
+    sb_ids = jax.lax.broadcasted_iota(I32, (1, SB), 1)
+    offs_blk = offs_ref[...]
+    zero = jnp.zeros((), I32)
     outs = (w0_ref, w1_ref, w2_ref)
-    parts = []
-    for k in range(3):
-        hit = (widx + jnp.asarray(k, I32)) == lane_ids        # (PT, WT)
-        parts.append(jnp.sum(jnp.where(hit, row, zero), axis=1,
-                             dtype=U32)[None, :])             # (1, PT)
 
     @pl.when(j == 0)
     def _init():
-        for ref, p in zip(outs, parts):
-            ref[:, :] = p
+        for ref in outs:
+            ref[...] = jnp.zeros((PT, SB), I32)
 
-    @pl.when(j > 0)
-    def _accumulate():
-        for ref, p in zip(outs, parts):
-            ref[:, :] = ref[:, :] + p
+    def body(r, carry):
+        sel = sb_ids == r                                     # (1, SB)
+        widx = jnp.sum(jnp.where(sel, offs_blk, zero), axis=1,
+                       keepdims=True, dtype=I32)              # (PT, 1)
+        row = words_ref[pl.ds(r, 1), :]                       # (1, WT)
+        for k, ref in enumerate(outs):
+            hit = (widx + jnp.asarray(k, I32)) == lane_ids    # (PT, WT)
+            col = jnp.sum(jnp.where(hit, row, zero), axis=1,
+                          keepdims=True, dtype=I32)           # (PT, 1)
+            ref[...] = ref[...] + jnp.where(sel, col, zero)
+        return carry
+
+    # i32 bounds: under x64 a python-int fori index is i64 (no Mosaic)
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(SB), body,
+                      jnp.asarray(0, I32))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _gather3_pallas(words32, offs, interpret: bool):
     """The Pallas gather: same (w0, w1, w2) contract as _gather3_jnp."""
-    if not HAVE_PALLAS:  # pragma: no cover
-        raise RuntimeError("pallas unavailable in this jax build")
     S, W32 = words32.shape
     P = offs.shape[1]
     Wpad = ((W32 + WT - 1) // WT) * WT
     Ppad = ((P + PT - 1) // PT) * PT
-    wp = jnp.zeros((S, Wpad), U32).at[:, :W32].set(words32)
+    Spad = ((S + SB - 1) // SB) * SB
+    wp = jnp.zeros((Spad, Wpad), I32).at[:S, :W32].set(
+        jax.lax.bitcast_convert_type(words32, I32))
     # Clip like the jnp path so both impls read the same padded zeros
     # for out-of-range offsets (bit-parity is the contract).
     oc = jnp.clip(offs >> jnp.asarray(5, I32), 0, max(W32 - 3, 0))
     # Padding lanes carry an impossible word index (>= Wpad) so they
     # match no word lane and gather 0.
-    op = jnp.full((S, Ppad), Wpad << 5, I32).at[:, :P].set(
-        oc << jnp.asarray(5, I32))
-    grid = (S, Ppad // PT, Wpad // WT)
-    out_shape = [jax.ShapeDtypeStruct((S, Ppad), U32)] * 3
-    spec_pt = pl.BlockSpec((1, PT), lambda s, p, j: (s, p))
+    op = jnp.full((Ppad, Spad), Wpad, I32).at[:P, :S].set(oc.T)
+    grid = (Spad // SB, Ppad // PT, Wpad // WT)
+    out_shape = [jax.ShapeDtypeStruct((Ppad, Spad), I32)] * 3
+    spec_pt = pl.BlockSpec((PT, SB), lambda s, p, j: (p, s))
     outs = pl.pallas_call(
         _gather_kernel,
         grid=grid,
         in_specs=[
             spec_pt,
-            pl.BlockSpec((1, WT), lambda s, p, j: (s, j)),
+            pl.BlockSpec((SB, WT), lambda s, p, j: (s, j)),
         ],
         out_specs=[spec_pt] * 3,
         out_shape=out_shape,
         interpret=interpret,
     )(op, wp)
-    return tuple(o[:, :P] for o in outs)
+    return tuple(jax.lax.bitcast_convert_type(o[:P, :S].T, U32)
+                 for o in outs)
 
 
 _IMPLS = ("pallas", "jnp", "auto")
@@ -174,8 +180,6 @@ def resolved_impl() -> str:
     impl = configured_impl()
     if impl != "auto":
         return impl
-    if not HAVE_PALLAS:
-        return "jnp"
     return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
 

@@ -5,15 +5,15 @@ round-6 decode split: a cheap sequential scan resolves the format into
 per-datapoint ``(value, bit offset, width)`` lanes, and phase 2
 assembles the output stream words from the lane fragments.  Placement
 is a SCATTER by construction — every fragment lands at its word index
-— and TPU scatters measured ~1us/element (TPU_RESULTS_r05.json), so
+— and TPU scatters measured ~1us/element (round 5), so
 this kernel inverts it into the same masked-sum shape as the decode
-gather kernel (parallel/pallas_decode.py): walk a 2-D grid over
-(series, word tiles x fragment tiles), compare each fragment's word
-key against the tile's word lane ids, and accumulate the hits into
-revisited (1, WT) output blocks.  Fragments at distinct bit ranges
+gather kernel (parallel/pallas_decode.py): walk a grid over
+(128-series blocks, word tiles, fragment tiles), compare each
+fragment's word key against the tile's word lane ids, and accumulate
+the hits into revisited (128, WT) output blocks.  Fragments at distinct bit ranges
 never overlap, so the u32 partial sums are exact ORs.
 
-All-uint32 on purpose (no 64-bit integer ops inside Mosaic): the
+32-bit only on purpose (no 64-bit integer ops inside Mosaic): the
 caller splits each u64 fragment into big-endian u32 halves — half
 ``h`` of the fragment at u64 word ``k`` targets u32 word ``2k + h`` —
 and recombines the (S, 2W) u32 output into u64 stream words outside
@@ -32,75 +32,83 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:  # pallas ships with jax, but guard anyway: this module is optional
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover - environment without pallas
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
 
 U32 = jnp.uint32
 U64 = jnp.uint64
 I32 = jnp.int32
 
-FT = 512   # fragment lanes per grid step
-WT = 512   # output u32 words per grid row: one (1, WT) revisited block;
-           # the (FT, WT) hit mask is the kernel's VMEM high-water mark
+FT = 512   # fragments per grid step (sublane axis of the hit mask)
+WT = 512   # output u32 words per grid step; the (FT, WT) hit mask is
+           # the kernel's VMEM high-water mark
+SB = 128   # series per grid step: the lane axis of the (FT, SB) blocks
 
 
 def _place_kernel(keys_ref, vals_ref, out_ref):
     """One (s, w, f) grid step: accumulate fragment-tile f's
-    contribution to series s's word tile w.  Fragments go down the
-    sublane axis, word lanes across — the same mask orientation as the
-    decode gather kernel, with gather/scatter roles reversed."""
+    contribution to word tile w of SB series.  Blocks are Mosaic-tiled
+    — keys/fragments (FT, SB) scan-major (series on lanes), output
+    (SB, WT) — and all-i32 (callers bitcast the u32 halves; disjoint
+    bit ranges make the wrapping i32 sums exact ORs).  The series walk
+    is a fori_loop: series r's keys and fragments come out of their
+    blocks as (FT, 1) columns by a one-hot lane reduction, the
+    (FT, WT) hit mask reduces over sublanes to a (1, WT) row that adds
+    into sublane r of the revisited output block — the decode gather
+    kernel with gather/scatter roles reversed."""
     w = pl.program_id(1)
     f = pl.program_id(2)
-    base = w * WT
-    lane_ids = base + jax.lax.broadcasted_iota(I32, (1, WT), 1)  # (1, WT)
-    keys = keys_ref[0, :][:, None]                               # (FT, 1)
-    vals = vals_ref[0, :][:, None]                               # (FT, 1)
-    hit = keys == lane_ids                                       # (FT, WT)
-    part = jnp.sum(jnp.where(hit, vals, jnp.zeros((), U32)), axis=0,
-                   dtype=U32)[None, :]                           # (1, WT)
+    lane_ids = w * WT + jax.lax.broadcasted_iota(I32, (1, WT), 1)
+    sb_ids = jax.lax.broadcasted_iota(I32, (1, SB), 1)
+    keys_blk = keys_ref[...]
+    vals_blk = vals_ref[...]
+    zero = jnp.zeros((), I32)
 
     @pl.when(f == 0)
     def _init():
-        out_ref[:, :] = part
+        out_ref[...] = jnp.zeros((SB, WT), I32)
 
-    @pl.when(f > 0)
-    def _accumulate():
-        out_ref[:, :] = out_ref[:, :] + part
+    def body(r, carry):
+        sel = sb_ids == r                                     # (1, SB)
+        keys = jnp.sum(jnp.where(sel, keys_blk, zero), axis=1,
+                       keepdims=True, dtype=I32)              # (FT, 1)
+        vals = jnp.sum(jnp.where(sel, vals_blk, zero), axis=1,
+                       keepdims=True, dtype=I32)              # (FT, 1)
+        hit = keys == lane_ids                                # (FT, WT)
+        part = jnp.sum(jnp.where(hit, vals, zero), axis=0,
+                       keepdims=True, dtype=I32)              # (1, WT)
+        out_ref[pl.ds(r, 1), :] = out_ref[pl.ds(r, 1), :] + part
+        return carry
+
+    # i32 bounds: under x64 a python-int fori index is i64 (no Mosaic)
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(SB), body,
+                      jnp.asarray(0, I32))
 
 
 @functools.partial(jax.jit, static_argnames=("w32", "interpret"))
 def _place_pallas(vals32, keys32, w32: int, interpret: bool):
     """(S, F) u32 fragments + u32-word keys -> (S, w32) u32 sums."""
-    if not HAVE_PALLAS:  # pragma: no cover
-        raise RuntimeError("pallas unavailable in this jax build")
     S, F = vals32.shape
     Fpad = ((F + FT - 1) // FT) * FT
     Wpad = ((w32 + WT - 1) // WT) * WT
+    Spad = ((S + SB - 1) // SB) * SB
     # Padding fragments carry an impossible word key (>= Wpad) so they
     # match no word lane; real keys beyond w32 are dropped the same way
     # (the caller's fallback flag owns stream-overflow reporting).
-    kp = jnp.full((S, Fpad), Wpad, I32).at[:, :F].set(
-        jnp.minimum(keys32, jnp.asarray(Wpad, I32)))
-    vp = jnp.zeros((S, Fpad), U32).at[:, :F].set(vals32)
-    grid = (S, Wpad // WT, Fpad // FT)
-    spec_w = pl.BlockSpec((1, WT), lambda s, w, f: (s, w))
+    kp = jnp.full((Fpad, Spad), Wpad, I32).at[:F, :S].set(
+        jnp.minimum(keys32, jnp.asarray(Wpad, I32)).T)
+    vp = jnp.zeros((Fpad, Spad), I32).at[:F, :S].set(
+        jax.lax.bitcast_convert_type(vals32, I32).T)
+    grid = (Spad // SB, Wpad // WT, Fpad // FT)
+    spec_f = pl.BlockSpec((FT, SB), lambda s, w, f: (f, s))
     out = pl.pallas_call(
         _place_kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, FT), lambda s, w, f: (s, f)),
-            pl.BlockSpec((1, FT), lambda s, w, f: (s, f)),
-        ],
-        out_specs=spec_w,
-        out_shape=jax.ShapeDtypeStruct((S, Wpad), U32),
+        in_specs=[spec_f, spec_f],
+        out_specs=pl.BlockSpec((SB, WT), lambda s, w, f: (s, w)),
+        out_shape=jax.ShapeDtypeStruct((Spad, Wpad), I32),
         interpret=interpret,
     )(kp, vp)
-    return out[:, :w32]
+    return jax.lax.bitcast_convert_type(out[:S, :w32], U32)
 
 
 def auto_interpret() -> bool:

@@ -8,7 +8,7 @@ query/functions.py builds its grouped PromQL aggregations on these.
 
 (The aggregation arenas used to carry a third ingest implementation on
 this idiom — parallel/sorted_ingest.py, built for TPU where scatter
-measured ~1us/element.  BENCH_r05 measured it at 0.45-0.50x of the
+measured ~1us/element.  round 5 measured it at 0.45-0.50x of the
 scatter path on CPU and it was never validated faster on real TPU
 hardware, so round 6 deleted it; the TPU answer to slow scatters is
 the hand-scheduled Pallas kernel, parallel/pallas_ingest.py.  These

@@ -31,6 +31,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from m3_tpu.aggregator import arena as _arena
@@ -95,6 +96,10 @@ class ShardedBatch(NamedTuple):
     slots: jnp.ndarray  # i32 (D, N)
     counter_values: jnp.ndarray  # i64 (D, N)
     gauge_values: jnp.ndarray  # f64 (D, N)
+    # i64 (D, N) packed.orderable_f64(gauge_values), made on the host
+    # from the written bits (the packed layout selects min/max/last on
+    # these; the f64 layout ignores them)
+    gauge_keys: jnp.ndarray
     timer_values: jnp.ndarray  # f64 (D, N)
     times: jnp.ndarray  # i64 (D, N)
 
@@ -148,8 +153,12 @@ def _sharded_ingest_consume(
       counter/gauge/timer -> ((D, C, L) lanes, (D, C) counts), sharded
       rollup              -> (C, 4) global [sum, count, min, max] across
                             shards (the forwarded-pipeline stage, via
-                            psum/pmin/pmax); min/max are NaN for slots
+                            psum / gathered min, max); min/max are NaN for slots
                             with no gauge samples on any shard
+    On the packed layout the gauge entry is packed.gauge_consume's
+    ((D, C, 5) computed, (D, C, 4) exact) pair and the rollup's min/max
+    columns are NaN placeholders for the i64 keys in ``rollup_sel``
+    (C, 2): read both through gauge_lanes() / rollup_lanes().
     """
     mesh = topo.mesh
 
@@ -166,7 +175,8 @@ def _sharded_ingest_consume(
                 b.windows, b.slots, num_windows, capacity)
             counters, gauges = _raw(_packed.rollup_ingest)(
                 st.counters, st.gauges, pidx, b.counter_values,
-                b.gauge_values, b.times, num_windows, capacity)
+                b.gauge_values, b.gauge_keys, b.times, num_windows,
+                capacity)
             timers = _raw(_packed.timer_ingest)(
                 st.timers, b.windows, b.slots, b.timer_values, b.times,
                 capacity)
@@ -186,6 +196,9 @@ def _sharded_ingest_consume(
                 counters, window, capacity)
             g_lanes, g_cnt = _raw(_packed.gauge_consume)(
                 gauges, window, capacity)
+            # (computed, exact) — see packed.gauge_consume
+            g_sum, g_n = g_lanes[:, 2], g_lanes[:, 1]
+            k_min, k_max = g_cnt[:, 2], g_cnt[:, 3]
             t_lanes, t_cnt = _raw(_packed.timer_consume)(
                 timers, window, capacity, quantiles)
             counters = _raw(_packed.counter_reset_window)(
@@ -213,6 +226,7 @@ def _sharded_ingest_consume(
                 counters, window, capacity)
             g_lanes, g_cnt = _raw(_arena.gauge_consume)(
                 gauges, window, capacity)
+            g_sum, g_n = g_lanes[:, 5], g_lanes[:, 4]
             t_lanes, t_cnt = _raw(_arena.timer_consume)(
                 timers, window, capacity, quantiles, timer_packed32
             )
@@ -228,20 +242,36 @@ def _sharded_ingest_consume(
             shard_err = jnp.int32(0)  # f64 arenas have no degraded mode
 
         # Cross-shard rollup stage: the multi-stage pipeline's second hop.
-        # Sum/count roll up by psum; min/max by pmin/pmax over real values,
+        # Sum/count roll up by psum; min/max over the gathered real values,
         # with the all-shards-empty NaN sentinel restored afterwards.
         g_sum = jax.lax.psum(
-            jnp.nan_to_num(g_lanes[:, 5]) + c_lanes[:, 5], SHARD_AXIS
+            jnp.nan_to_num(g_sum) + c_lanes[:, 5], SHARD_AXIS
         )
-        g_count = jax.lax.psum(c_lanes[:, 4] + g_lanes[:, 4], SHARD_AXIS)
-        g_min = jax.lax.pmin(
-            jnp.where(jnp.isnan(g_lanes[:, 1]), jnp.inf, g_lanes[:, 1]), SHARD_AXIS
-        )
-        g_max = jax.lax.pmax(
-            jnp.where(jnp.isnan(g_lanes[:, 2]), -jnp.inf, g_lanes[:, 2]), SHARD_AXIS
-        )
-        g_min = jnp.where(jnp.isposinf(g_min), jnp.nan, g_min)
-        g_max = jnp.where(jnp.isneginf(g_max), jnp.nan, g_max)
+        g_count = jax.lax.psum(c_lanes[:, 4] + g_n, SHARD_AXIS)
+        # (gather + local min/max, not pmin/pmax: the TPU compiler lowers
+        # an f64 all-reduce only for sums)
+        if layout == "packed":
+            # selected, not computed: on the keys (see packed.py)
+            K = _packed
+            k_min = jax.lax.all_gather(
+                jnp.where(k_min == K.KEY_NAN, K.KEY_PINF, k_min),
+                SHARD_AXIS).min(axis=0)
+            k_max = jax.lax.all_gather(
+                jnp.where(k_max == K.KEY_NAN, K.KEY_NINF, k_max),
+                SHARD_AXIS).max(axis=0)
+            rollup_sel = jnp.stack(
+                [jnp.where(k_min == K.KEY_PINF, K.KEY_NAN, k_min),
+                 jnp.where(k_max == K.KEY_NINF, K.KEY_NAN, k_max)], axis=1)
+            g_min = g_max = jnp.full_like(g_sum, jnp.nan)
+        else:
+            g_min = jax.lax.all_gather(
+                jnp.where(jnp.isnan(g_lanes[:, 1]), jnp.inf, g_lanes[:, 1]),
+                SHARD_AXIS).min(axis=0)
+            g_max = jax.lax.all_gather(
+                jnp.where(jnp.isnan(g_lanes[:, 2]), -jnp.inf, g_lanes[:, 2]),
+                SHARD_AXIS).max(axis=0)
+            g_min = jnp.where(jnp.isposinf(g_min), jnp.nan, g_min)
+            g_max = jnp.where(jnp.isneginf(g_max), jnp.nan, g_max)
         rollup = jnp.stack([g_sum, g_count, g_min, g_max], axis=1)
 
         new_state = ShardedAggregatorState(counters, gauges, timers)
@@ -257,6 +287,8 @@ def _sharded_ingest_consume(
             # that guards the engine path cannot fire inside shard_map
             "err": shard_err[None],
         }
+        if layout == "packed":
+            lanes["rollup_sel"] = rollup_sel
         return ShardedAggregatorState(*map(ex, new_state)), lanes
 
     shard_spec = jax.tree.map(lambda _: P(SHARD_AXIS), state)
@@ -268,12 +300,32 @@ def _sharded_ingest_consume(
         "rollup": P(),
         "err": P(SHARD_AXIS),
     }
+    if layout == "packed":
+        out_lane_spec["rollup_sel"] = P()
     return shard_map_compat(
         local_step,
         mesh,
         in_specs=(shard_spec, batch_spec, P()),
         out_specs=(shard_spec, out_lane_spec),
     )(state, batch, window)
+
+
+def gauge_lanes(lanes: dict):
+    """Host: the step's (D, C, 8) gauge lanes in SCALAR_LANES order
+    (the packed layout's exact LAST/MIN/MAX decoded from their keys)."""
+    if "rollup_sel" in lanes:  # packed layout
+        return _packed.gauge_lanes(*lanes["gauge"])[0]
+    return np.asarray(lanes["gauge"][0])
+
+
+def rollup_lanes(lanes: dict):
+    """Host: the step's (C, 4) cross-shard [sum, count, min, max], with
+    the packed layout's exact min/max decoded from ``rollup_sel``."""
+    out = np.array(lanes["rollup"], np.float64)
+    if "rollup_sel" in lanes:
+        out[:, 2:] = _packed.decode_orderable_f64(
+            np.asarray(lanes["rollup_sel"]))
+    return out
 
 
 # The sharded program composes raw(ingest) ops, whose scatter-vs-pallas

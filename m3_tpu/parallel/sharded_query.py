@@ -136,7 +136,7 @@ def _sharded_decode_rate_hq(
         # Partial sum-by-bucket, then one all-reduce over the shard axis.
         # Bucket counts are small and static, so the by-bucket sum is an
         # unrolled masked reduction — exact f64 adds, no scatter (TPU
-        # scatter measured ~1us/element, TPU_RESULTS_r05.json window #3).
+        # scatter measured ~1us/element, round 5, window 3).
         r0 = jnp.nan_to_num(rates)
         bidc = jnp.clip(bid, 0, num_buckets - 1)
         if num_buckets <= 64:

@@ -104,7 +104,7 @@ def _topk_mask_kernel(values, idx, mask, inv_g, inv_r, k: int, top: bool):
     keep_g = (key >= kth[:, None, :]) if top else (key <= kth[:, None, :])
     keep_g = keep_g & present
     # (G, R, T) back to (S, T) by GATHER through the inverse mapping —
-    # not scatter (~1us/element on TPU; TPU_RESULTS_r05.json window #3)
+    # not scatter (~1us/element on TPU; round 5, window 3)
     return keep_g[inv_g, inv_r, :]
 
 

@@ -216,11 +216,11 @@ class Engine:
 
     def _eval_instant_selector(self, sel: VectorSelector, steps: np.ndarray) -> Block:
         raw, eval_steps = self._fetch(sel, steps, self.lookback)
-        vals = tp.last_over_time(jnp.asarray(raw.ts),
-                                 jnp.asarray(raw.values),
-                                 jnp.asarray(eval_steps), self.lookback)
+        # a pure selection: host numpy, bit-exact (see tp.last_over_time)
+        vals = tp.last_over_time(raw.ts, raw.values, eval_steps,
+                                 self.lookback)
         if vals.shape[1] != len(steps):  # @-pinned single column
-            vals = jnp.broadcast_to(vals, (vals.shape[0], len(steps)))
+            vals = np.repeat(vals, len(steps), axis=1)
         return Block(steps, vals, raw.series)
 
     def _eval_call(self, call: Call, steps: np.ndarray):
@@ -253,6 +253,14 @@ class Engine:
                 # even shape its reshape.
                 return Block(steps, np.empty((0, len(steps)),
                                              np.float64), [])
+            if f == "last_over_time":
+                # a pure selection: host numpy, bit-exact whatever the
+                # device's f64 is (see tp.last_over_time)
+                out = tp.last_over_time(raw.ts, np.nan_to_num(raw.values),
+                                        eval_steps, sel_arg.range_nanos)
+                if out.shape[1] != len(steps):  # @-pinned single column
+                    out = np.repeat(out, len(steps), axis=1)
+                return Block(steps, out, [m.drop_name() for m in raw.series])
             from m3_tpu.query import precision
 
             narrow = precision.compute_dtype() == np.float32
@@ -292,8 +300,6 @@ class Engine:
                 W = tp.window_pad_for(raw.counts, raw.ts, rng)
                 out = tp.holt_winters(ts_j, vals_j, st_j, rng, max(W, 2),
                                       sfv, tfv)
-            elif f == "last_over_time":
-                out = tp.last_over_time(ts_j, vals_j, st_j, rng)
             elif f == "absent_over_time":
                 # 1 for every step where NO matched series has samples
                 # in the window; when nothing matched at all, a single

@@ -65,7 +65,7 @@ def _segment_reduce(values: np.ndarray, gids: np.ndarray, num_groups: int,
 
     Two formulations: XLA segment_* (scatter-based — fast on CPU) and a
     sort/scan/gather form for TPU, where scatter measured ~1us/element
-    (TPU_RESULTS_r05.json window #3) — a 100K-series `sum by (...)`
+    (round 5, window 3) — a 100K-series `sum by (...)`
     would otherwise scatter S*T elements.  Chosen at trace time by
     backend; both are pinned equal in tests/test_query_engine.py.
     """

@@ -5,7 +5,7 @@ TPU that default is expensive: v5e-class chips have no native f64 ALU,
 so XLA software-emulates every f64 elementwise op at ~10-20x the f32
 cost — measured here as the PromQL north star (BASELINE config #5)
 running 8x SLOWER on a TPU v5 lite than on the host CPU (47.9s vs 5.7s
-per eval; TPU_RESULTS_r05.json).
+per eval; round 5).
 
 The policy narrows the BULK stencil math (temporal kernels, the
 histogram-quantile kernel) to f32 when selected, keeping:

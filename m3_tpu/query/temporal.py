@@ -307,12 +307,24 @@ def holt_winters(ts, vals, step_times, range_nanos, window_pad: int,
     return jnp.where(n >= 2, s1, NAN)
 
 
-@jax.jit
-def last_over_time(ts, vals, step_times, range_nanos):
-    lo, hi = _window_bounds(ts, step_times, range_nanos)
-    P = vals.shape[1]
-    out = _gather_rows(vals, jnp.clip(hi - 1, 0, P - 1))
-    return jnp.where(hi > lo, out, NAN)
+def last_over_time(ts, vals, step_times, range_nanos) -> np.ndarray:
+    """Newest sample in (step - range, step] per (series, step): the
+    instant selector and ``last_over_time``.  A SELECTION, not a
+    computation, so it runs on the host in numpy: a sample that is only
+    chosen must leave the engine with the bits it was stored with, and
+    an accelerator's f64 is not IEEE (a TPU carries it as an f32 pair —
+    ~48 mantissa bits, f32 exponent range: 1e300 comes back inf).
+    ``ts`` rows are sorted with an i64-max padded tail, so no index
+    ever lands on padding."""
+    ts, vals = np.asarray(ts), np.asarray(vals)
+    step_times = np.asarray(step_times)
+    starts = step_times - range_nanos
+    out = np.full((vals.shape[0], len(step_times)), np.nan)
+    for s, row in enumerate(ts):
+        hi = np.searchsorted(row, step_times, side="right")
+        ok = hi > np.searchsorted(row, starts, side="right")
+        out[s, ok] = vals[s, hi[ok] - 1]
+    return out
 
 
 def window_pad_for(counts: np.ndarray, ts: np.ndarray, range_nanos: int) -> int:

@@ -7,7 +7,14 @@ block start; `Write` classifies warm/cold vs bufferPast/bufferFuture
 Instead of an encoder object per (series, block), the whole shard buffers
 into a ring of **append logs on device** — one per open block window:
 
-    slot (W, S) i32 | ts (W, S) i64 | val (W, S) f64 | n (W,)
+    slot (W, S) i32 | ts (W, S) i64 | val (W, S) u64 | n (W,)
+
+``val`` holds float64 BIT PATTERNS, not f64: the ring only ever moves
+values (append, sort payload, transfer), and a TPU keeps an f64 array
+as an f32 pair — ~48 mantissa bits, f32 exponent range — so a sample
+that crossed the device as f64 came back changed (measured on a v5e:
+3292 of 4109 full-mantissa values, 1e300 -> inf).  u64 lanes are exact
+everywhere; the host views them back as float64.
 
 Ingest is a single scatter per batch (same layout as the timer sample
 arenas).  Seal/flush drains a window with one lex-sort by
@@ -52,7 +59,7 @@ from m3_tpu.x import devguard, membudget
 class BufferState(NamedTuple):
     slot: jnp.ndarray  # i32 (W, S); capacity = empty sentinel
     ts: jnp.ndarray  # i64 (W, S)
-    val: jnp.ndarray  # f64 (W, S)
+    val: jnp.ndarray  # u64 (W, S) float64 bit patterns
     n: jnp.ndarray  # i64 (W,)
 
 
@@ -60,7 +67,7 @@ def buffer_init(num_windows: int, sample_capacity: int, slot_capacity: int) -> B
     return BufferState(
         slot=jnp.full((num_windows, sample_capacity), slot_capacity, jnp.int32),
         ts=jnp.full((num_windows, sample_capacity), jnp.iinfo(jnp.int64).max, jnp.int64),
-        val=jnp.zeros((num_windows, sample_capacity), jnp.float64),
+        val=jnp.zeros((num_windows, sample_capacity), jnp.uint64),
         n=jnp.zeros(num_windows, jnp.int64),
     )
 
@@ -71,16 +78,22 @@ def buffer_append(
     windows: jnp.ndarray,  # i32 (N,) ring row per sample; OOB drops
     slots: jnp.ndarray,  # i32 (N,)
     ts: jnp.ndarray,  # i64 (N,)
-    vals: jnp.ndarray,  # f64 (N,)
+    vals: jnp.ndarray,  # u64 (N,) float64 bit patterns
 ) -> BufferState:
     num_w, scap = state.slot.shape
     n = slots.shape[0]
     oob = (windows < 0) | (windows >= num_w)
     wkey = jnp.where(oob, num_w, windows)
     # Stable sort by window keeps arrival order within each window.
-    s_w, s_slot, s_ts, s_val = jax.lax.sort(
-        (wkey, slots, ts, vals), num_keys=1, is_stable=True
+    # Only (key, index) ride the sort and the columns are gathered by
+    # the permutation: the TPU compiler's time for a sort grows with
+    # every 32-bit lane it carries (26K rows: 77 s with the i64/u64
+    # columns as operands, 22 s for key+index), and each new batch
+    # size is a new compile.
+    s_w, perm = jax.lax.sort(
+        (wkey, jnp.arange(n, dtype=jnp.int32)), num_keys=1, is_stable=True
     )
+    s_slot, s_ts, s_val = slots[perm], ts[perm], vals[perm]
     pos = jnp.arange(n, dtype=jnp.int64)
     rank = pos - jnp.searchsorted(s_w, s_w, side="left")
     base = state.n[jnp.clip(s_w, 0, num_w - 1)]
@@ -103,7 +116,7 @@ def buffer_append(
         # A batch whose samples ALL target one valid window and fit
         # appends CONTIGUOUSLY at that window's write head: one
         # dynamic_update_slice (memcpy) per column instead of a scatter
-        # (~1us/element on TPU — TPU_RESULTS_r05.json window #3).  The
+        # (~1us/element on TPU — round 5, window 3).  The
         # common dbnode shape: in-order writes land in one warm window
         # of the multi-window ring, so the gate is on the BATCH, not
         # the ring size.
@@ -144,11 +157,18 @@ def buffer_drain(state: BufferState, window: jnp.ndarray):
     ts_w = jax.lax.dynamic_index_in_dim(state.ts, window, keepdims=False)
     val_w = jax.lax.dynamic_index_in_dim(state.val, window, keepdims=False)
     scap = slot_w.shape[0]
-    # arrival descending so the latest write sorts first within (slot, ts)
-    arr_desc = jnp.arange(scap - 1, -1, -1, dtype=jnp.int64)
-    s_slot, s_ts, _arr, s_val = jax.lax.sort(
-        (slot_w, ts_w, arr_desc, val_w), num_keys=3
-    )
+    # Order by (slot, ts, arrival DESCENDING) — the latest write first
+    # within (slot, ts) — as two stable single-key passes over a
+    # (key, index) pair, least significant key first, starting from
+    # arrival-descending order.  One 3-key sort of the four columns is
+    # the same order, but the TPU compiler takes minutes over it (1M
+    # rows: 239 s, against 71 s for the two passes; the i64 comparator
+    # and every carried lane multiply into each merge stage).
+    idx = jnp.arange(scap - 1, -1, -1, dtype=jnp.int32)
+    _, idx = jax.lax.sort((ts_w[idx], idx), num_keys=1, is_stable=True)
+    s_slot, idx = jax.lax.sort((slot_w[idx], idx), num_keys=1,
+                               is_stable=True)
+    s_ts, s_val = ts_w[idx], val_w[idx]
     first = jnp.concatenate(
         [jnp.ones(1, bool), (s_slot[1:] != s_slot[:-1]) | (s_ts[1:] != s_ts[:-1])]
     )
@@ -239,7 +259,7 @@ class ShardBuffer:
                     jnp.asarray(rows),
                     jnp.asarray(wslots.astype(np.int32)),
                     jnp.asarray(wts.astype(np.int64)),
-                    jnp.asarray(wvals.astype(np.float64)),
+                    jnp.asarray(wvals.astype(np.float64).view(np.uint64)),
                 )
                 self._n_host += per_row
                 self.state = state
@@ -293,7 +313,7 @@ class ShardBuffer:
                 self.state, jnp.int32(row))
             devguard.transfer_point("storage.buffer_drain")
             return (np.asarray(s_slot), np.asarray(s_ts),
-                    np.asarray(s_val), np.asarray(first))
+                    np.asarray(s_val).view(np.float64), np.asarray(first))
 
         return devguard.run_guarded("storage.buffer_drain", _device,
                                     lambda: self._host_drain(row))
@@ -303,7 +323,7 @@ class ShardBuffer:
         arrival-desc) order, same first mask; the degraded-mode tail."""
         slot_w = np.asarray(self.state.slot)[row]
         ts_w = np.asarray(self.state.ts)[row]
-        val_w = np.asarray(self.state.val)[row]
+        val_w = np.asarray(self.state.val)[row].view(np.float64)
         arrival = np.arange(len(slot_w))
         order = np.lexsort((-arrival, ts_w, slot_w))
         s_slot, s_ts, s_val = slot_w[order], ts_w[order], val_w[order]

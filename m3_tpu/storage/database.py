@@ -151,6 +151,11 @@ class Shard:
         self.slots = SlotAllocator(opts.slot_capacity,
                                    limiter=new_series_limiter)
         self.new_series_rejected = 0
+        # Series the flush encoded on the device / re-encoded with the
+        # scalar codec on the host (the batch encoder's fallback mask:
+        # streams over its 40 bit/point budget, >2^53 values).
+        self.encoded_on_device = 0
+        self.encoded_on_host = 0
         # Ring must cover (bufferPast + bufferFuture) / blockSize + 2 blocks.
         span = opts.buffer_past_nanos + opts.buffer_future_nanos
         num_windows = max(2, span // opts.block_size_nanos + 2)
@@ -214,6 +219,8 @@ class Shard:
         streams, fallback = encode_batch(
             tmat, vmat, starts, counts=counts, out_words=max(16, T * 40 // 64 + 8)
         )
+        self.encoded_on_host += int(fallback.sum())
+        self.encoded_on_device += S - int(fallback.sum())
         out = []
         for r, slot in enumerate(uniq):
             sid = self.slots.id_of(int(slot))

@@ -107,7 +107,7 @@ class CompileFailure(DeviceError):
 
 
 class DeviceLost(DeviceError):
-    """The accelerator (or its runtime/relay) went away mid-flight."""
+    """The accelerator (or its runtime) went away mid-flight."""
 
     kind = "lost"
 

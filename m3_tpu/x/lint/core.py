@@ -139,7 +139,7 @@ class Context:
                              "m3_tpu/client/session.py")
     deadline_prefixes: tuple = ()
     # the numeric/device layer the jax families police (transfer-
-    # hygiene's module-scope checks); bench.py sits outside the linted
+    # hygiene's module-scope checks); chip_smoke.py sits outside the linted
     # package and is covered by the runtime twin (tracewatch) instead
     jax_prefixes: tuple = ("m3_tpu/encoding/", "m3_tpu/parallel/",
                           "m3_tpu/aggregator/")

@@ -90,7 +90,11 @@ def parse_bytes(v) -> int:
     return int(float(m.group(1)) * mult)
 
 
-_lock = threading.Lock()
+# Re-entrant: a cyclic GC pass can start inside _admit (any allocation
+# under the lock) and run an owner's weakref finalizer, which releases
+# through _admit on the same thread — with a plain Lock that thread
+# deadlocks on itself (seen: a test worker hung for 12 minutes, PR 22).
+_lock = threading.RLock()
 _budget = parse_bytes(os.environ.get("M3_DEVICE_MEM_BUDGET", "") or 0)
 _used = 0
 _peak = 0
@@ -281,7 +285,7 @@ def arena_bytes(layout: str, num_windows: int, capacity: int,
 
 
 def buffer_bytes(num_windows: int, sample_capacity: int) -> int:
-    """Series-buffer ring bytes: slot i32 + ts i64 + val f64 per
+    """Series-buffer ring bytes: slot i32 + ts i64 + val u64 per
     (window, sample) plus the per-window i64 write heads."""
     return 20 * num_windows * sample_capacity + 8 * num_windows
 

@@ -79,13 +79,14 @@ __all__ = [
 
 DEFAULT_BUDGET = 32
 
-# Greedy to the LAST ']' in the record: the avals list itself contains
-# one ']' per array argument ("[ShapedArray(f64[2,2]), ShapedArray(
-# i32[5])]"), and the trailing "Argument mapping: (...)" carries none —
-# a non-greedy match would truncate at the first shape's ']' and
-# collapse every multi-argument signature to one broken entry.
-_COMPILE_RE = re.compile(r"^Compiling ([^\s]+) with global shapes and types "
-                         r"(\[.*\])", re.S)
+# The record reads "Compiling jit(fn) with global shapes and types
+# (ShapedArray(f64[2,2]), ShapedArray(i32[5])). Argument mapping: (...)".
+# The avals tuple carries one ')' per array argument, so its end is
+# anchored on the ". Argument mapping" that follows it, not on the
+# first ')'.
+_COMPILE_RE = re.compile(r"^Compiling (?:jit\()?([^\s]+?)\)? with global "
+                         r"shapes and types (\(.*?\))\. Argument mapping",
+                         re.S)
 
 _installed = False
 _raise_on_violation = True
@@ -177,7 +178,7 @@ class _CompileHandler(logging.Handler):
 
 
 _handler = _CompileHandler(level=logging.WARNING)
-# The one logger that emits the per-cache-miss record in jax 0.4.x.
+# The one logger that emits the per-cache-miss record.
 _PXLA_LOGGER = "jax._src.interpreters.pxla"
 
 

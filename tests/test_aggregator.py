@@ -481,12 +481,9 @@ class TestNativeIdMapParity:
 
     def test_native_matches_python(self):
         from m3_tpu.aggregator.engine import MetricMap
-        from m3_tpu.native.idmap import available
 
         py = MetricMap(1 << 10, use_native=False)
         out_py = self._drive(py)
-        if not available():
-            pytest.skip("native idmap unavailable")
         nat = MetricMap(1 << 10, use_native=True)
         assert nat._native is not None
         out_nat = self._drive(nat)

@@ -148,13 +148,14 @@ class TestCounterGaugeParity:
             idx = packed.packed_flat_index(*args, self.W, self.C)
             cs, gs = packed.rollup_ingest(
                 cs, gs, idx, jnp.asarray(cvals), jnp.asarray(gvals),
+                jnp.asarray(packed.orderable_f64(gvals)),
                 jnp.asarray(times), self.W, self.C)
         for w in range(self.W):
             for (a, _), (b, _b) in (
                 (pca.consume(w), packed.counter_consume(
                     cs, jnp.int32(w), self.C)),
-                (pga.consume(w), packed.gauge_consume(
-                    gs, jnp.int32(w), self.C)),
+                (pga.consume(w), packed.gauge_lanes(
+                    *packed.gauge_consume(gs, jnp.int32(w), self.C))),
             ):
                 a, b = np.asarray(a), np.asarray(b)
                 assert np.all((a == b) | (np.isnan(a) & np.isnan(b)))

@@ -2,7 +2,7 @@
 
 These tests predate round 6 as the scatter half of the sorted-vs-
 scatter parity suite (tests/test_sorted_ingest.py).  The sorted impl
-was deleted (BENCH_r05: 0.45-0.50x of scatter on CPU, never validated
+was deleted (round 5: 0.45-0.50x of scatter on CPU, never validated
 faster on real TPU), but the CONTRACT it was parity-tested against is
 package-wide and stays pinned here: invalid indices DROP (negative
 slots must not numpy-wrap under mode='drop', slot >= C must not alias
@@ -99,24 +99,22 @@ class TestScatterSentinels:
         assert float(np.asarray(st.sum).sum()) == 0.0
 
 
-class TestAutoImpl:
-    def test_auto_resolves_scatter_on_cpu(self):
-        arena.set_ingest_impl("auto")
-        try:
-            assert arena.ingest_impl() == "auto"
-            assert arena.resolved_ingest_impl() == "scatter"  # CPU tier
-            # and the arenas still work end to end under auto
-            st = arena.counter_ingest(
-                arena.counter_init(1, 8),
-                jnp.asarray([3], jnp.int64), jnp.asarray([3], jnp.int32),
-                jnp.asarray([5], jnp.int64), jnp.asarray([9], jnp.int64))
-            assert int(st.sum[3]) == 5
-        finally:
-            arena.set_ingest_impl("scatter")
+class TestImplNames:
+    def test_pallas_is_refused_on_a_tpu(self, monkeypatch):
+        """The kernel does not compile for a chip: there the name is an
+        error, at set time and (for the env's choice) at use."""
+        monkeypatch.setattr(arena.jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError, match="does not compile for a TPU"):
+            arena.set_ingest_impl("pallas")
+        assert arena.ingest_impl() == "scatter"  # unchanged by the refusal
+        monkeypatch.setattr(arena, "_INGEST_IMPL", "pallas")
+        with pytest.raises(ValueError, match="does not compile for a TPU"):
+            arena.ingest_impl()
 
     def test_sorted_impl_is_gone(self):
-        with pytest.raises(ValueError):
-            arena.set_ingest_impl("sorted")
+        for gone in ("sorted", "auto"):
+            with pytest.raises(ValueError):
+                arena.set_ingest_impl(gone)
 
 
 class TestGaugeOracleFuzz:

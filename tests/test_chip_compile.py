@@ -1,0 +1,213 @@
+"""Compile-only guards for the chip the node runs on: every program on
+the served path, at the widths the node uses, through the TPU compiler
+for a DESCRIBED v5e (no chip attached, nothing runs).
+
+This is the rehearsal that costs no chip time: it raises what the
+chip's compiler would raise — a Pallas block the Mosaic lowering
+refuses, a 64-bit bitcast the X64 rewriter has no rule for, a program
+that does not fit the device.  A compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture (never at
+import: only one process may load the TPU library, and every xdist
+worker imports this file), and all of it lives in this one file so one
+worker owns the library.
+"""
+
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import m3_tpu  # noqa: F401 — x64 on
+
+# The node's widths: T = 240-point scrapes in 2 h blocks; the flush
+# encodes one shard's series per call (chip_smoke: ~26K of 104K over 4
+# shards; 8192 rows is the per-call width ISSUE 22 names) into
+# out_words = max(16, T * 40 // 64 + 8) u64 words (storage/database.py).
+S, T = 8192, 240
+OW = max(16, T * 40 // 64 + 8)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device compile is written to the persistent cache but
+    # cannot be read back without a chip: keep it out
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+class A:
+    """One array argument: shape + dtype (a leaf for tree_map)."""
+
+    def __init__(self, shape, dtype):
+        self.shape, self.dtype = tuple(shape), dtype
+
+
+def _compile(fn, one_chip, *shapes, **static):
+    """Lower + compile `fn` for the described chip; prints seconds and
+    temporaries (`pytest -s` shows them: the numbers CHANGES.md cites)."""
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        shapes)
+    t0 = time.monotonic()
+    compiled = fn.lower(*args, **static).compile()
+    mem = compiled.memory_analysis()
+    print(f"\n[chip-compile] {getattr(fn, '__name__', fn)} {static}: "
+          f"{time.monotonic() - t0:.1f}s, temp "
+          f"{mem.temp_size_in_bytes / 2**20:.0f} MiB, args "
+          f"{mem.argument_size_in_bytes / 2**20:.0f} MiB")
+    return compiled
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+class TestPallasKernels:
+    """Every Pallas kernel `auto` can select on a TPU, compiled by
+    Mosaic (interpret=False) at the node's widths."""
+
+    @pytest.mark.parametrize("rows", [8, 1024, S])
+    def test_decode_gather(self, one_chip, rows):
+        from m3_tpu.parallel import pallas_decode as pd
+
+        w32 = 2 * (OW + 16)          # u32 halves of the padded stream
+        c = _compile(pd._gather3_pallas, one_chip,
+                     A((rows, w32), jnp.uint32), A((rows, 2 * T), jnp.int32),
+                     interpret=False)
+        assert _has_kernel(c)
+
+    @pytest.mark.parametrize("rows", [8, 1024, S])
+    def test_encode_place(self, one_chip, rows):
+        from m3_tpu.parallel import pallas_encode as pe
+
+        f32 = 2 * 2 * 4 * T          # hi+lo fragments, two u32 halves each
+        c = _compile(pe._place_pallas, one_chip,
+                     A((rows, f32), jnp.uint32), A((rows, f32), jnp.int32),
+                     w32=2 * OW, interpret=False)
+        assert _has_kernel(c)
+
+
+class TestCodecPrograms:
+    @pytest.mark.parametrize("place", ["pallas", "gather", "scatter"])
+    def test_encode_batch_device(self, one_chip, place, monkeypatch):
+        from m3_tpu.encoding import m3tsz_jax as mj
+        from m3_tpu.parallel import pallas_encode
+
+        # the process' backend is the CPU, so the seam would pick
+        # interpret mode: steer it as the chip would resolve it
+        monkeypatch.setattr(pallas_encode, "auto_interpret", lambda: False)
+
+        c = _compile(mj._encode_batch_device, one_chip,
+                     A((S, T), jnp.int64), A((S, T), jnp.uint64),
+                     A((S,), jnp.int64), A((S, T), jnp.bool_),
+                     unit=1, out_words=OW, place=place)
+        assert _has_kernel(c) == (place == "pallas")
+
+    @pytest.mark.parametrize("chains,extract", [
+        ("gather", "pallas"), ("gather", "jnp"), ("fused", "jnp")])
+    def test_decode_batch_device(self, one_chip, chains, extract,
+                                 monkeypatch):
+        from m3_tpu.encoding import m3tsz_jax as mj
+        from m3_tpu.parallel import pallas_decode
+
+        monkeypatch.setattr(pallas_decode, "auto_interpret", lambda: False)
+
+        c = _compile(mj._decode_batch_device, one_chip,
+                     A((S, OW), jnp.uint64), A((S,), jnp.int64),
+                     A((1 << 18,), jnp.uint32),
+                     max_points=T + 8, default_unit=1, chains=chains,
+                     scan_major=True, extract=extract)
+        assert _has_kernel(c) == (extract == "pallas")
+
+
+class TestStoragePrograms:
+    # a 2-window ring of 1M samples per shard (chip_smoke's node at
+    # ~16K series over 4 shards); compile seconds grow with the ring
+    # and with every lane a sort carries: see storage/buffer.py
+    W, CAP = 2, 1 << 20
+
+    def _state(self):
+        from m3_tpu.storage.buffer import BufferState
+
+        return BufferState(
+            slot=A((self.W, self.CAP), jnp.int32),
+            ts=A((self.W, self.CAP), jnp.int64),
+            val=A((self.W, self.CAP), jnp.uint64),
+            n=A((self.W,), jnp.int64))
+
+    def test_buffer_append(self, one_chip):
+        from m3_tpu.storage import buffer
+
+        n = 4_096                    # one scrape's share of one shard
+        _compile(buffer.buffer_append, one_chip, self._state(),
+                 A((n,), jnp.int32), A((n,), jnp.int32), A((n,), jnp.int64),
+                 A((n,), jnp.uint64))
+
+    def test_buffer_drain(self, one_chip):
+        from m3_tpu.storage import buffer
+
+        _compile(buffer.buffer_drain, one_chip, self._state(),
+                 A((), jnp.int32))
+
+
+class TestAggregatorPrograms:
+    """The packed gauge arena the downsampler feeds (HTTP writes carry
+    no metric type, so every sample lands in the gauge arena)."""
+
+    W, C, N = 4, 1 << 16, 16_384
+
+    def _state(self):
+        from m3_tpu.aggregator import packed
+
+        shapes = jax.eval_shape(lambda: packed.gauge_init(self.W, self.C))
+        return jax.tree_util.tree_map(lambda a: A(a.shape, a.dtype), shapes)
+
+    def test_gauge_ingest(self, one_chip):
+        from m3_tpu.aggregator import packed
+
+        _compile(packed.gauge_ingest, one_chip, self._state(),
+                 A((self.N,), jnp.int64), A((self.N,), jnp.float64),
+                 A((self.N,), jnp.int64), A((self.N,), jnp.int64),
+                 num_windows=self.W, capacity=self.C)
+
+    def test_gauge_consume(self, one_chip):
+        from m3_tpu.aggregator import packed
+
+        _compile(packed.gauge_consume, one_chip, self._state(),
+                 A((), jnp.int32), capacity=self.C)
+
+
+class TestQueryPrograms:
+    """rate -> sum by (le) is host-grouped; the device programs are the
+    rate stencil and the histogram_quantile kernel."""
+
+    def test_rate(self, one_chip):
+        from m3_tpu.query import temporal
+
+        _compile(temporal.rate_family, one_chip,
+                 A((S, T), jnp.int64), A((S, T), jnp.float64),
+                 A((T,), jnp.int64), A((), jnp.int64), func="rate")
+
+    def test_histogram_quantile(self, one_chip):
+        from m3_tpu.query import device_fns
+
+        G, B = 16, 10
+        _compile(device_fns._histogram_quantile_kernel, one_chip,
+                 A((G * B, T), jnp.float64), A((G, B), jnp.int32),
+                 A((G,), jnp.int32), A((G, B), jnp.float64),
+                 A((), jnp.float64))

@@ -305,7 +305,7 @@ class TestCheckGateMechanics:
         errs = costs_tool.check_artifact(
             _mini(_fp(), platform="tpu"), _mini(_fp(), platform="cpu"))
         assert [e["kind"] for e in errs] == ["platform"]
-        assert "tpu_backlog" in errs[0]["message"]
+        assert "head-to-head" in errs[0]["message"]
 
     def test_schema_mismatch_refused(self):
         base = _mini(_fp())

@@ -418,7 +418,7 @@ class TestCheckGateMechanics:
     def test_platform_mismatch_refused(self):
         errs = irlint.check_artifact(_mini(), _mini(platform="tpu"))
         assert [e["kind"] for e in errs] == ["platform"]
-        assert "tpu_backlog" in errs[0]["message"]
+        assert "head-to-head" in errs[0]["message"]
 
     def test_jax_version_mismatch_refused(self):
         base = _mini(jax="0.4.36")

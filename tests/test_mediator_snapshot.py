@@ -300,7 +300,11 @@ class TestMediator:
         med = Mediator(db, clock=lambda: START + BLOCK * 2,
                        tick_interval_s=0.05)
         med.open()
-        time.sleep(0.3)
+        # poll, don't sleep a fixed 0.3 s: the first tick seals a block
+        # and a loaded box (6 xdist workers) can take longer over it
+        deadline = time.monotonic() + 20
+        while med._ticks < 2 and time.monotonic() < deadline:
+            time.sleep(0.05)
         med.close()
         assert med._ticks >= 2
 
@@ -397,9 +401,9 @@ mediator: {{enabled: false}}
                 "db: {root: /tmp/x}\n"
                 "coordinator: {arena_ingest: scattter}\n").validate()
         cfg = load_config(
-            "db: {root: /tmp/x}\ncoordinator: {arena_ingest: auto}\n")
+            "db: {root: /tmp/x}\ncoordinator: {arena_ingest: scatter}\n")
         cfg.validate()
-        assert cfg.coordinator.arena_ingest == "auto"
+        assert cfg.coordinator.arena_ingest == "scatter"
 
     def test_arena_layout_validated(self):
         with pytest.raises(ConfigError, match="arena_layout"):

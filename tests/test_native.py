@@ -9,10 +9,6 @@ from m3_tpu.encoding.m3tsz import Datapoint, decode_series, encode_series
 
 START = 1_700_000_000 * 10**9
 
-pytestmark = pytest.mark.skipif(
-    not native.available(), reason="native toolchain unavailable"
-)
-
 
 def _cases():
     rng = np.random.default_rng(11)

@@ -11,10 +11,8 @@ import pytest
 jnp = pytest.importorskip("jax.numpy")
 
 from m3_tpu.parallel.pallas_ingest import (  # noqa: E402
-    HAVE_PALLAS, pallas_segment_ingest, xla_segment_ingest,
+    pallas_segment_ingest, xla_segment_ingest,
 )
-
-pytestmark = pytest.mark.skipif(not HAVE_PALLAS, reason="no pallas")
 
 
 @pytest.mark.parametrize("C,N,seed", [(100, 257, 0), (3000, 5000, 1),

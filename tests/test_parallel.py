@@ -7,18 +7,25 @@ import pytest
 
 from m3_tpu.aggregator import arena as _arena
 from m3_tpu.parallel import make_mesh, sharded_init, sharded_ingest_consume
-from m3_tpu.parallel.sharded_agg import ShardedBatch
+from m3_tpu.aggregator.packed import orderable_f64
+from m3_tpu.parallel.sharded_agg import (
+    ShardedBatch, gauge_lanes, rollup_lanes,
+)
 
 
 def _mk_batch(topo, W, C, N, seed=0):
     D = topo.num_shards
     rng = np.random.default_rng(seed)
     sh = lambda a, dt: jax.device_put(jnp.asarray(a, dt), topo.sharded(None))
+    gvals = rng.normal(100.0, 10.0, (D, N))
+    gvals[:, ::7] = 1e300   # beyond an accelerator's f64 range
+    gvals[:, 3::11] = -5e-324
     return ShardedBatch(
         windows=sh(rng.integers(0, W, (D, N)), jnp.int32),
         slots=sh(rng.integers(0, C, (D, N)), jnp.int32),
         counter_values=sh(rng.integers(0, 1000, (D, N)), jnp.int64),
-        gauge_values=sh(rng.normal(100.0, 10.0, (D, N)), jnp.float64),
+        gauge_values=sh(gvals, jnp.float64),
+        gauge_keys=sh(orderable_f64(gvals), jnp.int64),
         timer_values=sh(np.abs(rng.normal(0.1, 0.02, (D, N))), jnp.float64),
         times=sh(np.tile(np.arange(1, N + 1), (D, 1)), jnp.int64),
     )
@@ -40,10 +47,14 @@ def test_sharded_step_matches_single_device(shards, replicas):
     slots = np.asarray(batch.slots)
     cvals = np.asarray(batch.counter_values)
     times = np.asarray(batch.times)
+    gvals = np.asarray(batch.gauge_values)
     c_lanes = np.asarray(lanes["counter"][0])
-    assert c_lanes.shape == (shards, C, 8)
+    gl = gauge_lanes(lanes)
+    assert c_lanes.shape == gl.shape == (shards, C, 8)
+    g_min = np.full(C, np.inf)
+    g_max = np.full(C, -np.inf)
     for d in range(shards):
-        a, _g, _t = _arena.make_arenas(W, C, 4 * N, (0.5, 0.95, 0.99))
+        a, g, _t = _arena.make_arenas(W, C, 4 * N, (0.5, 0.95, 0.99))
         a.ingest(
             jnp.asarray(windows[d]),
             jnp.asarray(slots[d]),
@@ -52,15 +63,27 @@ def test_sharded_step_matches_single_device(shards, replicas):
         )
         want, _ = a.consume(0)
         np.testing.assert_allclose(c_lanes[d], np.asarray(want), rtol=0, atol=0)
+        # gauge LAST / MIN / MAX are selections: bit-equal to the
+        # single-device arena, extreme values included
+        g.ingest(windows[d], slots[d], gvals[d], times[d])
+        want = np.asarray(g.consume(0)[0])
+        assert (gl[d, :, :3].view(np.int64)
+                == want[:, :3].view(np.int64)).all()
+        np.testing.assert_allclose(gl[d, :, 3:], want[:, 3:], rtol=1e-12)
+        g_min = np.fmin(g_min, want[:, 1])
+        g_max = np.fmax(g_max, want[:, 2])
 
     # Packed degraded-state flags must be clean on a healthy run (the
     # engine path raises; the sharded path surfaces the same bits here).
     assert int(np.asarray(lanes["err"]).sum()) == 0
 
     # Global rollup = sum of per-shard sums for window 0.
-    rollup = np.asarray(lanes["rollup"])
+    rollup = rollup_lanes(lanes)
+    np.testing.assert_array_equal(
+        rollup[:, 2], np.where(np.isinf(g_min), np.nan, g_min))
+    np.testing.assert_array_equal(
+        rollup[:, 3], np.where(np.isinf(g_max), np.nan, g_max))
     gsum_want = 0.0
-    gl = np.asarray(lanes["gauge"][0])
     for d in range(shards):
         gsum_want += np.nan_to_num(gl[d, :, 5]) + c_lanes[d, :, 5]
     np.testing.assert_allclose(rollup[:, 0], gsum_want, rtol=1e-12)

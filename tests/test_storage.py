@@ -288,7 +288,8 @@ class TestBufferAppendFastPath:
             st = buffer_append(st, jnp.asarray(windows, jnp.int32),
                                jnp.asarray(slots, jnp.int32),
                                jnp.asarray(ts, jnp.int64),
-                               jnp.asarray(vals))
+                               jnp.asarray(np.asarray(vals, np.float64)
+                                           .view(np.uint64)))
         return st
 
     def test_consecutive_fitting_batches(self):
@@ -305,7 +306,7 @@ class TestBufferAppendFastPath:
         np.testing.assert_array_equal(
             np.asarray(st.slot[0][:40]), batches[0][1].astype(np.int32))
         np.testing.assert_array_equal(
-            np.asarray(st.val[0][40:80]), batches[1][3])
+            np.asarray(st.val[0][40:80]).view(np.float64), batches[1][3])
 
     def test_drops_fall_back_to_scatter_exactly(self):
         rng = np.random.default_rng(5)
@@ -318,7 +319,8 @@ class TestBufferAppendFastPath:
         keep = windows == 0
         np.testing.assert_array_equal(np.asarray(st.slot[0][:3]),
                                       slots[keep].astype(np.int32))
-        np.testing.assert_array_equal(np.asarray(st.val[0][:3]), vals[keep])
+        np.testing.assert_array_equal(
+            np.asarray(st.val[0][:3]).view(np.float64), vals[keep])
 
     def test_overflow_batch_keeps_scatter_semantics(self):
         windows = np.zeros(32, np.int32)
@@ -327,7 +329,8 @@ class TestBufferAppendFastPath:
         vals = np.arange(32, dtype=np.float64)
         st = self._drive(1, 16, [(windows, slots, ts, vals)])
         assert int(st.n[0]) == 32  # n counts past capacity (overflow signal)
-        np.testing.assert_array_equal(np.asarray(st.val[0]), vals[:16])
+        np.testing.assert_array_equal(
+            np.asarray(st.val[0]).view(np.float64), vals[:16])
 
     def test_multiwindow_uniform_batch_fast_path(self):
         """The production shape: a batch targeting ONE window of a
@@ -344,7 +347,7 @@ class TestBufferAppendFastPath:
         np.testing.assert_array_equal(
             np.asarray(st.slot[2][:30]), batches[0][1].astype(np.int32))
         np.testing.assert_array_equal(
-            np.asarray(st.val[2][30:60]), batches[1][3])
+            np.asarray(st.val[2][30:60]).view(np.float64), batches[1][3])
 
     def test_multiwindow_mixed_batch_scatter_parity(self):
         """A batch spanning windows must land identically to per-window
@@ -396,7 +399,8 @@ class TestBufferAppendFastPath:
             st = buffer_init(W, S, 64)
             for wd, sl, ts, vl in batches:
                 st = buffer_append(st, jnp.asarray(wd), jnp.asarray(sl),
-                                   jnp.asarray(ts), jnp.asarray(vl))
+                                   jnp.asarray(ts),
+                                   jnp.asarray(vl.view(np.uint64)))
             o_slot = np.full((W, S), 64, np.int32)
             o_ts = np.full((W, S), np.iinfo(np.int64).max, np.int64)
             o_val = np.zeros((W, S))
@@ -413,5 +417,6 @@ class TestBufferAppendFastPath:
                         o_n[w] += 1
             np.testing.assert_array_equal(np.asarray(st.slot), o_slot)
             np.testing.assert_array_equal(np.asarray(st.ts), o_ts)
-            np.testing.assert_array_equal(np.asarray(st.val), o_val)
+            np.testing.assert_array_equal(
+                np.asarray(st.val).view(np.float64), o_val)
             np.testing.assert_array_equal(np.asarray(st.n), o_n)

@@ -180,10 +180,7 @@ def test_deriv_and_predict_linear():
 
 def test_last_over_time():
     ts, vals, counts, steps = _mk_series()
-    got = np.asarray(
-        tp.last_over_time(jnp.asarray(ts), jnp.asarray(np.nan_to_num(vals)),
-                          jnp.asarray(steps), RANGE)
-    )
+    got = tp.last_over_time(ts, np.nan_to_num(vals), steps, RANGE)
     for s in range(ts.shape[0]):
         for j, t in enumerate(steps):
             _, wv = _window(ts[s], np.nan_to_num(vals[s]), counts[s], t, RANGE)
