@@ -22,6 +22,8 @@ import numpy as np
 
 from m3_tpu.aggregator.engine import AggregatorOptions, MetricList
 from m3_tpu.index.doc import Document
+from m3_tpu.instrument import tracing
+from m3_tpu.instrument.tracing import Tracepoint
 from m3_tpu.metrics.aggregation import AggregationID, AggregationType
 from m3_tpu.metrics.policy import StoragePolicy
 from m3_tpu.metrics.rules import Matcher, RuleSet
@@ -58,8 +60,10 @@ class Downsampler:
         # HTTP/carbon handler threads while the mediator drives flush
         # and checkpointing — an unsynchronized flush racing an ingest
         # would tear the arena state mid-snapshot (and a checkpoint of
-        # it would not be bit-exact).
-        self._lock = threading.Lock()
+        # it would not be bit-exact).  The wait for it is a span of its
+        # own (downsample.lock.wait).
+        self._lock = tracing.SpanLock(
+            threading.Lock(), Tracepoint.DOWNSAMPLE_LOCK_WAIT)
 
     def output_namespace(self, sp: StoragePolicy) -> str:
         """Aggregates write to the policy's own namespace (the reference
@@ -106,37 +110,40 @@ class Downsampler:
         # RollupResult.pipeline was silently dropped here, so a rule
         # like rollup(...).perSecond() aggregated wrong).
         batches: Dict[tuple, List] = {}
-        for i, doc in enumerate(docs):
-            res = self.matcher.match(doc.id, doc.tags())
-            if res.drop:
-                keep[i] = False
-            for m in res.mappings:
-                self._series_tags.setdefault(doc.id, doc.tags())
-                for sp in m.policies:
-                    batches.setdefault(
-                        (sp, m.aggregation_id, doc.id, None), []).append(i)
-            for r in res.rollups:
-                self._series_tags.setdefault(r.id, r.tags)
-                for sid2, stags2 in r.stage_tags:
-                    # Downstream pipeline stages' outputs need their
-                    # tags registered too, or the final writeback
-                    # couldn't index them.
-                    self._series_tags.setdefault(sid2, stags2)
-                pl = r.pipeline if not r.pipeline.is_empty() else None
-                for sp in r.policies:
-                    batches.setdefault(
-                        (sp, r.aggregation_id, r.id, pl), []).append(i)
-        # Group by (policy, agg, tail) for batched arena adds.
-        grouped: Dict[tuple, List] = {}
-        for (sp, agg, mid, pl), idxs in batches.items():
-            g = grouped.setdefault((sp, agg, pl), ([], []))
-            g[0].extend([mid] * len(idxs))
-            g[1].extend(idxs)
-        for (sp, agg, pl), (ids, idxs) in grouped.items():
-            sel = np.asarray(idxs)
-            self._list_for(sp).add_batch(
-                metric_type, ids, vals[sel], ts[sel], agg, pipeline=pl
-            )
+        with tracing.span(Tracepoint.DOWNSAMPLE_MATCH):
+            for i, doc in enumerate(docs):
+                res = self.matcher.match(doc.id, doc.tags())
+                if res.drop:
+                    keep[i] = False
+                for m in res.mappings:
+                    self._series_tags.setdefault(doc.id, doc.tags())
+                    for sp in m.policies:
+                        batches.setdefault(
+                            (sp, m.aggregation_id, doc.id, None), []
+                        ).append(i)
+                for r in res.rollups:
+                    self._series_tags.setdefault(r.id, r.tags)
+                    for sid2, stags2 in r.stage_tags:
+                        # Downstream pipeline stages' outputs need their
+                        # tags registered too, or the final writeback
+                        # couldn't index them.
+                        self._series_tags.setdefault(sid2, stags2)
+                    pl = r.pipeline if not r.pipeline.is_empty() else None
+                    for sp in r.policies:
+                        batches.setdefault(
+                            (sp, r.aggregation_id, r.id, pl), []).append(i)
+        with tracing.span(Tracepoint.DOWNSAMPLE_ADD):
+            # Group by (policy, agg, tail) for batched arena adds.
+            grouped: Dict[tuple, List] = {}
+            for (sp, agg, mid, pl), idxs in batches.items():
+                g = grouped.setdefault((sp, agg, pl), ([], []))
+                g[0].extend([mid] * len(idxs))
+                g[1].extend(idxs)
+            for (sp, agg, pl), (ids, idxs) in grouped.items():
+                sel = np.asarray(idxs)
+                self._list_for(sp).add_batch(
+                    metric_type, ids, vals[sel], ts[sel], agg, pipeline=pl
+                )
         return keep
 
     # -- flush path --------------------------------------------------------
@@ -146,7 +153,7 @@ class Downsampler:
         (reference flush_handler.go → ingest write path).  Aggregated
         series IDs carry the aggregation-type suffix (reference id
         suffixing, e.g. `.p99` for timer quantiles)."""
-        with self._lock:
+        with tracing.span(Tracepoint.DOWNSAMPLE_FLUSH), self._lock:
             return self._flush_locked(now_nanos)
 
     def _flush_locked(self, now_nanos: int) -> int:
@@ -155,46 +162,56 @@ class Downsampler:
             # Multi-stage rollups: consume self-delivers forwarded stage
             # outputs per window back into this list (the in-process
             # forwarded writer); each hop flushes one window later.
-            for flushed in ml.consume(now_nanos):
-                owner = ml.maps[flushed.metric_type]
-                ids: List[bytes] = []
-                ts_out: List[int] = []
-                vals_out: List[float] = []
-                docs: List[Document] = []
-                mt = flushed.metric_type
-                defaults = AggregationID.DEFAULT.types_for(mt)
-                default_mask = 0
-                for t in defaults:
-                    default_mask |= 1 << int(t)
-                # Only a SINGLE-type default set may emit unsuffixed:
-                # multi-type sets (timers) would collide on one ID.
-                single_default = len(defaults) == 1
-                for slot, t_, v in zip(flushed.slots, flushed.types, flushed.values):
-                    at = AggregationType(int(t_))
-                    base = owner.id_of(int(slot))
-                    if base is None:
-                        continue
-                    # Reference naming: the default aggregation set for a
-                    # metric type emits unsuffixed IDs; anything else
-                    # carries the type suffix (types_options.go).
-                    is_default = (
-                        single_default
-                        and int(owner.agg_mask[int(slot)]) == default_mask
-                    )
-                    out_id = base if is_default else base + at.suffix
-                    tags = dict(self._series_tags.get(base) or {b"__name__": base})
-                    if not is_default and b"__name__" in tags:
-                        tags[b"__name__"] = tags[b"__name__"] + at.suffix
-                    docs.append(Document.from_tags(out_id, tags))
-                    ids.append(out_id)
-                    ts_out.append(flushed.timestamp_nanos)
-                    vals_out.append(float(v))
-                if ids:
-                    self.db.write_tagged_batch(
-                        self.output_namespace(sp), docs,
-                        np.asarray(ts_out, np.int64), np.asarray(vals_out),
-                    )
-                    written += len(ids)
+            with tracing.span(Tracepoint.AGG_CONSUME):
+                drained = ml.consume(now_nanos)
+            with tracing.span(Tracepoint.DOWNSAMPLE_WRITEBACK):
+                written += self._write_back(sp, ml, drained)
+        return written
+
+    def _write_back(self, sp: StoragePolicy, ml: MetricList, drained) -> int:
+        """Drained windows -> aggregated series in the policy's
+        namespace, through the database's ordinary write path."""
+        written = 0
+        for flushed in drained:
+            owner = ml.maps[flushed.metric_type]
+            ids: List[bytes] = []
+            ts_out: List[int] = []
+            vals_out: List[float] = []
+            docs: List[Document] = []
+            mt = flushed.metric_type
+            defaults = AggregationID.DEFAULT.types_for(mt)
+            default_mask = 0
+            for t in defaults:
+                default_mask |= 1 << int(t)
+            # Only a SINGLE-type default set may emit unsuffixed:
+            # multi-type sets (timers) would collide on one ID.
+            single_default = len(defaults) == 1
+            for slot, t_, v in zip(flushed.slots, flushed.types, flushed.values):
+                at = AggregationType(int(t_))
+                base = owner.id_of(int(slot))
+                if base is None:
+                    continue
+                # Reference naming: the default aggregation set for a
+                # metric type emits unsuffixed IDs; anything else
+                # carries the type suffix (types_options.go).
+                is_default = (
+                    single_default
+                    and int(owner.agg_mask[int(slot)]) == default_mask
+                )
+                out_id = base if is_default else base + at.suffix
+                tags = dict(self._series_tags.get(base) or {b"__name__": base})
+                if not is_default and b"__name__" in tags:
+                    tags[b"__name__"] = tags[b"__name__"] + at.suffix
+                docs.append(Document.from_tags(out_id, tags))
+                ids.append(out_id)
+                ts_out.append(flushed.timestamp_nanos)
+                vals_out.append(float(v))
+            if ids:
+                self.db.write_tagged_batch(
+                    self.output_namespace(sp), docs,
+                    np.asarray(ts_out, np.int64), np.asarray(vals_out),
+                )
+                written += len(ids)
         return written
 
     # -- checkpoint/restore (aggregator/checkpoint.py; the mediator's

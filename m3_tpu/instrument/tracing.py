@@ -32,12 +32,27 @@ wire (``<QQB``).  The context seam mirrors ``x/deadline.py`` exactly:
 Span ids are drawn from a per-process random 64-bit space (not a
 counter) so ids minted by different processes in one trace cannot
 collide.
+
+**One switch, two ways to turn it on.**  A span records iff its tracer
+is ``enabled`` (the operator's ``coordinator.tracing``) OR a JAX
+profiler session is live (``jax.profiler.TraceAnnotation.is_enabled()``
+— capturing a profile turns the node's spans on for as long as the
+capture lasts).  A recorded span goes to the ring AND stands as an
+``m3:<name>`` annotation in the profiler's own trace, on plane
+``/host:CPU`` of the same ``.xplane.pb`` as the device's ops: that is
+how the node's host time and the device's idle gaps meet on one clock.
+Not recording costs one call, one flag and one contextvar read per
+site.  ``install``
+makes a node's tracer the process's tracer, so code with no handle
+(devguard, the namespace's write tail, the downsampler, the mediator)
+opens spans through module-level :func:`span`.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import random
 import struct
 import threading
@@ -45,26 +60,51 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 
+import jax
+
+# True inside a jax.profiler session (start_trace .. stop_trace, or a
+# capture through the profiler server), False outside: a static C++ flag
+_profiling = jax.profiler.TraceAnnotation.is_enabled
+
 
 class Tracepoint:
     """Stable span names (reference dbnode/tracepoint/tracepoint.go)."""
 
     DB_WRITE_BATCH = "db.writeBatch"
+    DB_INDEX_WRITE = "db.index.write"        # series lookup / insert
+    DB_BUFFER_WRITE = "db.buffer.write"      # shard hash, staging, append
+    DB_COMMITLOG_WRITE = "db.commitlog.write"
+    DB_LOCK_WAIT = "db.lock.wait"            # acquisition of Database._mu
     DB_READ = "db.read"
     DB_QUERY_IDS = "db.queryIDs"
     DB_BOOTSTRAP = "db.bootstrap"
     DB_TICK = "db.tick"
     DB_SNAPSHOT = "db.snapshot"
     ENGINE_EXECUTE = "query.engine.execute"
+    EVAL_CALL = "query.eval.call"                # tag fn: the function
+    EVAL_AGGREGATION = "query.eval.aggregation"  # tag op: the operator
     FETCH_COMPRESSED = "query.storage.fetchCompressed"
-    API_QUERY_RANGE = "api.queryRange"
-    API_WRITE = "api.write"
+    API_QUERY_RANGE = "api.queryRange"           # the whole read handler
+    API_QUERY_RENDER = "api.queryRange.render"   # values loop + JSON
+    API_WRITE = "api.write"                      # the whole write handler
+    API_WRITE_DECODE = "api.write.decode"        # body, parse, Documents
+    API_WRITE_SNAPPY = "api.write.decode.snappy"      # remote write only
+    API_WRITE_PROTOBUF = "api.write.decode.protobuf"  # remote write only
     INGEST_TCP_BATCH = "ingest.tcp.batch"
     AGG_CONSUME = "aggregator.consume"
+    DOWNSAMPLE_LOCK_WAIT = "downsample.lock.wait"
+    DOWNSAMPLE_MATCH = "downsample.match"        # per-doc rule match loop
+    DOWNSAMPLE_ADD = "downsample.add"            # arena staging
+    DOWNSAMPLE_FLUSH = "downsample.flush"
+    DOWNSAMPLE_WRITEBACK = "downsample.writeback"
+    MEDIATOR_RUN_ONCE = "mediator.runOnce"
+    # host time inside one guarded device call (staging, dispatch, any
+    # blocking transfer; NOT device time): "device." + devguard's stage
+    DEVICE = "device."
+    RUNTIME_GC = "runtime.gc"                    # tag generation (>= 1)
     # cross-process hops (round 10): the server-side spans each wire
     # protocol opens around dispatch, and the client-side fan-out span
     RPC_SERVER = "rpc.server"
-    RPC_CLIENT = "rpc.client"
     REMOTE_FETCH = "query.remote.fetch"
     SESSION_WRITE = "session.writeReplica"
 
@@ -137,6 +177,11 @@ class Span:
     end_ns: int = 0
     tags: dict = field(default_factory=dict)
     error: str | None = None
+    # CPU nanoseconds of the span's own thread between start and end
+    # (while open: the thread's CPU clock at start).  Under one GIL the
+    # wall time of a span includes the wait for the GIL; this does not,
+    # so CPU self times of concurrent requests add up to wall time.
+    cpu_ns: int = 0
 
     @property
     def duration_ns(self) -> int:
@@ -147,7 +192,7 @@ class Span:
             "name": self.name, "trace_id": self.trace_id,
             "span_id": self.span_id, "parent_id": self.parent_id,
             "start_ns": self.start_ns, "duration_ns": self.duration_ns,
-            "tags": self.tags, "error": self.error,
+            "cpu_ns": self.cpu_ns, "tags": self.tags, "error": self.error,
         }
 
     @property
@@ -156,12 +201,13 @@ class Span:
 
 
 class _ActiveSpan:
-    __slots__ = ("_tracer", "span", "_token")
+    __slots__ = ("_tracer", "span", "_token", "_annotation")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self.span = span
         self._token = None
+        self._annotation = None
 
     def set_tag(self, key: str, value) -> None:
         self.span.tags[key] = value
@@ -171,9 +217,18 @@ class _ActiveSpan:
         # children parent on it via the tracer stack, wire clients
         # serialize it via tracing.current()/current_wire()
         self._token = _current.set(self.span.context)
+        if _profiling():
+            # the same span in the profiler's own trace, beside the
+            # device's ops (plane /host:CPU, this thread's line)
+            self._annotation = jax.profiler.TraceAnnotation(
+                "m3:" + self.span.name)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         if exc is not None:
             self.span.error = f"{type(exc).__name__}: {exc}"
         if self._token is not None:
@@ -200,11 +255,13 @@ _UNSAMPLED = TraceContext(0, 0, sampled=False)
 
 
 class _UnsampledSpan:
-    """Returned when a ROOT span loses the sampling roll: records
-    nothing, but BINDS a not-sampled context for its scope so every
-    descendant (and every wire hop) inherits the negative decision —
-    otherwise each child would re-roll as a fresh root and litter the
-    ring with unparented fragment traces."""
+    """Returned when a ROOT span loses the sampling roll, or opens
+    while nothing records: records nothing, but BINDS a not-sampled
+    context for its scope so every descendant (and every wire hop)
+    inherits the negative decision — otherwise each child would
+    re-roll as a fresh root and litter the ring with unparented
+    fragment traces (the children of a request that was in flight
+    when a profiler session opened, for one)."""
 
     __slots__ = ("_token",)
 
@@ -223,18 +280,41 @@ class _UnsampledSpan:
 class Tracer:
     """Span factory + bounded finished-span ring; parentage flows
     through a thread-local active-span stack in-process and through the
-    bound :class:`TraceContext` across processes."""
+    bound :class:`TraceContext` across processes.
 
-    def __init__(self, max_finished: int = 4096, enabled: bool = True,
+    ``enabled`` is the operator's switch; a live profiler session turns
+    recording on as well (module docstring).  The ring holds the newest
+    ``max_finished`` spans: ``dropped`` counts those it pushed out and
+    ``dropped_until_ns`` is the latest end among them, so a reader
+    knows its account of an interval is whole iff nothing was dropped
+    or the interval began after that instant."""
+
+    def __init__(self, max_finished: int = 65536, enabled: bool = True,
                  sample_rate: float = 1.0):
         self.enabled = enabled
         self.sample_rate = float(sample_rate)
-        self._ring: deque[Span] = deque(maxlen=max_finished)
-        self._lock = threading.Lock()
+        self.max_finished = int(max_finished)
+        self._ring: deque[Span] = deque()
+        self.dropped = 0
+        self.dropped_until_ns = 0
+        # reentrant: the collector's hook (install) opens and finishes
+        # runtime.gc spans on whatever thread collects, which may be
+        # inside one of this lock's (few-bytecode) sections
+        self._lock = threading.RLock()
         self._tls = threading.local()
         # Random 64-bit ids: two processes in one trace must not mint
         # colliding span ids the way a shared counter would.
         self._rng = random.Random()
+
+    @property
+    def recording(self) -> bool:
+        return self.enabled or _profiling()
+
+    @property
+    def oldest_start_ns(self) -> int | None:
+        """Start of the oldest span the ring still holds."""
+        with self._lock:
+            return self._ring[0].start_ns if self._ring else None
 
     def _ids(self) -> int:
         with self._lock:
@@ -259,8 +339,13 @@ class Tracer:
         bound remote :class:`TraceContext` (a server dispatch joining
         its caller's trace), else a fresh root — sampled per
         ``sample_rate`` (a bound context's sampled flag always wins)."""
-        if not self.enabled:
-            return NOOP_SPAN
+        if not self.recording:
+            # not recording.  A would-be root still binds the negative
+            # decision for its scope: were a profiler session to open
+            # mid-request, the request's later spans find it and stay
+            # out of the ring instead of entering it as orphan roots
+            return (NOOP_SPAN if _current.get() is not None
+                    else _UnsampledSpan())
         stack = self._stack()
         parent = stack[-1] if stack else None
         if parent is not None:
@@ -284,17 +369,45 @@ class Tracer:
             parent_id=parent_id,
             start_ns=time.monotonic_ns(),
             tags=dict(tags or {}),
+            cpu_ns=time.thread_time_ns(),
         )
         stack.append(span)
         return _ActiveSpan(self, span)
 
     def _finish(self, span: Span) -> None:
+        span.cpu_ns = time.thread_time_ns() - span.cpu_ns
         span.end_ns = time.monotonic_ns()
         stack = self._stack()
         if stack and stack[-1] is span:
             stack.pop()
         with self._lock:
-            self._ring.append(span)
+            ring = self._ring
+            ring.append(span)
+            while len(ring) > self.max_finished:
+                self.dropped += 1
+                # max: threads append a little out of the order of end
+                self.dropped_until_ns = max(self.dropped_until_ns,
+                                            ring.popleft().end_ns)
+
+    # -- the collector ------------------------------------------------------
+
+    def gc_hook(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` entry (registered by :func:`install`): a
+        collection of generation >= 1 is a ``runtime.gc`` span under
+        whatever is open on the collecting thread.  It holds the GIL,
+        so every thread of the node stalls for its length."""
+        if info["generation"] < 1:
+            return
+        if phase == "start":
+            if self.recording:
+                active = self.start_span(
+                    Tracepoint.RUNTIME_GC, {"generation": info["generation"]})
+                self._tls.gc = active.__enter__()
+        else:
+            active = getattr(self._tls, "gc", None)
+            if active is not None:
+                self._tls.gc = None
+                active.__exit__(None, None, None)
 
     # -- introspection -----------------------------------------------------
 
@@ -327,9 +440,77 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._ring.clear()
+            self.dropped = 0
+            self.dropped_until_ns = 0
 
 
-NOOP_TRACER = Tracer(enabled=False)
+class _NoopTracer(Tracer):
+    """The tracer of code that was handed none: never records, profiler
+    session or not."""
+
+    recording = False
+
+    def start_span(self, name: str, tags: dict | None = None):
+        return NOOP_SPAN
+
+
+NOOP_TRACER = _NoopTracer(enabled=False)
+
+
+# -- the process's tracer ----------------------------------------------------
+
+_installed: Tracer = NOOP_TRACER
+
+
+def install(tracer: Tracer) -> None:
+    """Make ``tracer`` the process's tracer (what module-level
+    :func:`span` opens spans on) and hand it the collector's hook.  A
+    node does this once, in ``run_node``; the last node installed in a
+    process wins."""
+    global _installed
+    uninstall(_installed)
+    _installed = tracer
+    gc.callbacks.append(tracer.gc_hook)
+
+
+def uninstall(tracer: Tracer) -> None:
+    """Undo :func:`install` if ``tracer`` is still the one installed;
+    its ring stays readable."""
+    global _installed
+    if tracer is _installed and tracer is not NOOP_TRACER:
+        gc.callbacks.remove(tracer.gc_hook)
+        _installed = NOOP_TRACER
+
+
+def span(name: str, tags: dict | None = None):
+    """``start_span`` on the process's tracer, for code with no handle."""
+    return _installed.start_span(name, tags)
+
+
+class SpanLock:
+    """A lock whose every acquisition stands under a span named
+    ``name``: the wait for it, which a span opened inside the ``with``
+    cannot see.  ``with`` and acquire/release as the wrapped lock."""
+
+    __slots__ = ("_lock", "_name", "_tracer")
+
+    def __init__(self, lock, name: str, tracer: Tracer | None = None):
+        self._lock, self._name, self._tracer = lock, name, tracer
+
+    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+        with (self._tracer or _installed).start_span(self._name):
+            return self._lock.acquire(blocking, timeout)
+
+    def release(self) -> None:
+        self._lock.release()
+
+    def __enter__(self) -> "SpanLock":
+        self.acquire()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._lock.release()
+        return False
 
 
 # -- cross-process trace assembly -------------------------------------------
@@ -342,7 +523,8 @@ def traces_response(tracer: "Tracer", trace_id=None,
     dtest harness collects through either port; the two handlers must
     not drift).  ``trace_id`` → that trace's spans parent-before-child;
     ``name`` → spans of one tracepoint; default → ring inventory + raw
-    spans."""
+    spans (``dropped`` > 0 = the ring has pushed spans out: whatever
+    ended at or before ``dropped_until_ns`` may be missing)."""
     if trace_id is not None:
         tid = int(trace_id)
         spans = [s.to_dict() for s in tracer.finished()
@@ -351,6 +533,8 @@ def traces_response(tracer: "Tracer", trace_id=None,
                 "data": join_traces(spans).get(tid, [])}
     return {"status": "success",
             "inventory": tracer.inventory() if name is None else None,
+            "dropped": tracer.dropped,
+            "dropped_until_ns": tracer.dropped_until_ns,
             "data": [s.to_dict() for s in tracer.finished(name)]}
 
 

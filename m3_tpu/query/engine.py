@@ -18,6 +18,7 @@ from typing import Protocol
 import jax.numpy as jnp
 import numpy as np
 
+from m3_tpu.instrument.tracing import NOOP_TRACER, Tracepoint
 from m3_tpu.query import functions as fn
 from m3_tpu.query import temporal as tp
 from m3_tpu.x import deadline as xdeadline
@@ -60,8 +61,6 @@ class Engine:
 
     def __init__(self, storage: Storage, lookback_nanos: int = LOOKBACK_NANOS,
                  tracer=None):
-        from m3_tpu.instrument.tracing import NOOP_TRACER
-
         self.storage = storage
         self.lookback = lookback_nanos
         self.tracer = tracer if tracer is not None else NOOP_TRACER
@@ -80,8 +79,6 @@ class Engine:
         the whole evaluation: checked between eval nodes and inside
         per-step loops, threaded to storage fetches through the context
         binding (callers that already bound one can omit it)."""
-        from m3_tpu.instrument.tracing import Tracepoint
-
         with self.tracer.start_span(Tracepoint.ENGINE_EXECUTE,
                                     {"query": query}):
             with xdeadline.bind(deadline if deadline is not None
@@ -136,9 +133,12 @@ class Engine:
                 raise ValueError("range selector outside temporal function")
             return self._eval_instant_selector(e, steps)
         if isinstance(e, Call):
-            return self._eval_call(e, steps)
+            with self.tracer.start_span(Tracepoint.EVAL_CALL, {"fn": e.func}):
+                return self._eval_call(e, steps)
         if isinstance(e, Aggregation):
-            return self._eval_aggregation(e, steps)
+            with self.tracer.start_span(Tracepoint.EVAL_AGGREGATION,
+                                        {"op": e.op}):
+                return self._eval_aggregation(e, steps)
         if isinstance(e, BinaryOp):
             return self._eval_binary(e, steps)
         raise ValueError(f"cannot evaluate {e}")

@@ -15,6 +15,8 @@ import numpy as np
 from m3_tpu.index.search import (
     All, Conjunction, Negation, Query, Regexp, Term,
 )
+from m3_tpu.instrument import tracing
+from m3_tpu.instrument.tracing import Tracepoint
 from m3_tpu.query.block import RawBlock, SeriesMeta
 from m3_tpu.query.promql import LabelMatcher
 from m3_tpu.storage.database import Database, ShardNotOwnedError
@@ -65,7 +67,7 @@ class DatabaseStorage:
         fault.fire("query.fetch")
         dl = xdeadline.current()
         with (dl.phase("fetch") if dl is not None
-              else _NULL_PHASE):
+              else _NULL_PHASE), tracing.span(Tracepoint.FETCH_COMPRESSED):
             return self._fetch_raw(name, matchers, start_nanos, end_nanos)
 
     def _fetch_raw(self, name, matchers, start_nanos, end_nanos) -> RawBlock:

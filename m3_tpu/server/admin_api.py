@@ -175,7 +175,7 @@ class _AdminHandler(BaseHTTPRequestHandler):
                 from m3_tpu.instrument.tracing import traces_response
 
                 tr = self.ctx.tracer
-                if tr is None:
+                if tr is None or not tr.recording:
                     return self._json(404, {"error": "no tracer configured"})
                 q = parse_qs(urlparse(self.path).query)
                 return self._json(200, traces_response(

@@ -96,6 +96,11 @@ class Assembly:
         if self.kv is not None and hasattr(self.kv, "close"):
             self.kv.close()
         self.db.close()
+        if self.tracer is not None:
+            # no longer the process's tracer; its ring stays readable
+            from m3_tpu.instrument import tracing
+
+            tracing.uninstall(self.tracer)
 
     def drain(self, handoff_timeout_s: float = 60.0) -> None:
         """True SIGTERM drain (the reference dbnode's graceful shutdown
@@ -236,11 +241,14 @@ def run_node(source, start_mediator: bool | None = None,
     from m3_tpu.instrument.procstats import install_process_collector
 
     install_process_collector(registry, scope)
-    tracer = None
-    if cfg.coordinator is not None and cfg.coordinator.tracing:
-        from m3_tpu.instrument.tracing import Tracer
+    # One span mechanism (instrument/tracing.py): the node's tracer is
+    # always there and is the process's tracer; it records while the
+    # operator's coordinator.tracing is set or a profile is captured.
+    from m3_tpu.instrument import tracing
 
-        tracer = Tracer()
+    tracer = tracing.Tracer(
+        enabled=cfg.coordinator is not None and cfg.coordinator.tracing)
+    tracing.install(tracer)
 
     from m3_tpu.storage.limits import LimitsOptions, QueryLimits
 

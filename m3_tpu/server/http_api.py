@@ -444,7 +444,9 @@ class _Handler(BaseHTTPRequestHandler):
         ``?name=<tracepoint>``              — spans of one tracepoint
         """
         tr = self.ctx.tracer
-        if tr is None:
+        if not tr.recording:
+            # off unless coordinator.tracing is set or a profile is
+            # being captured (instrument/tracing.py)
             return self._error(404, "no tracer configured")
         q = q or {}
         return self._json(200, traces_response(
@@ -471,40 +473,46 @@ class _Handler(BaseHTTPRequestHandler):
             k + b"=" + v for k, v in sorted(tags.items()) if k != b"__name__"
         ) + b"}"
 
-    def _ingest_tagged(self, docs, ts, vals) -> tuple[int, int]:
-        """Shared downsample-then-write tail of every write handler.
-        Returns (written, rejected): rejected = samples whose series
-        creation hit the new-series rate limit — the typed
-        back-pressure signal, surfaced so HTTP writers can back off.
+    def _write_span(self):
+        """The ``api.write`` root span, over a write handler's whole
+        body (the coordinator-ingest end of a cross-process trace:
+        downstream session/rpc hops join it through the bound context).
+        Its first child is ``api.write.decode`` — body read, parse,
+        Documents — after which the handler tags it ``n`` = samples."""
+        return self.ctx.tracer.start_span(Tracepoint.API_WRITE)
 
-        Opens the ``api.write`` root span (the coordinator-ingest end
-        of a cross-process trace: downstream session/rpc hops join it
-        through the bound context) and records the batch into the
-        windowed ingest-latency histogram."""
+    def _decode_span(self):
+        return self.ctx.tracer.start_span(Tracepoint.API_WRITE_DECODE)
+
+    def _ingest_tagged(self, docs, ts, vals) -> tuple[int, int]:
+        """Shared downsample-then-write tail of every write handler
+        (under the handler's ``api.write`` span).  Returns (written,
+        rejected): rejected = samples whose series creation hit the
+        new-series rate limit — the typed back-pressure signal,
+        surfaced so HTTP writers can back off.  Records the batch into
+        the windowed ingest-latency histogram."""
         ctx = self.ctx
         t0 = time.perf_counter()
-        with (ctx.tracer or NOOP_TRACER).start_span(
-                Tracepoint.API_WRITE, {"n": len(docs)}):
-            keep = np.ones(len(docs), bool)
-            if ctx.downsampler is not None:
-                keep = ctx.downsampler.write_batch(
-                    docs, np.asarray(ts, np.int64), np.asarray(vals)
-                )
-            idx = np.nonzero(keep)[0]
-            rejected = not_owned = 0
-            if len(idx):
-                res = ctx.db.write_tagged_batch(
-                    ctx.namespace,
-                    [docs[i] for i in idx],
-                    np.asarray(ts, np.int64)[idx],
-                    np.asarray(vals)[idx],
-                )
-                rejected = getattr(res, "rejected", 0)
-                # samples whose shard this node does not own
-                # (placement-scoped node fed directly): dropped, not
-                # written — the correct ingest path for a scoped
-                # cluster is the session
-                not_owned = getattr(res, "not_owned", 0)
+        keep = np.ones(len(docs), bool)
+        if ctx.downsampler is not None:
+            keep = ctx.downsampler.write_batch(
+                docs, np.asarray(ts, np.int64), np.asarray(vals)
+            )
+        idx = np.nonzero(keep)[0]
+        rejected = not_owned = 0
+        if len(idx):
+            res = ctx.db.write_tagged_batch(
+                ctx.namespace,
+                [docs[i] for i in idx],
+                np.asarray(ts, np.int64)[idx],
+                np.asarray(vals)[idx],
+            )
+            rejected = getattr(res, "rejected", 0)
+            # samples whose shard this node does not own
+            # (placement-scoped node fed directly): dropped, not
+            # written — the correct ingest path for a scoped
+            # cluster is the session
+            not_owned = getattr(res, "not_owned", 0)
         if ctx.hist_ingest is not None:
             ctx.hist_ingest.record(time.perf_counter() - t0)
         return int(len(idx)) - rejected - not_owned, rejected
@@ -514,18 +522,25 @@ class _Handler(BaseHTTPRequestHandler):
         (reference handler/prometheus/remote/write.go)."""
         from m3_tpu.server.prom_remote import parse_write_request
 
-        series = parse_write_request(self._body())
-        docs, ts, vals = [], [], []
-        for s in series:
-            sid = self._series_id(s.labels)
-            doc = Document.from_tags(sid, s.labels)
-            for t_nanos, v in s.samples:
-                docs.append(doc)
-                ts.append(t_nanos)
-                vals.append(v)
-        rejected = 0
-        if docs:
-            _, rejected = self._ingest_tagged(docs, ts, vals)
+        with self._write_span() as root:
+            with self._decode_span():
+                series = parse_write_request(self._body())
+                docs, ts, vals = [], [], []
+                for s in series:
+                    sid = self._series_id(s.labels)
+                    doc = Document.from_tags(sid, s.labels)
+                    for t_nanos, v in s.samples:
+                        docs.append(doc)
+                        ts.append(t_nanos)
+                        vals.append(v)
+            root.set_tag("n", len(docs))
+            rejected = 0
+            if docs:
+                _, rejected = self._ingest_tagged(docs, ts, vals)
+            self._prom_write_reply(rejected)
+        return None
+
+    def _prom_write_reply(self, rejected: int) -> None:
         # Prometheus remote-write clients back off on 429 — the typed
         # signal for new-series rate limiting; 2xx otherwise.  The 429
         # is deliberate despite the accepted subset having been
@@ -544,7 +559,6 @@ class _Handler(BaseHTTPRequestHandler):
             self.send_header("X-Rejected", str(rejected))
         self.send_header("Content-Length", "0")
         self.end_headers()
-        return None
 
     def _prom_remote_read(self, uq):
         """Prometheus remote read: snappy+protobuf ReadRequest →
@@ -596,24 +610,29 @@ class _Handler(BaseHTTPRequestHandler):
     def _write_json(self):
         """reference api/v1/json/write: one sample or a list of
         {tags: {..}, timestamp (unix s or nanos), value}."""
-        payload = json.loads(self._body())
-        samples = payload if isinstance(payload, list) else [payload]
-        docs, ts, vals = [], [], []
-        for s in samples:
-            tags = {k.encode(): v.encode() for k, v in s["tags"].items()}
-            docs.append(Document.from_tags(self._series_id(tags), tags))
-            t = s["timestamp"]
-            ts.append(int(t * 1e9) if t < 1e12 else int(t))
-            vals.append(float(s["value"]))
-        written, rejected = (self._ingest_tagged(docs, ts, vals)
-                             if docs else (0, 0))
-        body = {"status": "success", "written": written}
-        if rejected:
-            # partial acceptance: series churn hit the rate limit
-            body.update(status="partial", rejected=rejected,
-                        error="new-series rate limit exceeded")
-            return self._json(429, body)
-        return self._json(200, body)
+        with self._write_span() as root:
+            with self._decode_span():
+                payload = json.loads(self._body())
+                samples = payload if isinstance(payload, list) else [payload]
+                docs, ts, vals = [], [], []
+                for s in samples:
+                    tags = {k.encode(): v.encode()
+                            for k, v in s["tags"].items()}
+                    docs.append(
+                        Document.from_tags(self._series_id(tags), tags))
+                    t = s["timestamp"]
+                    ts.append(int(t * 1e9) if t < 1e12 else int(t))
+                    vals.append(float(s["value"]))
+            root.set_tag("n", len(docs))
+            written, rejected = (self._ingest_tagged(docs, ts, vals)
+                                 if docs else (0, 0))
+            body = {"status": "success", "written": written}
+            if rejected:
+                # partial acceptance: series churn hit the rate limit
+                body.update(status="partial", rejected=rejected,
+                            error="new-series rate limit exceeded")
+                return self._json(429, body)
+            return self._json(200, body)
 
     def _influx_write(self, q):
         """InfluxDB line-protocol write endpoint (reference
@@ -624,19 +643,27 @@ class _Handler(BaseHTTPRequestHandler):
         from m3_tpu.server.influx import parse_lines, points_to_writes
 
         precision = q.get("precision", ["ns"])[0]
-        points = parse_lines(self._body().decode(), precision,
-                             now_nanos=int(_time.time() * 1e9))
-        docs, ts, vals = points_to_writes(points)
-        written, rejected = (self._ingest_tagged(docs, ts, vals)
-                             if docs else (0, 0))
-        self.send_response(429 if rejected else 204)
-        self.send_header("X-Written", str(written))
-        if rejected:
-            self.send_header("X-Rejected", str(rejected))
-        self.send_header("Content-Length", "0")
-        self.end_headers()
+        with self._write_span() as root:
+            with self._decode_span():
+                points = parse_lines(self._body().decode(), precision,
+                                     now_nanos=int(_time.time() * 1e9))
+                docs, ts, vals = points_to_writes(points)
+            root.set_tag("n", len(docs))
+            written, rejected = (self._ingest_tagged(docs, ts, vals)
+                                 if docs else (0, 0))
+            self.send_response(429 if rejected else 204)
+            self.send_header("X-Written", str(written))
+            if rejected:
+                self.send_header("X-Rejected", str(rejected))
+            self.send_header("Content-Length", "0")
+            self.end_headers()
 
     def _query(self, is_range: bool, q):
+        with self.ctx.tracer.start_span(
+                Tracepoint.API_QUERY_RANGE, {"range": is_range}):
+            return self._query_traced(is_range, q)
+
+    def _query_traced(self, is_range: bool, q):
         query = q["query"][0]
         if is_range:
             start = _parse_time(q["start"][0])
@@ -660,6 +687,11 @@ class _Handler(BaseHTTPRequestHandler):
         except Exception as e:  # noqa: BLE001 — observed, then re-raised
             ctx.observe_query("promql", query, dl, error=e)
             raise
+        with ctx.tracer.start_span(Tracepoint.API_QUERY_RENDER):
+            return self._render_block(block, is_range, query, dl)
+
+    def _render_block(self, block, is_range: bool, query: str, dl):
+        ctx = self.ctx
         result = []
         for i, meta in enumerate(block.series):
             values = [
@@ -735,7 +767,7 @@ class ApiContext:
         self.namespace = namespace
         self.downsampler = downsampler
         self.registry = registry
-        self.tracer = tracer
+        self.tracer = tracer if tracer is not None else NOOP_TRACER
         self.migrator = migrator  # storage.migration.ShardMigrator | None
         self.checkpointer = checkpointer  # aggregator checkpoint driver
         self.selfmon = selfmon  # instrument.selfmon.SelfMonitor | None

@@ -26,6 +26,8 @@ from __future__ import annotations
 import struct
 from dataclasses import dataclass, field
 
+from m3_tpu.instrument import tracing
+from m3_tpu.instrument.tracing import Tracepoint
 from m3_tpu.server import snappy
 
 # ---------------------------------------------------------------------------
@@ -147,11 +149,13 @@ def _parse_timeseries(data: bytes) -> PromTimeSeries:
 
 def parse_write_request(body: bytes) -> list[PromTimeSeries]:
     """snappy-compressed WriteRequest → series list."""
-    raw = snappy.decompress(body)
+    with tracing.span(Tracepoint.API_WRITE_SNAPPY):
+        raw = snappy.decompress(body)
     out = []
-    for fnum, _wt, val in _fields(raw):
-        if fnum == 1:
-            out.append(_parse_timeseries(val))
+    with tracing.span(Tracepoint.API_WRITE_PROTOBUF):
+        for fnum, _wt, val in _fields(raw):
+            if fnum == 1:
+                out.append(_parse_timeseries(val))
     return out
 
 

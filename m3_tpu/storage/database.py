@@ -50,6 +50,7 @@ from m3_tpu.persist.fs import (
 from m3_tpu.persist import quarantine as quar
 from m3_tpu.persist import snapshot as snap
 from m3_tpu.instrument import logger
+from m3_tpu.instrument import tracing
 from m3_tpu.instrument.tracing import Tracepoint
 from m3_tpu.storage.limits import NO_LIMITS, NewSeriesLimiter, QueryLimits
 from m3_tpu.storage.buffer import ShardBuffer, dedupe_last_write_wins
@@ -556,14 +557,16 @@ class Namespace:
         The index only learns documents whose series were ACCEPTED —
         rate-limited churn must not grow the reverse index either (that
         is the unbounded-memory failure the limit exists to stop)."""
-        res = self.write_batch([d.id for d in docs], ts, vals, now_nanos)
-        if res.accepted is None:
-            self.index.write_batch(list(docs), ts)
-        else:
-            acc = res.accepted
-            kept = [d for d, a in zip(docs, acc) if a]
-            if kept:
-                self.index.write_batch(kept, ts[acc])
+        with tracing.span(Tracepoint.DB_BUFFER_WRITE):
+            res = self.write_batch([d.id for d in docs], ts, vals, now_nanos)
+        with tracing.span(Tracepoint.DB_INDEX_WRITE):
+            if res.accepted is None:
+                self.index.write_batch(list(docs), ts)
+            else:
+                acc = res.accepted
+                kept = [d for d, a in zip(docs, acc) if a]
+                if kept:
+                    self.index.write_batch(kept, ts[acc])
         return res
 
     def query_ids(self, q: Query, start: int, end: int,
@@ -699,7 +702,11 @@ class Database:
         # (shard.go RLock ladders); here every operation is already a
         # whole-batch array program, so one coarse lock adds no
         # meaningful serialization beyond what the batched design has.
-        self._mu = threading.RLock()
+        # Every acquisition stands under a db.lock.wait span: with
+        # several requests in flight, most of a request's time can be
+        # the wait for this lock, which no span opened inside it sees.
+        self._mu = tracing.SpanLock(
+            threading.RLock(), Tracepoint.DB_LOCK_WAIT, self.tracer)
         Path(self.opts.root).mkdir(parents=True, exist_ok=True)
         from m3_tpu.storage.block_cache import BlockCache
 
@@ -875,14 +882,16 @@ class Database:
             # enqueue - commit_log.go:716).  Bootstrap replay then
             # re-admits exactly the accepted set, bypassing the limiter.
             if self.commitlog is not None:
-                if res.accepted is None:
-                    self.commitlog.write_batch(list(ids), ts, vals,
-                                               namespace=namespace.encode())
-                else:
-                    acc = res.accepted
-                    self.commitlog.write_batch(
-                        [sid for sid, a in zip(ids, acc) if a],
-                        ts[acc], vals[acc], namespace=namespace.encode())
+                with self.tracer.start_span(Tracepoint.DB_COMMITLOG_WRITE):
+                    if res.accepted is None:
+                        self.commitlog.write_batch(
+                            list(ids), ts, vals,
+                            namespace=namespace.encode())
+                    else:
+                        acc = res.accepted
+                        self.commitlog.write_batch(
+                            [sid for sid, a in zip(ids, acc) if a],
+                            ts[acc], vals[acc], namespace=namespace.encode())
             if self._hist_write is not None:
                 self._hist_write.record(_time.perf_counter() - t0)
             return res
@@ -918,18 +927,19 @@ class Database:
                 # index documents (the reference's commitlog entries carry
                 # the series metadata for the same reason).  Only the
                 # ACCEPTED samples are logged - see write_batch.
-                if res.accepted is None:
-                    kept = list(docs)
-                    kts, kvs = ts, vals
-                else:
-                    kept = [d for d, a in zip(docs, res.accepted) if a]
-                    kts, kvs = ts[res.accepted], vals[res.accepted]
-                if kept:
-                    self.commitlog.write_batch(
-                        [d.id for d in kept], kts, kvs,
-                        namespace=namespace.encode(),
-                        annotations=[encode_tags(d) for d in kept],
-                    )
+                with self.tracer.start_span(Tracepoint.DB_COMMITLOG_WRITE):
+                    if res.accepted is None:
+                        kept = list(docs)
+                        kts, kvs = ts, vals
+                    else:
+                        kept = [d for d, a in zip(docs, res.accepted) if a]
+                        kts, kvs = ts[res.accepted], vals[res.accepted]
+                    if kept:
+                        self.commitlog.write_batch(
+                            [d.id for d in kept], kts, kvs,
+                            namespace=namespace.encode(),
+                            annotations=[encode_tags(d) for d in kept],
+                        )
             if self._hist_write is not None:
                 self._hist_write.record(_time.perf_counter() - t0)
             return res

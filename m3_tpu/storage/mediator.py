@@ -20,7 +20,7 @@ import threading
 import time
 from typing import Callable, Optional
 
-from m3_tpu.instrument import logger
+from m3_tpu.instrument import logger, tracing
 from m3_tpu.storage.database import Database
 
 _LOG = logger("storage.mediator")
@@ -121,7 +121,7 @@ class Mediator:
         """One maintenance pass: tick (seal+flush) every call, snapshot and
         cleanup on their cadence (mediator.go:284 ongoingTick + :318
         runFileSystemProcesses)."""
-        with self._lock:
+        with tracing.span(tracing.Tracepoint.MEDIATOR_RUN_ONCE), self._lock:
             t0 = time.monotonic()
             now = self.clock() if now_nanos is None else now_nanos
             stats: dict = {"tick": self.db.tick(now)}

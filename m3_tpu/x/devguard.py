@@ -65,6 +65,7 @@ import os
 import threading
 from typing import Callable, Dict
 
+from m3_tpu.instrument import tracing
 from m3_tpu.x import fault
 from m3_tpu.x.breaker import BreakerOpenError, breaker_for
 
@@ -286,6 +287,17 @@ def transfer_point(stage: str) -> None:
 
 def run_guarded(stage: str, primary: Callable[[], object],
                 fallback: Callable[[], object] | None = None):
+    """:func:`_run_guarded` under the span ``device.<stage>``: the HOST
+    time of one guarded device call, fallback included (staging,
+    dispatch, any blocking transfer — not the device's own time, which
+    the profiler's trace has).  Every device entry point of the served
+    path passes here, so this one span sees every dispatch."""
+    with tracing.span(tracing.Tracepoint.DEVICE + stage):
+        return _run_guarded(stage, primary, fallback)
+
+
+def _run_guarded(stage: str, primary: Callable[[], object],
+                 fallback: Callable[[], object] | None = None):
     """``primary()`` behind the stage's device guard.
 
     Closed breaker (or no fallback): faultpoints fire, ``primary``
