@@ -55,6 +55,7 @@ from m3_tpu.instrument.tracing import NOOP_TRACER, Tracepoint, traces_response
 from m3_tpu.query.engine import Engine
 from m3_tpu.query.fanout import FederatedStorage, PartialResultError
 from m3_tpu.query.storage_adapter import DatabaseStorage
+from m3_tpu.server.prom_remote import SeriesCache, decode_write_request
 from m3_tpu.storage.database import Database, ShardNotOwnedError
 from m3_tpu.storage.limits import QueryLimitExceeded
 from m3_tpu.x import deadline as xdeadline
@@ -486,27 +487,25 @@ class _Handler(BaseHTTPRequestHandler):
 
     def _ingest_tagged(self, docs, ts, vals) -> tuple[int, int]:
         """Shared downsample-then-write tail of every write handler
-        (under the handler's ``api.write`` span).  Returns (written,
-        rejected): rejected = samples whose series creation hit the
-        new-series rate limit — the typed back-pressure signal,
-        surfaced so HTTP writers can back off.  Records the batch into
-        the windowed ingest-latency histogram."""
+        (under the handler's ``api.write`` span); ``ts`` and ``vals``
+        are arrays or lists.  Returns (written, rejected): rejected =
+        samples whose series creation hit the new-series rate limit —
+        the typed back-pressure signal, surfaced so HTTP writers can
+        back off.  Records the batch into the windowed ingest-latency
+        histogram."""
         ctx = self.ctx
         t0 = time.perf_counter()
-        keep = np.ones(len(docs), bool)
+        ts = np.asarray(ts, np.int64)
+        vals = np.asarray(vals)
         if ctx.downsampler is not None:
-            keep = ctx.downsampler.write_batch(
-                docs, np.asarray(ts, np.int64), np.asarray(vals)
-            )
-        idx = np.nonzero(keep)[0]
+            keep = ctx.downsampler.write_batch(docs, ts, vals)
+            if not keep.all():
+                idx = np.nonzero(keep)[0]
+                docs, ts, vals = [docs[i] for i in idx], ts[idx], vals[idx]
+        n = len(docs)
         rejected = not_owned = 0
-        if len(idx):
-            res = ctx.db.write_tagged_batch(
-                ctx.namespace,
-                [docs[i] for i in idx],
-                np.asarray(ts, np.int64)[idx],
-                np.asarray(vals)[idx],
-            )
+        if n:
+            res = ctx.db.write_tagged_batch(ctx.namespace, docs, ts, vals)
             rejected = getattr(res, "rejected", 0)
             # samples whose shard this node does not own
             # (placement-scoped node fed directly): dropped, not
@@ -515,24 +514,20 @@ class _Handler(BaseHTTPRequestHandler):
             not_owned = getattr(res, "not_owned", 0)
         if ctx.hist_ingest is not None:
             ctx.hist_ingest.record(time.perf_counter() - t0)
-        return int(len(idx)) - rejected - not_owned, rejected
+        return n - rejected - not_owned, rejected
 
     def _prom_remote_write(self):
         """Prometheus remote write: snappy+protobuf WriteRequest
-        (reference handler/prometheus/remote/write.go)."""
-        from m3_tpu.server.prom_remote import parse_write_request
-
+        (reference handler/prometheus/remote/write.go), decoded as
+        columns with the node's series-identity cache."""
+        ctx = self.ctx
         with self._write_span() as root:
-            with self._decode_span():
-                series = parse_write_request(self._body())
-                docs, ts, vals = [], [], []
-                for s in series:
-                    sid = self._series_id(s.labels)
-                    doc = Document.from_tags(sid, s.labels)
-                    for t_nanos, v in s.samples:
-                        docs.append(doc)
-                        ts.append(t_nanos)
-                        vals.append(v)
+            with self._decode_span() as decode:
+                docs, ts, vals, n_series, n_hits = decode_write_request(
+                    self._body(), ctx.series_cache)
+                decode.set_tag("series", n_series)
+                decode.set_tag("hits", n_hits)
+            ctx.count_decode(n_series, n_hits)
             root.set_tag("n", len(docs))
             rejected = 0
             if docs:
@@ -791,12 +786,22 @@ class ApiContext:
         # by the deadline's phase accumulator, eval = the rest).
         self.hist_ingest = self.hist_query = None
         self._hist_query_phase = {}
+        # remote write: label-field bytes -> Document, built as the
+        # JSON handler builds its (one per node, shared by the handler
+        # threads), and its hit/miss counters
+        self.series_cache = SeriesCache(
+            lambda tags: Document.from_tags(_Handler._series_id(tags), tags))
+        self._decode_hits = self._decode_misses = None
         if registry is not None:
             # under the node's metrics prefix (assembly passes its
             # prefixed scope) so the series merge across a fleet
             base = (metrics_scope if metrics_scope is not None
                     else registry.scope(""))
             self.hist_ingest = base.scope("ingest").histogram("seconds")
+            self._decode_hits = base.scope("ingest").counter(
+                "decode_cache_hits")
+            self._decode_misses = base.scope("ingest").counter(
+                "decode_cache_misses")
             qscope = base.scope("query")
             self.hist_query = qscope.histogram("seconds")
             self._hist_query_phase = {
@@ -821,6 +826,13 @@ class ApiContext:
         from m3_tpu.query.graphite import GraphiteEngine, GraphiteStorage
 
         self.graphite = GraphiteEngine(GraphiteStorage(db, namespace))
+
+    def count_decode(self, n_series: int, n_hits: int) -> None:
+        """One remote-write body's series, by whether the series cache
+        knew their label bytes."""
+        if self._decode_hits is not None:
+            self._decode_hits.inc(n_hits)
+            self._decode_misses.inc(n_series - n_hits)
 
     def engine_for(self, namespace: str | None) -> Engine:
         """The engine serving one namespace: the default request path

@@ -35,8 +35,8 @@ sys.path.insert(0, str(REPO))
 
 from benchmark import selftest, stats  # noqa: E402
 from benchmark.reducers import (  # noqa: E402
-    node_span_ms, node_span_slice_pct, node_span_unnamed_pct, node_spans,
-    trace_idle_unnamed_pct, tracefile,
+    node_span_ms, node_span_slice_pct, node_span_tag_pct,
+    node_span_unnamed_pct, node_spans, trace_idle_unnamed_pct, tracefile,
 )
 
 
@@ -494,6 +494,27 @@ class TestReducers:
             cell, {"spans": ["db.lock.wait", "db.writeBatch"]}) == \
             pytest.approx(100 * 5 / 30)
 
+    def test_tag_share_sums_over_the_spans_that_carry_both_tags(self):
+        rows = [(1, None, "api.write", 0.0, 10.0, {"n": 2000}),
+                (2, 1, "api.write.decode", 0.0, 1.0,
+                 {"series": 1000, "hits": 0}),
+                (3, None, "api.write", 11.0, 12.0, {"n": 2000}),
+                (4, 3, "api.write.decode", 11.0, 11.5,
+                 {"series": 1000, "hits": 900}),
+                (5, None, "api.write", 13.0, 14.0, {"n": 1}),
+                (6, 5, "api.write.decode", 13.0, 13.5, {}),    # a JSON write
+                (7, None, "api.write", 31.0, 32.0, {"n": 9}),  # past the slice
+                (8, 7, "api.write.decode", 31.0, 31.5,
+                 {"series": 9, "hits": 9})]
+        params = {"spans": ["api.write.decode"], "tag": "hits", "of": "series"}
+        cell = _cell(_FakeTracer(node_spans.build(rows)))
+        assert node_span_tag_pct.read(cell, params) == pytest.approx(45.0)
+        # a program whose decode spans carry no such tags: nothing to read
+        assert node_span_tag_pct.read(
+            _cell(_FakeTracer(node_spans.build(rows[4:6]))), params) is None
+        assert node_span_tag_pct.read(
+            cell, {**params, "spans": ["db.lock.wait"]}) is None
+
     def test_only_roots_wholly_inside_the_slice_count(self):
         cell = _cell(_FakeTracer(_tree()), slice_=(0.5, 30.0))
         spans = node_spans.load(cell)
@@ -518,6 +539,9 @@ class TestReducers:
             cell, {"roots": ["api.write"]}) is None
         assert node_span_slice_pct.read(
             cell, {"spans": ["runtime.gc"]}) is None
+        assert node_span_tag_pct.read(cell, {
+            "spans": ["api.write.decode"], "tag": "hits",
+            "of": "series"}) is None
         assert trace_idle_unnamed_pct.read(cell, {}) is None
 
     def test_overflow_before_the_slice_is_still_a_whole_account(self):
@@ -685,7 +709,7 @@ class TestNames:
                                or pat[:-1].startswith(n) for n in names), pat
                 else:
                     assert pat in names, (path.name, pat)
-        assert seen >= 25
+        assert seen >= 27
         # what the reducers themselves name
         for n in ("api.write", "api.queryRange", "mediator.runOnce",
                   "runtime.gc"):
@@ -723,16 +747,18 @@ def test_new_per_layer_entries_are_well_formed():
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     new = [m for m in bench["per_layer"] if m["source"] == "program_span"
            and m["name"] != "maintain_ms_per_pass"]
-    assert len(new) == 25
+    assert len(new) == 27
     layers = {m["layer"] for m in bench["per_layer"]
               if m not in new}
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     for m in new:
-        assert m["layer"] in layers and m["better"] == "lower"
+        hit_share = m["name"].startswith("decode_cache_hit_pct")
+        assert m["layer"] in layers
+        assert m["better"] == ("higher" if hit_share else "lower")
         (cell,) = m["workloads"]
         assert cell in e2e[m["moves"]]["workloads"]
         spec = json.loads((REPO / "benchmark" / "metrics"
                            / (m["name"] + ".json")).read_text())
         assert spec["reducer"] in ("node_span_ms", "node_span_unnamed_pct",
-                                   "node_span_slice_pct",
+                                   "node_span_slice_pct", "node_span_tag_pct",
                                    "trace_idle_unnamed_pct")
