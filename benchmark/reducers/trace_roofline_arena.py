@@ -1,0 +1,20 @@
+"""``trace_roofline`` with the byte counts of ``benchmark/roofline_
+arena.py``: the least time the chip could take for the bytes the arena
+programs' work must move, over the device time their executions took in
+the traced slice.  Nothing to read -> None, never 0."""
+
+from benchmark import roofline, roofline_arena
+
+
+def read(cell, params):
+    tr = cell.trace_events
+    if tr is None:
+        return None
+    seconds, calls = tr.program_seconds(params["programs"])
+    if not calls or seconds <= 0:
+        return None
+    nbytes = getattr(roofline_arena, params["bytes"])(cell, calls)
+    if not nbytes:
+        return None
+    peak = roofline.peak(cell.device_kind)["hbm_bytes_per_s"]
+    return 100.0 * (nbytes / peak) / seconds

@@ -36,6 +36,8 @@ import numpy as np
 
 from m3_tpu.aggregator.arena import make_arenas
 from m3_tpu.core.hash import shard_for
+from m3_tpu.instrument import tracing
+from m3_tpu.instrument.tracing import Tracepoint
 from m3_tpu.metrics.aggregation import AggregationID, AggregationType
 from m3_tpu.metrics.policy import StoragePolicy
 from m3_tpu.metrics.transformation import TransformationType
@@ -95,6 +97,20 @@ class AggregatorOptions:
     # series creation exceeds it are dropped with a typed counter —
     # churn degrades gracefully instead of filling the slot maps.
     new_series_limit_per_sec: float = 0.0
+    # The aggregation types a batch with the DEFAULT aggregation id
+    # gets, per metric type, as ((MetricType, AggregationID), ...)
+    # (reference aggregation/types_options.go defaultCounter/Timer/
+    # GaugeAggregationTypes); a type not listed keeps upstream's
+    # defaults (metrics/aggregation.py).
+    default_aggregations: tuple = ()
+
+    def aggregation_for(self, mt: MetricType,
+                        agg_id: AggregationID) -> AggregationID:
+        if agg_id.is_default():
+            for t, default in self.default_aggregations:
+                if t is mt:
+                    return default
+        return agg_id
 
 
 @dataclasses.dataclass
@@ -167,6 +183,12 @@ class MetricMap:
             return (self._native_ids[slot]
                     if slot < len(self._native_ids) else None)
         return self._ids[slot] if slot < len(self._ids) else None
+
+    def id_table(self) -> List[bytes | None]:
+        """The slot -> id list itself, for bulk lookups (a flush
+        handler resolving a drained window's slots); not to be
+        changed."""
+        return self._native_ids if self._native is not None else self._ids
 
     def resolve(self, ids: Sequence[bytes], agg_id: AggregationID,
                 mt: MetricType, tail_sig: int = 0) -> np.ndarray:
@@ -449,6 +471,20 @@ class MetricList:
         (map.go:149) where this engine keys slots on (id, mask); two
         rules matching one output ID with different tails must be
         rewritten as two rollup IDs."""
+        slots, acc = self.resolve_batch(mt, ids, agg_id, pipeline)
+        if acc is not None:
+            values = np.asarray(values)[acc]
+            times = np.asarray(times)[acc]
+        self.add_batch_slots(mt, slots, values, times)
+        return acc  # None = everything accepted
+
+    def resolve_batch(self, mt: MetricType, ids: Sequence[bytes],
+                      agg_id: AggregationID = AggregationID.DEFAULT,
+                      pipeline=None):
+        """The host half of ``add_batch``: ids -> slots, the batch's
+        pipeline tail registered on them.  Returns (slots of the
+        accepted samples, accepted mask or None = all): feed the
+        accepted samples to ``add_batch_slots``."""
         sig, key_ops = 0, ()
         if pipeline is not None and not pipeline.is_empty():
             key_ops = self._validate_tail(pipeline)
@@ -468,17 +504,12 @@ class MetricList:
                 if s >= 0:
                     self._pipelines[(mt, int(s))] = key_ops
         rej = slots < 0
-        acc = None
-        if rej.any():
-            # Rate-limited series creations: drop those samples with a
-            # typed counter (entry.go errWriteNewMetricRateLimitExceeded).
-            self.new_series_rejected += int(rej.sum())
-            acc = ~rej
-            slots = slots[acc]
-            values = np.asarray(values)[acc]
-            times = np.asarray(times)[acc]
-        self.add_batch_slots(mt, slots, values, times)
-        return acc  # None = everything accepted
+        if not rej.any():
+            return slots, None
+        # Rate-limited series creations: drop those samples with a
+        # typed counter (entry.go errWriteNewMetricRateLimitExceeded).
+        self.new_series_rejected += int(rej.sum())
+        return slots[~rej], ~rej
 
     @staticmethod
     def _validate_tail(pipeline) -> tuple:
@@ -974,7 +1005,7 @@ class AggregatorShard:
         lists = list(self.lists.values())
         if not lists:
             return
-        acc = lists[0].add_batch(mt, ids, values, times, agg_id)
+        acc = self._add(lists[0], mt, ids, values, times, agg_id)
         rest = lists[1:]
         if not rest:
             return
@@ -989,7 +1020,19 @@ class AggregatorShard:
         ctx = lim.bypass() if lim is not None else contextlib.nullcontext()
         with ctx:
             for ml in rest:
-                ml.add_batch(mt, ids, values, times, agg_id)
+                self._add(ml, mt, ids, values, times, agg_id)
+
+    @staticmethod
+    def _add(ml: MetricList, mt, ids, values, times, agg_id):
+        """``ml.add_batch`` with its two halves under their spans."""
+        with tracing.span(Tracepoint.AGG_RESOLVE):
+            slots, acc = ml.resolve_batch(mt, ids, agg_id)
+        with tracing.span(Tracepoint.AGG_ADD):
+            if acc is not None:
+                values = np.asarray(values)[acc]
+                times = np.asarray(times)[acc]
+            ml.add_batch_slots(mt, slots, values, times)
+        return acc
 
     def add_timed_batch(self, mt, ids, values, times,
                         agg_id=AggregationID.DEFAULT,
@@ -1103,6 +1146,7 @@ class Aggregator:
         return self.shards[self.shard_index(mid)]
 
     def add_untimed_batch(self, mt, ids, values, times, agg_id=AggregationID.DEFAULT):
+        agg_id = self.opts.aggregation_for(mt, agg_id)
         if len(self.shards) == 1:
             self.shards[0].add_batch(mt, ids, values, times, agg_id)
             return
@@ -1122,6 +1166,7 @@ class Aggregator:
         aggregator.go:77 AddTimed; see MetricList.add_timed_batch)."""
         values = np.asarray(values, np.float64)
         times = np.asarray(times, np.int64)
+        agg_id = self.opts.aggregation_for(mt, agg_id)
         if len(self.shards) == 1:
             return self.shards[0].add_timed_batch(
                 mt, ids, values, times, agg_id, now_nanos=now_nanos)

@@ -31,10 +31,12 @@ from __future__ import annotations
 
 import functools
 import json
-from typing import Callable, Dict, List, Tuple
+from typing import Callable, Dict, Tuple
 
 from m3_tpu.aggregator.engine import Aggregator, FlushedMetric, MetricList
 from m3_tpu.cluster.kv import KVStore, LeaderElection
+from m3_tpu.instrument import tracing
+from m3_tpu.instrument.tracing import Tracepoint
 
 FlushHandler = Callable[[MetricList, FlushedMetric], None]
 
@@ -135,21 +137,22 @@ class FlushManager:
         handler, then persist the new flush times.  Follower: shadow-
         consume (no emission) up to the leader's persisted times.
         """
+        with tracing.span(Tracepoint.AGG_FLUSH) as root:
+            role = self._tick(now_nanos)
+            root.set_tag("role", role)
+        return role
+
+    def _tick(self, now_nanos: int) -> str:
         if self.election.campaign(now_nanos):
-            results: List[FlushedMetric] = []
-
-            def emit(ml: MetricList, fm: FlushedMetric) -> None:
-                results.append(fm)
-                if self.flush_handler is not None:
-                    self.flush_handler(ml, fm)
-
             # Route through the aggregator's forward sink: multi-stage
             # rollup outputs must land on the NEXT stage's owning shard,
             # not re-ingest into their source shard's list.
-            for sh in self.aggregator.shards:
-                sh.consume(now_nanos, emit,
-                           forward_sink=self.aggregator._route_forwards)
-            self._write_times(self._collect_times())
+            with tracing.span(Tracepoint.AGG_CONSUME):
+                for sh in self.aggregator.shards:
+                    sh.consume(now_nanos, self.flush_handler,
+                               forward_sink=self.aggregator._route_forwards)
+            with tracing.span(Tracepoint.AGG_FLUSH_PERSIST):
+                self._write_times(self._collect_times())
             return "leader"
 
         # Follower: drain to the leader's watermark, discarding output

@@ -498,9 +498,90 @@ class CoordinatorConfig:
 
 
 @dataclasses.dataclass
+class AggregatorConfig:
+    """The standalone aggregator service (reference
+    ``cmd/services/m3aggregator/config``): a rawtcp front door, the
+    arenas, a leader-elected flush manager and the m3msg topic its
+    flushes are published to (``server/assembly.run_aggregator``).  A
+    node file with this section is an aggregator process: it serves no
+    database and no coordinator.
+
+    ``capacity`` is metric slots per metric type per aggregator shard
+    (the device arenas are fixed-size); ``default_aggregations`` maps
+    ``counter`` / ``gauge`` / ``timer`` to the aggregation type names a
+    sample with no aggregation id of its own gets (types that are not
+    valid for the metric type are left out, as upstream's
+    ``IsValidFor*``; a type not listed keeps upstream's defaults).
+    ``flush_interval`` is the cadence of flush-manager ticks on the
+    service's clock; the topic has one shard per aggregator shard;
+    consumers connect to ``msg_listen_port`` and introduce themselves
+    as ``consumer_service``; an unacked message is redelivered after
+    ``msg_retry_after``."""
+
+    listen_host: str = "127.0.0.1"
+    listen_port: int = 0            # rawtcp ingest; 0 = ephemeral
+    num_shards: int = 1
+    capacity: int = 1 << 16
+    num_windows: int = 2
+    # timer samples buffered per window before the arena grows (the
+    # downsampler's default; a deployment without timers keeps it small:
+    # every drain sorts the buffer, used or not)
+    timer_sample_capacity: int = 1 << 18
+    storage_policies: list = dataclasses.field(
+        default_factory=lambda: ["10s:2d"])
+    default_aggregations: dict = dataclasses.field(default_factory=dict)
+    instance_id: str = "aggregator-0"
+    lease: str = "30s"
+    flush_interval: str = "1s"
+    topic: str = "aggregated_metrics"
+    consumer_service: str = "coordinator"
+    msg_listen_port: int = 0
+    msg_retry_after: str = "5s"
+    metrics_listen_port: Optional[int] = None  # None = no /metrics
+    tracing: bool = False
+
+    def validate(self, errs: list) -> None:
+        from m3_tpu.metrics.aggregation import AggregationType
+        from m3_tpu.metrics.policy import StoragePolicy
+
+        for f in ("listen_port", "msg_listen_port", "metrics_listen_port"):
+            v = getattr(self, f)
+            if v is not None and not (0 <= v < 65536):
+                errs.append(f"aggregator.{f}: out of range")
+        for f in ("num_shards", "capacity", "num_windows",
+                  "timer_sample_capacity"):
+            if getattr(self, f) < 1:
+                errs.append(f"aggregator.{f}: must be >= 1")
+        for f in ("lease", "flush_interval", "msg_retry_after"):
+            try:
+                parse_duration(getattr(self, f))
+            except ConfigError as e:
+                errs.append(f"aggregator.{f}: {e}")
+        if not self.storage_policies:
+            errs.append("aggregator.storage_policies: at least one")
+        for sp in self.storage_policies:
+            try:
+                StoragePolicy.parse(sp)
+            except ValueError as e:
+                errs.append(f"aggregator.storage_policies: {e}")
+        for mt, names in self.default_aggregations.items():
+            if mt not in ("counter", "gauge", "timer"):
+                errs.append(f"aggregator.default_aggregations: {mt!r} is "
+                            "not counter, gauge or timer")
+            for n in names:
+                if n not in AggregationType.__members__:
+                    errs.append(f"aggregator.default_aggregations.{mt}: "
+                                f"unknown aggregation type {n!r}")
+        for f in ("instance_id", "topic", "consumer_service"):
+            if not getattr(self, f):
+                errs.append(f"aggregator.{f}: must be non-empty")
+
+
+@dataclasses.dataclass
 class NodeConfig:
     """One process = db + coordinator (+ mediator), the reference's
-    combined dbnode/coordinator configuration (config.go:102-107)."""
+    combined dbnode/coordinator configuration (config.go:102-107) — or,
+    with an ``aggregator`` section, one standalone aggregator."""
 
     db: DBConfig = dataclasses.field(default_factory=DBConfig)
     coordinator: Optional[CoordinatorConfig] = dataclasses.field(
@@ -513,6 +594,7 @@ class NodeConfig:
     selfmon: SelfmonConfig = dataclasses.field(default_factory=SelfmonConfig)
     controller: ControllerConfig = dataclasses.field(
         default_factory=ControllerConfig)
+    aggregator: Optional[AggregatorConfig] = None
     metrics_prefix: str = "m3tpu"
 
     def validate(self) -> None:
@@ -526,6 +608,8 @@ class NodeConfig:
         self.disk.validate(errs)
         self.selfmon.validate(errs)
         self.controller.validate(errs)
+        if self.aggregator is not None:
+            self.aggregator.validate(errs)
         if self.controller.enabled and not self.selfmon.enabled:
             errs.append(
                 "controller.enabled: requires selfmon.enabled (the burn "
@@ -549,10 +633,11 @@ _NESTED = {
     "disk": DiskConfig,
     "selfmon": SelfmonConfig,
     "controller": ControllerConfig,
+    "aggregator": AggregatorConfig,
 }
 # Optional nested sections: an explicit `field: null` disables the
 # subsystem (yields None) instead of instantiating defaults.
-_NESTED_OPTIONAL = {"coordinator"}
+_NESTED_OPTIONAL = {"coordinator", "aggregator"}
 
 
 def _build(cls, data, path: str):

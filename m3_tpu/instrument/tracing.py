@@ -90,8 +90,19 @@ class Tracepoint:
     API_WRITE_DECODE = "api.write.decode"        # body, parse, Documents
     API_WRITE_SNAPPY = "api.write.decode.snappy"      # remote write only
     API_WRITE_PROTOBUF = "api.write.decode.protobuf"  # remote write only
-    INGEST_TCP_BATCH = "ingest.tcp.batch"
+    # the aggregator's TCP front door (server/ingest_tcp.py): one root
+    # per frame from "received" to "acked", parented on the sender's
+    # span where an INGEST_TRACE preamble carried one
+    INGEST_FRAME = "ingest.frame"                # tag n: samples
+    INGEST_FRAME_DECODE = "ingest.frame.decode"
+    INGEST_QUEUE_WAIT = "ingest.queue.wait"      # enqueue -> worker
+    AGG_LOCK_WAIT = "aggregator.lock.wait"       # the sink's lock
+    AGG_RESOLVE = "aggregator.resolve"           # ids -> slots
+    AGG_ADD = "aggregator.add"                   # window routing, staging
+    AGG_FLUSH = "aggregator.flush"               # one flush-manager tick
     AGG_CONSUME = "aggregator.consume"
+    AGG_FLUSH_EMIT = "aggregator.flush.emit"     # slots -> ids, encode, publish
+    AGG_FLUSH_PERSIST = "flush.persist"          # flush times -> KV
     DOWNSAMPLE_LOCK_WAIT = "downsample.lock.wait"
     DOWNSAMPLE_MATCH = "downsample.match"        # per-doc rule match loop
     DOWNSAMPLE_ADD = "downsample.add"            # arena staging
@@ -251,7 +262,7 @@ class _NoopSpan:
 
 NOOP_SPAN = _NoopSpan()
 
-_UNSAMPLED = TraceContext(0, 0, sampled=False)
+UNSAMPLED = TraceContext(0, 0, sampled=False)
 
 
 class _UnsampledSpan:
@@ -269,7 +280,7 @@ class _UnsampledSpan:
         pass
 
     def __enter__(self):
-        self._token = _current.set(_UNSAMPLED)
+        self._token = _current.set(UNSAMPLED)
         return self
 
     def __exit__(self, *exc) -> bool:
@@ -380,6 +391,42 @@ class Tracer:
         stack = self._stack()
         if stack and stack[-1] is span:
             stack.pop()
+        self._keep(span)
+
+    # -- spans whose ends lie on different threads --------------------------
+
+    def reserve(self, parent: TraceContext | None = None
+                ) -> TraceContext | None:
+        """Ids for a span that one thread begins and another ends (a
+        frame from receipt to ack): bind the result around the work on
+        either thread and its spans parent on it; :meth:`record` puts
+        the span itself into the ring once its end is known.  None
+        when nothing records or ``parent`` says not sampled: bind
+        :data:`UNSAMPLED` then, so the work's spans stay out of the
+        ring instead of entering it as roots."""
+        if not self.recording or (parent is not None and not parent.sampled):
+            return None
+        return TraceContext(
+            parent.trace_id if parent is not None else self._ids(),
+            self._ids())
+
+    def record(self, name: str, start_ns: int, end_ns: int,
+               tags: dict | None = None, ctx: TraceContext | None = None,
+               parent: TraceContext | None = None) -> None:
+        """A finished span from times the caller observed (monotonic
+        ns): under the ids ``ctx`` that :meth:`reserve` gave, or with
+        fresh ids as a child of ``parent``.  No CPU time: the interval
+        belongs to no one thread."""
+        if ctx is None and parent is None:
+            return
+        self._keep(Span(
+            name=name,
+            trace_id=(ctx or parent).trace_id,
+            span_id=ctx.span_id if ctx is not None else self._ids(),
+            parent_id=parent.span_id if parent is not None else None,
+            start_ns=start_ns, end_ns=end_ns, tags=dict(tags or {})))
+
+    def _keep(self, span: Span) -> None:
         with self._lock:
             ring = self._ring
             ring.append(span)

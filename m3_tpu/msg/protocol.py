@@ -27,6 +27,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from m3_tpu.core.idbytes import PackedIds
 from m3_tpu.persist.digest import digest
 
 _HDR = struct.Struct("<IBI")
@@ -119,7 +120,8 @@ def recv_frame(sock: socket.socket) -> tuple[int, bytes] | None:
 class MetricBatch:
     """One ingest batch: parallel arrays + per-sample metric type.
 
-    metric_types: uint8 array (MetricType values); ids: list of bytes;
+    metric_types: uint8 array (MetricType values); ids: a sequence of
+    bytes (a list, or core.idbytes.PackedIds from the columnar reader);
     values/times: float64/int64 arrays; agg_id: compressed aggregation
     bitmask applied to the whole batch (0 = default per-type)."""
 
@@ -159,6 +161,84 @@ def decode_metric_batch(raw: bytes) -> MetricBatch:
     if pos != len(raw):
         raise ProtocolError("metric batch trailing bytes")
     return MetricBatch(mts, ids, values, times, agg_id)
+
+
+_TV = np.arange(16)
+
+
+def decode_metric_columns(raw: bytes) -> MetricBatch:
+    """``decode_metric_batch`` as columns: one pass over the records for
+    their offsets (a record's length is known only from its own idlen
+    field), then numpy for the types, times and values; the ids stay
+    where the frame has them, as ``PackedIds`` runs (no ``bytes`` object
+    per sample).  Same layout, same errors; the scalar reader stays as the
+    oracle (tests/test_aggregator_service.py holds the two equal)."""
+    n, agg_id = struct.unpack_from("<IQ", raw, 0)
+    starts = [0] * n
+    pos = 12
+    try:
+        for i in range(n):
+            starts[i] = pos
+            # type u8 + idlen u16 + time i64 + value f64 = 19, and the id
+            pos += 19 + raw[pos + 1] + (raw[pos + 2] << 8)
+    except IndexError:
+        raise ProtocolError("metric batch truncated") from None
+    if pos != len(raw):
+        raise ProtocolError("metric batch trailing bytes")
+    u8 = np.frombuffer(raw, np.uint8)
+    st = np.asarray(starts, np.int64)
+    idlens = u8[st + 1].astype(np.int64) | (u8[st + 2].astype(np.int64) << 8)
+    # (n, 16) bytes: time then value of every record, as two i64 columns
+    tv = u8[(st + 3 + idlens)[:, None] + _TV].view("<i8")
+    return MetricBatch(u8[st], PackedIds(u8, st + 3, idlens),
+                       tv[:, 1].copy().view(np.float64), tv[:, 0].copy(),
+                       agg_id)
+
+
+# -- aggregated batch codec (the aggregator's output over m3msg) -------------
+
+_AGG_HDR = struct.Struct("<BHqII")
+
+
+def encode_aggregated_batch(metric_type: int, policy: str, timestamp: int,
+                            ids, row_ids: np.ndarray, row_types: np.ndarray,
+                            values: np.ndarray) -> bytes:
+    """One m3msg payload of flushed aggregates (reference
+    aggregator/handler/writer protobuf ``AggregatedMetric``, batched):
+    the ids of the chunk's series once, then one row per (series,
+    aggregation type): index into the id table u32, type u8, value f64
+    bits; every row is at ``timestamp`` (the window's end) under
+    ``policy``."""
+    p = policy.encode()
+    lens = np.fromiter(map(len, ids), "<u2", len(ids))
+    return b"".join((
+        _AGG_HDR.pack(metric_type, len(p), timestamp, len(ids), len(values)),
+        p, lens.tobytes(), b"".join(ids),
+        np.ascontiguousarray(row_ids, "<u4").tobytes(),
+        np.ascontiguousarray(row_types, np.uint8).tobytes(),
+        np.ascontiguousarray(values, "<f8").tobytes()))
+
+
+def decode_aggregated_batch(raw: bytes):
+    """-> (metric_type, policy str, timestamp, ids list of bytes,
+    row_ids u32, row_types u8, values f64)."""
+    mt, lp, ts, n_ids, n_rows = _AGG_HDR.unpack_from(raw, 0)
+    pos = _AGG_HDR.size
+    policy = raw[pos:pos + lp].decode()
+    pos += lp
+    lens = np.frombuffer(raw, "<u2", n_ids, pos)
+    pos += 2 * n_ids
+    ends = pos + np.cumsum(lens, dtype=np.int64)
+    ids = [raw[a:b] for a, b in zip((ends - lens).tolist(), ends.tolist())]
+    pos = int(ends[-1]) if n_ids else pos
+    row_ids = np.frombuffer(raw, "<u4", n_rows, pos)
+    pos += 4 * n_rows
+    row_types = np.frombuffer(raw, np.uint8, n_rows, pos)
+    pos += n_rows
+    values = np.frombuffer(raw, "<f8", n_rows, pos)
+    if pos + 8 * n_rows != len(raw):
+        raise ProtocolError("aggregated batch trailing bytes")
+    return mt, policy, ts, ids, row_ids, row_types, values
 
 
 def encode_passthrough_batch(policy: str, ids, values, times) -> bytes:

@@ -18,6 +18,7 @@ the topic (topic/consumption_type.go).
 
 from __future__ import annotations
 
+import select
 import socket
 import socketserver
 import threading
@@ -166,6 +167,8 @@ class RemoteBusProducer:
 class RemoteBusConsumer:
     """Consumer edge: hello, then poll deliveries / send acks."""
 
+    frame_timeout_s = 60.0  # to finish reading a delivery that has begun
+
     def __init__(self, address, service: str, instance_id: str):
         self._lock = threading.Lock()
         self._sock = wire.connect(address)
@@ -182,19 +185,21 @@ class RemoteBusConsumer:
 
     def poll(self, timeout_s: float = 1.0, max_messages: int = 128):
         """Blocking read of up to max_messages deliveries within
-        timeout_s; returns list of (mid, shard, payload)."""
+        timeout_s; returns list of (mid, shard, payload).  The timeout
+        bounds the wait for a delivery to BEGIN: one that has begun is
+        read to its end (``frame_timeout_s``), however large — a
+        drained window's message is hundreds of KB, and giving up in
+        the middle of one would desync the stream."""
         out = []
         deadline = time.monotonic() + timeout_s
-        self._sock.settimeout(timeout_s)
         while len(out) < max_messages:
             remain = deadline - time.monotonic()
             if remain <= 0:
                 break
-            self._sock.settimeout(remain)
-            try:
-                frame = wire.recv_frame(self._sock)
-            except (socket.timeout, TimeoutError):
+            if not select.select([self._sock], [], [], remain)[0]:
                 break
+            self._sock.settimeout(self.frame_timeout_s)
+            frame = wire.recv_frame(self._sock)
             if frame is None:
                 break
             if frame[0] == wire.BUS_DELIVER:

@@ -13,6 +13,7 @@ import ctypes
 
 import numpy as np
 
+from m3_tpu.core.idbytes import PackedIds
 from m3_tpu.native._build import load_native
 
 _lib = None
@@ -24,7 +25,6 @@ def _load():
         return _lib
     lib = load_native("idmap.cc", "libidmap.so", ("-std=c++20",))
     u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
-    u64p = np.ctypeslib.ndpointer(np.uint64, flags="C_CONTIGUOUS")
     i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
     i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
 
@@ -35,7 +35,7 @@ def _load():
     lib.idmap_len.argtypes = [ctypes.c_void_p]
     lib.idmap_resolve_batch.restype = ctypes.c_int64
     lib.idmap_resolve_batch.argtypes = [
-        ctypes.c_void_p, u8p, u64p, ctypes.c_int64, ctypes.c_uint64,
+        ctypes.c_void_p, u8p, i64p, i64p, ctypes.c_int64, ctypes.c_uint64,
         i32p, i64p,
     ]
     lib.idmap_release.restype = ctypes.c_int32
@@ -59,18 +59,19 @@ class NativeIdMap:
 
     def resolve(self, ids, mask: int):
         """(slots int32 (n,), new_positions int64 (k,)) — find-or-create
-        for every id under the given aggregation mask.  Raises
+        for every id (a sequence of bytes, or a PackedIds whose buffer
+        is read in place) under the given aggregation mask.  Raises
         RuntimeError when capacity would be exceeded."""
         n = len(ids)
-        buf = np.frombuffer(b"".join(ids), dtype=np.uint8)
-        offsets = np.zeros(n + 1, np.uint64)
-        lens = np.fromiter(map(len, ids), np.uint64, n)
-        np.cumsum(lens, out=offsets[1:])
+        if not isinstance(ids, PackedIds):
+            ids = PackedIds.from_ids(ids)
         slots = np.empty(n, np.int32)
         new_idx = np.empty(n, np.int64)
         n_new = self._lib.idmap_resolve_batch(
-            self._h, buf if buf.size else np.zeros(1, np.uint8),
-            offsets, n, mask, slots, new_idx,
+            self._h, ids.buf if ids.buf.size else np.zeros(1, np.uint8),
+            np.ascontiguousarray(ids.starts, np.int64),
+            np.ascontiguousarray(ids.lens, np.int64), n, mask, slots,
+            new_idx,
         )
         if n_new < 0:
             raise RuntimeError(f"idmap capacity {self.capacity} exhausted")

@@ -26,7 +26,7 @@ from m3_tpu.storage.mediator import Mediator
 class Assembly:
     config: NodeConfig
     registry: "instrument.Registry"
-    db: Database
+    db: Database | None              # None: an aggregator process
     mediator: Mediator | None
     http_server: object | None
     carbon_server: object | None = None
@@ -44,6 +44,7 @@ class Assembly:
     checkpointer: object | None = None  # aggregator.checkpoint driver
     selfmon: object | None = None       # instrument.selfmon.SelfMonitor
     controller: object | None = None    # x.controller.Controller
+    aggregator: object | None = None    # aggregator.service.AggregatorService
 
     @property
     def port(self) -> int | None:
@@ -66,6 +67,8 @@ class Assembly:
         return self.admin_server.server_address[1] if self.admin_server else None
 
     def close(self) -> None:
+        if self.aggregator is not None:
+            self.aggregator.close()
         for h in self.peer_handles:
             h.close()
         for r in self.remote_stores:
@@ -95,7 +98,8 @@ class Assembly:
         # down — a racing admin request must not reconnect a closed store
         if self.kv is not None and hasattr(self.kv, "close"):
             self.kv.close()
-        self.db.close()
+        if self.db is not None:
+            self.db.close()
         if self.tracer is not None:
             # no longer the process's tracer; its ring stays readable
             from m3_tpu.instrument import tracing
@@ -125,6 +129,11 @@ class Assembly:
         if self.mediator is not None:
             self.mediator.close()
             self.mediator = None
+        if self.db is None:
+            # an aggregator holds only its open windows: nothing to
+            # persist beyond the flush times its ticks already wrote
+            self.close()
+            return
         # Persist everything persistable: seal+flush whatever left the
         # warm window, snapshot the still-open buffers, rotate the WAL
         # — a restart replays cleanly AND peers can stream every
@@ -171,6 +180,24 @@ def namespace_options(ns_cfg) -> NamespaceOptions:
         num_shards=ns_cfg.num_shards,
         **kw,
     )
+
+
+def _open_kv(cfg: NodeConfig):
+    """The process's control plane: the shared external KV service
+    (etcd role — survives this process and is visible to every
+    replica) where `db.kv_endpoint` names one, else file-backed under
+    `db.root`."""
+    if cfg.db.kv_endpoint:
+        from m3_tpu.cluster.kv_remote import RemoteKVStore
+
+        h, _, p = cfg.db.kv_endpoint.rpartition(":")
+        return RemoteKVStore((h, int(p)))
+    from pathlib import Path
+
+    from m3_tpu.cluster.kv import KVStore
+
+    Path(cfg.db.root).mkdir(parents=True, exist_ok=True)
+    return KVStore(cfg.db.root)
 
 
 def run_node(source, start_mediator: bool | None = None,
@@ -300,17 +327,7 @@ def run_node(source, start_mediator: bool | None = None,
                 and cfg.coordinator.admin_listen_port is not None)
         )
         if need_kv:
-            if cfg.db.kv_endpoint:
-                # shared external control plane (etcd role) — survives
-                # this node and is visible to every replica
-                from m3_tpu.cluster.kv_remote import RemoteKVStore
-
-                h, _, p = cfg.db.kv_endpoint.rpartition(":")
-                asm.kv = RemoteKVStore((h, int(p)))
-            else:
-                from m3_tpu.cluster.kv import KVStore
-
-                asm.kv = KVStore(cfg.db.root)  # file-backed control plane
+            asm.kv = _open_kv(cfg)
         if cfg.db.instance_id is not None and asm.kv is not None:
             from m3_tpu.cluster.placement import PlacementService
             from m3_tpu.cluster.topology import TopologyWatcher
@@ -699,3 +716,81 @@ def run_node(source, start_mediator: bool | None = None,
         asm.close()
         raise
     return asm
+
+
+def run_aggregator(source, clock=_time.time_ns) -> Assembly:
+    """Boot a standalone aggregator from the ``aggregator:`` section of
+    a YAML path/string or a NodeConfig (reference
+    ``cmd/services/m3aggregator/main``): rawtcp front door -> arenas ->
+    leader flush manager on the process's KV -> m3msg topic.  The
+    device-boundary knobs, the registry and the tracer are wired as
+    ``run_node`` wires them.  ``clock`` (nanoseconds) is what the flush
+    loop ticks on and what anchors timed batches: a benchmark that
+    compresses time hands in its data clock, or sets ``flush_interval``
+    out of the way and calls ``asm.aggregator.tick(now)`` itself."""
+    from m3_tpu.core.config import ConfigError
+
+    cfg = source if isinstance(source, NodeConfig) else load_config(source)
+    cfg.validate()
+    if cfg.aggregator is None:
+        raise ConfigError("run_aggregator needs an `aggregator:` section")
+    from m3_tpu.aggregator.service import AggregatorService
+    from m3_tpu.instrument import tracing
+    from m3_tpu.instrument.procstats import install_process_collector
+    from m3_tpu.x import devguard as _devguard, membudget as _membudget
+    from m3_tpu.x import register_metrics
+
+    _membudget.set_budget(cfg.device.mem_budget)
+    _devguard.configure(
+        failures=cfg.device.breaker_failures,
+        reset_s=parse_duration(cfg.device.breaker_reset) / 1e9)
+    registry = instrument.new_registry()
+    scope = registry.scope(cfg.metrics_prefix)
+    register_metrics(registry)
+    install_process_collector(registry, scope)
+    tracer = tracing.Tracer(enabled=cfg.aggregator.tracing)
+    tracing.install(tracer)
+    asm = Assembly(cfg, registry, None, None, None, tracer=tracer)
+    try:
+        asm.kv = _open_kv(cfg)
+        asm.aggregator = AggregatorService(
+            cfg.aggregator, asm.kv, scope=scope, tracer=tracer, clock=clock)
+        if cfg.aggregator.metrics_listen_port is not None:
+            asm.http_server = serve_metrics_background(
+                registry, cfg.aggregator.listen_host,
+                cfg.aggregator.metrics_listen_port)
+    except BaseException:
+        asm.close()
+        raise
+    return asm
+
+
+def serve_metrics_background(registry, host: str, port: int):
+    """``GET /metrics`` (Prometheus text) and ``/health`` for a process
+    with no coordinator API."""
+    import http.server
+    import threading
+
+    class Handler(http.server.BaseHTTPRequestHandler):
+        def do_GET(self):
+            if self.path == "/metrics":
+                body, ctype = (registry.render_prometheus().encode(),
+                               "text/plain; version=0.0.4")
+            elif self.path == "/health":
+                body, ctype = b'{"ok":true}', "application/json"
+            else:
+                self.send_error(404)
+                return
+            self.send_response(200)
+            self.send_header("Content-Type", ctype)
+            self.send_header("Content-Length", str(len(body)))
+            self.end_headers()
+            self.wfile.write(body)
+
+        def log_message(self, *a):
+            pass
+
+    srv = http.server.ThreadingHTTPServer((host, port), Handler)
+    srv.daemon_threads = True
+    threading.Thread(target=srv.serve_forever, daemon=True).start()
+    return srv

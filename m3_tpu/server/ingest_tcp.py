@@ -45,8 +45,10 @@ import time
 
 import numpy as np
 
+from m3_tpu.core.idbytes import PackedIds
 from m3_tpu.instrument import tracing
 from m3_tpu.instrument.tracing import NOOP_TRACER, Tracepoint
+from m3_tpu.metrics.aggregation import AggregationID
 from m3_tpu.metrics.policy import StoragePolicy
 from m3_tpu.metrics.types import MetricType
 from m3_tpu.msg import protocol as wire
@@ -86,8 +88,12 @@ def aggregator_sink(aggregator, lock: threading.Lock | None = None,
     The returned sink handles all three ingest classes (reference
     aggregator.go AddUntimed :263 / AddTimed :77 / AddPassthrough :86)
     via its ``kind`` argument — the frame type dispatches in the
-    handler."""
-    lock = lock or threading.Lock()
+    handler.  A metric batch's ids are handed on as ``PackedIds`` and a
+    position array per metric type: no id is copied or re-listed per
+    frame.  The wait for ``lock`` stands under the span
+    ``aggregator.lock.wait``."""
+    lock = tracing.SpanLock(lock or threading.Lock(),
+                            Tracepoint.AGG_LOCK_WAIT)
 
     def sink(batch, kind: int = wire.METRIC_BATCH) -> None:
         with lock:
@@ -102,20 +108,23 @@ def aggregator_sink(aggregator, lock: threading.Lock | None = None,
                     StoragePolicy.parse(policy), entries)
                 return
             mts = np.asarray(batch.metric_types)
+            all_ids = (batch.ids if isinstance(batch.ids, PackedIds)
+                       else PackedIds.from_ids(batch.ids))
+            agg_id = AggregationID(batch.agg_id)
             for mt in np.unique(mts):
                 sel = np.nonzero(mts == mt)[0]
-                ids = [batch.ids[i] for i in sel]
+                ids = all_ids.take(sel)
                 if kind == wire.TIMED_BATCH:
                     # The server clock anchors fresh window rings
                     # (entry.go addTimed validates against now±buffer).
                     aggregator.add_timed_batch(
                         MetricType(int(mt)), ids,
-                        batch.values[sel], batch.times[sel],
+                        batch.values[sel], batch.times[sel], agg_id,
                         now_nanos=clock())
                 else:
                     aggregator.add_untimed_batch(
                         MetricType(int(mt)), ids,
-                        batch.values[sel], batch.times[sel])
+                        batch.values[sel], batch.times[sel], agg_id)
 
     return sink
 
@@ -138,6 +147,39 @@ class _ConnState:
         self.inflight = 0  # frames queued; guarded by server._q_lock
         self.wlock = threading.Lock()
         self.pending_trace = None
+
+
+class _FrameSpan:
+    """One frame's root span, begun on the handler thread and ended on
+    the worker (``Tracer.reserve`` / ``record``).  ``ctx`` is what both
+    threads bind: the root's ids, or UNSAMPLED where nothing records,
+    so that the frame's spans never enter the ring as roots of their
+    own."""
+
+    __slots__ = ("tracer", "parent", "ids", "t0", "t_queued")
+
+    def __init__(self, tracer, parent):
+        self.tracer, self.parent = tracer, parent
+        self.ids = tracer.reserve(parent)
+        self.t0 = self.t_queued = time.monotonic_ns()
+
+    @property
+    def ctx(self):
+        return self.ids if self.ids is not None else tracing.UNSAMPLED
+
+    def queued(self) -> None:
+        self.t_queued = time.monotonic_ns()
+
+    def dequeued(self) -> None:
+        if self.ids is not None:
+            self.tracer.record(Tracepoint.INGEST_QUEUE_WAIT, self.t_queued,
+                               time.monotonic_ns(), parent=self.ids)
+
+    def done(self, n: int, ftype: int) -> None:
+        if self.ids is not None:
+            self.tracer.record(Tracepoint.INGEST_FRAME, self.t0,
+                               time.monotonic_ns(), {"n": n, "frame": ftype},
+                               ctx=self.ids, parent=self.parent)
 
 
 class _IngestHandler(socketserver.BaseRequestHandler):
@@ -193,22 +235,29 @@ class _IngestHandler(socketserver.BaseRequestHandler):
                 break
             if act == "drop":
                 break
+            # The frame's root span, ``ingest.frame``, runs from here
+            # (the frame is in hand) to the ack, which the worker
+            # thread sends: its ids are reserved now and both threads
+            # bind them (a sampled sender's context is its parent).
+            parent, conn.pending_trace = conn.pending_trace, None
+            frame = _FrameSpan(srv.tracer, parent)
             try:
-                if ftype == wire.PASSTHROUGH_BATCH:
-                    batch = wire.decode_passthrough_batch(payload)
-                    n = len(batch[1])
-                elif ftype == wire.FORWARDED_BATCH:
-                    batch = wire.decode_forwarded_batch(payload)
-                    n = len(batch[1])
-                else:
-                    batch = wire.decode_metric_batch(payload)
-                    n = len(batch.ids)
+                with tracing.bind(frame.ctx), srv.tracer.start_span(
+                        Tracepoint.INGEST_FRAME_DECODE):
+                    if ftype == wire.PASSTHROUGH_BATCH:
+                        batch = wire.decode_passthrough_batch(payload)
+                        n = len(batch[1])
+                    elif ftype == wire.FORWARDED_BATCH:
+                        batch = wire.decode_forwarded_batch(payload)
+                        n = len(batch[1])
+                    else:
+                        batch = wire.decode_metric_columns(payload)
+                        n = len(batch.ids)
             except (wire.ProtocolError, Exception):  # noqa: BLE001
                 if mx is not None:
                     mx.decode_errors.inc()
                 break
-            tctx, conn.pending_trace = conn.pending_trace, None
-            if not srv._try_enqueue(conn, sock, ftype, batch, n, tctx):
+            if not srv._try_enqueue(conn, sock, ftype, batch, n, frame):
                 # Load shed: explicit BACKOFF, connection stays up.
                 # Writability-probed: a fire-and-forget client that
                 # never reads its socket eventually closes the TCP
@@ -288,7 +337,7 @@ class IngestServer(socketserver.ThreadingTCPServer):
 
     # -- ingest queue ------------------------------------------------------
 
-    def _try_enqueue(self, conn, sock, ftype, batch, n, tctx=None) -> bool:
+    def _try_enqueue(self, conn, sock, ftype, batch, n, frame) -> bool:
         # Disk-pressure shed rides the SAME refuse-before-ack path as
         # queue overflow: at CRITICAL the frame is never enqueued, the
         # client gets the explicit BACKOFF hint, and since the ack is
@@ -316,7 +365,8 @@ class IngestServer(socketserver.ThreadingTCPServer):
             # unbounded; the watermark above is the real bound) so an
             # accepted frame can never land AFTER the shutdown
             # sentinel, which is enqueued under this same lock.
-            self._queue.put((conn, sock, ftype, batch, n, tctx))
+            frame.queued()
+            self._queue.put((conn, sock, ftype, batch, n, frame))
         return True
 
     def _drain(self) -> None:
@@ -324,24 +374,21 @@ class IngestServer(socketserver.ThreadingTCPServer):
             item = self._queue.get()
             if item is None:
                 return
-            conn, sock, ftype, batch, n, tctx = item
+            conn, sock, ftype, batch, n, frame = item
             t0 = time.perf_counter()
+            frame.dequeued()
             try:
                 # The worker thread never inherits a binding
                 # (contextvar rule): the frame's own context is bound
-                # here — BEFORE the span opens, so the batch span
-                # parents on the SENDER's span, joining its trace.
-                with tracing.bind(tctx):
-                    span = (self.tracer.start_span(
-                        Tracepoint.INGEST_TCP_BATCH,
-                        {"n": n, "frame": ftype})
-                        if tctx is not None else tracing.NOOP_SPAN)
-                    with span:
-                        if ftype == wire.METRIC_BATCH:
-                            # one-arg call: custom sinks keep working
-                            self.sink(batch)
-                        else:
-                            self.sink(batch, ftype)
+                # here, so the sink's spans parent on the frame's root
+                # (and through it on the SENDER's span, joining its
+                # trace).
+                with tracing.bind(frame.ctx):
+                    if ftype == wire.METRIC_BATCH:
+                        # one-arg call: custom sinks keep working
+                        self.sink(batch)
+                    else:
+                        self.sink(batch, ftype)
             except Exception:  # noqa: BLE001 — a sink fault (e.g. no
                 # passthrough handler configured, or a one-arg custom
                 # sink receiving a timed frame) must close THIS
@@ -376,6 +423,7 @@ class IngestServer(socketserver.ThreadingTCPServer):
                     except OSError:
                         pass  # client went away; its loss is counted
                         # client-side by the missing ack
+            frame.done(n, ftype)
 
     def _dec_inflight(self, conn) -> None:
         with self._q_lock:

@@ -2,8 +2,9 @@
 
 Equivalent of the reference's service mains
 (`src/cmd/services/m3dbnode/main/main.go` — parse config, server.Run,
-block on signals).  Writes a `<root>/node.json` status file (pid + HTTP
-port) once serving, so harnesses (dtest) can discover the ephemeral
+block on signals; with an `aggregator:` section the process is a
+standalone aggregator, `src/cmd/services/m3aggregator/main`).  Writes a
+`<root>/node.json` status file (pid + ports) once serving, so harnesses (dtest) can discover the ephemeral
 port; exits cleanly on SIGTERM, flushing the commitlog.
 """
 
@@ -28,16 +29,19 @@ def main(argv=None) -> int:
     # JAX_PLATFORMS=cpu itself (dtest/harness.NodeProcess).
     from m3_tpu.core.config import load_config
     from m3_tpu.instrument import logger
-    from m3_tpu.server.assembly import run_node
+    from m3_tpu.server.assembly import run_aggregator, run_node
     from m3_tpu.x import jaxcache
 
     jaxcache.configure()
     log = logger("node_main")
     cfg = load_config(argv[0])
-    asm = run_node(cfg)
+    # a node file with an `aggregator:` section is an aggregator process
+    asm = run_aggregator(cfg) if cfg.aggregator is not None else run_node(cfg)
     status = {
         "pid": os.getpid(),
         "port": asm.port,
+        "ingest_port": asm.aggregator.port if asm.aggregator else None,
+        "msg_port": asm.aggregator.msg_port if asm.aggregator else None,
         "carbon_port": asm.carbon_port,
         "rpc_port": asm.rpc_port,
         "admin_port": asm.admin_port,

@@ -147,16 +147,20 @@ def run_pipeline(S: int = S_DEFAULT, T: int = T_DEFAULT,
 
     import jax
 
-    from m3_tpu.aggregator.engine import AggregatorOptions
-    from m3_tpu.metrics.policy import StoragePolicy
+    from m3_tpu.aggregator.service import aggregator_options
+    from m3_tpu.core.config import AggregatorConfig
     from m3_tpu.x import hopwatch
 
-    policy = StoragePolicy.parse(f"{RESOLUTION_S}s:2d")
-    opts = AggregatorOptions(
+    # the served aggregator's own constructor path (run_aggregator
+    # builds its engine from the same section); the timer buffer stays
+    # at the size the pinned PIPELINE baseline was taken with
+    opts = aggregator_options(AggregatorConfig(
         capacity=1 << max(10, (S - 1).bit_length()),
         num_windows=4,
-        storage_policies=(policy,),
-    )
+        timer_sample_capacity=1 << 24,
+        storage_policies=[f"{RESOLUTION_S}s:2d"],
+    ))
+    (policy,) = opts.storage_policies
     ids, ts, vals = _corpus(S, T)
     frames = _encode_frames(ids, ts, vals)
     wire_bytes = sum(len(f) for f in frames)

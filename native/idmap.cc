@@ -84,21 +84,21 @@ int64_t idmap_len(void* h) {
   return static_cast<int64_t>(static_cast<IdMap*>(h)->slots.size());
 }
 
-// Resolve a packed batch: ids laid out back-to-back in `buf`,
-// `offsets[i]..offsets[i+1]` delimiting id i (n+1 entries).  Fills
-// out_slots[n].  Newly-allocated entries are reported via
+// Resolve a packed batch: id i is the `lens[i]` bytes of `buf` at
+// `starts[i]` (the ids need not be adjacent: the wire reader points
+// into the frame it received).  Fills out_slots[n].  Newly-allocated entries are reported via
 // out_new_idx (their batch positions); returns the count of new
 // entries, or -1 when allocation would exceed capacity (no partial
 // allocation is rolled back; callers treat -1 as fatal for the batch).
 int64_t idmap_resolve_batch(void* h, const uint8_t* buf,
-                            const uint64_t* offsets, int64_t n,
-                            uint64_t mask, int32_t* out_slots,
+                            const int64_t* starts, const int64_t* lens,
+                            int64_t n, uint64_t mask, int32_t* out_slots,
                             int64_t* out_new_idx) {
   auto* m = static_cast<IdMap*>(h);
   int64_t n_new = 0;
   for (int64_t i = 0; i < n; ++i) {
-    std::string_view sv(reinterpret_cast<const char*>(buf) + offsets[i],
-                        offsets[i + 1] - offsets[i]);
+    std::string_view sv(reinterpret_cast<const char*>(buf) + starts[i],
+                        lens[i]);
     RefKey ref{sv, mask};
     auto it = m->slots.find(ref);
     if (it != m->slots.end()) {
@@ -118,8 +118,7 @@ int64_t idmap_resolve_batch(void* h, const uint8_t* buf,
       for (int64_t k = 0; k < n_new; ++k) {
         int64_t j = out_new_idx[k];
         std::string_view jsv(
-            reinterpret_cast<const char*>(buf) + offsets[j],
-            offsets[j + 1] - offsets[j]);
+            reinterpret_cast<const char*>(buf) + starts[j], lens[j]);
         auto jit = m->slots.find(RefKey{jsv, mask});
         if (jit != m->slots.end()) {
           m->free_list.push_back(jit->second);

@@ -192,6 +192,35 @@ class TestAggregatorPrograms:
                  A((), jnp.int32), capacity=self.C)
 
 
+class TestServedAggregatorPrograms:
+    """The standalone aggregator's arenas at the shapes of the cell
+    m3agg.untimed_rollup (run_aggregator; 131,072 series): one frame is
+    one counter_ingest and one gauge_ingest call of 4,096 samples over
+    2 windows x 2^16 slots per type."""
+
+    W, C, N = 2, 1 << 16, 4096
+
+    def _state(self, init):
+        shapes = jax.eval_shape(lambda: init(self.W, self.C))
+        return jax.tree_util.tree_map(lambda a: A(a.shape, a.dtype), shapes)
+
+    def test_counter_ingest(self, one_chip):
+        from m3_tpu.aggregator import packed
+
+        _compile(packed.counter_ingest, one_chip,
+                 self._state(packed.counter_init),
+                 A((self.N,), jnp.int64), A((self.N,), jnp.int64),
+                 A((self.N,), jnp.int64),
+                 num_windows=self.W, capacity=self.C)
+
+    def test_counter_consume(self, one_chip):
+        from m3_tpu.aggregator import packed
+
+        _compile(packed.counter_consume, one_chip,
+                 self._state(packed.counter_init), A((), jnp.int32),
+                 capacity=self.C)
+
+
 class TestQueryPrograms:
     """rate -> sum by (le) is host-grouped; the device programs are the
     rate stencil and the histogram_quantile kernel."""
