@@ -138,13 +138,15 @@ def set_ingest_impl(impl: str) -> None:
 # or set_arena_layout():
 #   packed — the sort/segment formulation + adaptive-width counter state
 #            (aggregator/packed.py): one u64 key sort per ingest batch,
-#            dense merges, no hot-path scatter.  Counter stats exact,
+#            a merge in the sorted batch's domain (one batch-sized
+#            scatter a state lane).  Counter stats exact,
 #            gauge sum/sum_sq within 1e-6 of the f64 path (segmented
 #            tree adds), timer value lanes at f32 (packed32) precision.
 #   f64    — the original scatter arenas in THIS module: the parity
 #            oracle, bit-exact reference semantics throughout.
-#   auto   — packed (faster on both measured backends: CPU avoids the
-#            ~60ns/elt scatter floor, TPU its ~1us/elt scatter).
+#   auto   — packed (one sort and ~a dozen lane scatters of segment
+#            TAILS a batch, against the oracle's 3-key lex sort and a
+#            scatter per statistic of every sample).
 # Resolution happens on the HOST at arena construction (tracewatch
 # contract: nothing reads the environment under a tracer) — engine
 # arenas bind their layout at __init__, the sharded program takes it as
@@ -167,10 +169,9 @@ def arena_layout() -> str:
 
 
 def resolved_arena_layout() -> str:
-    """'auto' resolves to 'packed' on every backend: the sort/segment
-    formulation wins on CPU (no scatter floor) and by construction on
-    TPU (scatter measured ~1us/element there).  'f64' remains the
-    explicit parity-oracle escape hatch."""
+    """'auto' resolves to 'packed' on every backend (the sort/segment
+    formulation: aggregator/packed.py).  'f64' remains the explicit
+    parity-oracle escape hatch."""
     return "packed" if _LAYOUT == "auto" else _LAYOUT
 
 

@@ -1,24 +1,27 @@
 """Packed arena: sort/segment ingest + adaptive-width counter state.
 
 The scatter arenas (``arena.py``) pay one XLA scatter per statistic lane
-— ~11 random-access passes plus a 3-key lex sort per ingest batch.  On
-XLA-CPU a scatter has a ~40-60ns/element floor regardless of dtype, and
-on TPU it measured ~1us/element (round 5, window 3).  This
-module reformulates the whole hot path around ONE u64 key sort per
-batch and otherwise touches memory only with the primitives XLA runs at
-streaming speed (gather ~4.5ns/elt, cumsum ~6ns, dense ~4ns on the r07
-box):
+of every SAMPLE — ~11 random-access passes plus a 3-key lex sort per
+ingest batch (a scatter costs ~40-60ns an element on XLA-CPU and
+~0.08us per 64-bit element on a TPU v5e; PERF.md, PR 28).  This module
+reformulates the whole hot path around ONE u64 key sort per batch, and
+after it never leaves the sorted batch's domain: an ingest costs its N
+rows, whatever the arena holds (the dense merge it replaces paid
+~0.3us per SLOT per call on the TPU):
 
     key    = flat_idx << AB | arrival          (AB = batch-size bits)
-    sorted -> permutation + per-slot segment boundaries
-    sum/sum_sq/count  = cumulative-sum differences at the boundaries
-    min/max/last      = one segmented associative scan
-    state update      = DENSE merge over the (W*C,) arena — no scatter
+    sorted -> permutation + segment heads/tails (neighbour compares)
+    count/sum/sum_sq/min/max/last = one segmented associative scan;
+                        a segment's aggregates stand at its TAIL row
+    state update      = gather the state at the N rows' flat slots,
+                        merge, scatter the tail rows back (per lane,
+                        batch-sized, into the donated state)
 
-Boundaries come from one monotone scatter-min (`indices_are_sorted`)
-plus a reverse cummin — no searchsorted on the ingest path.  The only
-remaining scatters are the timer sample append (one packed word) and
-the bounded-K overflow-pool promotion below.
+No lane of arena length is computed.  The scatters are batch-sized:
+one per state lane at the tail rows (distinct slots; every other row
+is sent to its own out-of-range index and dropped), one scatter-max
+into the (C,) expiry column, the bounded-K overflow-pool promotion
+below, and the timer sample append (one packed word).
 
 Counter state adopts the SALSA / Counter Pools layout
 (arXiv:2102.12531, arXiv:2502.14699): narrow base lanes packed per
@@ -124,16 +127,12 @@ _ERR_TIMER_OVERFLOW = 4
 
 
 class _Segments(NamedTuple):
-    """One sorted batch view: permutation + dense per-slot boundaries."""
+    """One sorted batch view: permutation + per-row segment marks."""
 
     perm: jnp.ndarray   # i32 (N,) original position of sorted element
     sslot: jnp.ndarray  # i32 (N,) flat (window*C+slot) index, ascending
     head: jnp.ndarray   # bool (N,) first element of its segment
-    start: jnp.ndarray  # i32 (WC,) first sorted position per dense slot
-    end: jnp.ndarray    # i32 (WC,) one past last sorted position
-    cnt: jnp.ndarray    # i64 (WC,) segment length (0 for empty slots)
-    has: jnp.ndarray    # bool (WC,)
-    ab: int             # arrival bits (static)
+    tail: jnp.ndarray   # bool (N,) last element of its segment
 
 
 def _arrival_bits(n: int) -> int:
@@ -158,10 +157,10 @@ def packed_flat_index(windows, slots, num_windows: int, capacity: int):
 
 
 def _segment_view(idx: jnp.ndarray, n_flat: int) -> _Segments:
-    """Sort a batch of flat indices into dense per-slot segments.
+    """Sort a batch of flat indices into per-slot segments.
 
     ``idx`` values == n_flat are the drop sentinel: they sort to the
-    tail and fall outside every dense slot's [start, end) range."""
+    tail as one segment that no merge reads."""
     n = idx.shape[0]
     ab = _arrival_bits(n)
     if (n_flat + 1).bit_length() + ab > 63:
@@ -174,61 +173,17 @@ def _segment_view(idx: jnp.ndarray, n_flat: int) -> _Segments:
     ks = jax.lax.sort(key)
     perm = (ks & jnp.uint64((1 << ab) - 1)).astype(jnp.int32)
     sslot = (ks >> jnp.uint64(ab)).astype(jnp.int32)
-    head = jnp.concatenate(
-        [jnp.ones(1, bool), sslot[1:] != sslot[:-1]])
-    # Dense boundaries: one monotone scatter-min marks each slot's first
-    # sorted position; a reverse cummin over the NEXT slots' starts
-    # yields the ends (empty slots collapse to start > end -> cnt 0).
-    bpos = jnp.full(n_flat + 1, n, jnp.int32).at[sslot].min(
-        jnp.arange(n, dtype=jnp.int32), mode="drop",
-        indices_are_sorted=True)
-    start = bpos[:n_flat]
-    # (an associative scan, not lax.cummin: the TPU compiler takes 67 s
-    # over a 327K-element cummin and 12 s over this, same values)
-    end = jax.lax.associative_scan(jnp.minimum, bpos[1:], reverse=True)
-    cnt = jnp.maximum(end - start, 0).astype(jnp.int64)
-    return _Segments(perm, sslot, head, start, end, cnt, cnt > 0, ab)
-
-
-def _seg_sum_i64(seg: _Segments, v_sorted: jnp.ndarray) -> jnp.ndarray:
-    """Exact (mod-2^64-wrapping) per-slot sums via cumsum differences —
-    identical arithmetic to the scatter path's i64 accumulate."""
-    cs = jnp.concatenate([jnp.zeros(1, jnp.int64), jnp.cumsum(v_sorted)])
-    return jnp.where(seg.has, cs[seg.end] - cs[seg.start], jnp.int64(0))
-
-
-def _seg_flag_counts(seg: _Segments, flags: tuple) -> tuple:
-    """Per-slot counts for up to three boolean lanes, packed into ONE
-    i64 cumsum.  Each lane gets ``seg.ab + 1`` bits: a whole batch can
-    land in ONE segment, so a count reaches n == 2^ab exactly at
-    power-of-two batch sizes — ab bits alone would carry into the next
-    lane.  Falls back to one cumsum per lane when the lanes don't fit
-    63 bits."""
-    lb = seg.ab + 1
-    k = len(flags)
-    if k * lb <= 63:
-        word = flags[0].astype(jnp.int64)
-        for i, f in enumerate(flags[1:], start=1):
-            word = word + (f.astype(jnp.int64) << jnp.int64(i * lb))
-        cs = jnp.concatenate([jnp.zeros(1, jnp.int64), jnp.cumsum(word)])
-        d = jnp.where(seg.has, cs[seg.end] - cs[seg.start], jnp.int64(0))
-        out = []
-        for i in range(k):
-            lane = (d >> jnp.int64(i * lb))
-            if i < k - 1:
-                lane = lane & jnp.int64((1 << lb) - 1)
-            out.append(lane)
-        return tuple(out)
-    return tuple(_seg_sum_i64(seg, f.astype(jnp.int64)) for f in flags)
+    edge = sslot[1:] != sslot[:-1]
+    one = jnp.ones(1, bool)
+    return _Segments(perm, sslot, jnp.concatenate([one, edge]),
+                     jnp.concatenate([edge, one]))
 
 
 def _seg_scan(seg: _Segments, lanes: tuple, combine) -> tuple:
     """Segmented associative scan over the sorted batch: ``combine``
     merges two within-segment prefixes; segment heads reset the carry.
-    Returns the RAW scanned lanes — gather per-slot reductions at each
-    segment's end-1 with ``_at_ends`` (over whichever dense view the
-    caller needs, so stats gathers stay on the [0, W*C) region while
-    the time lane also covers the ghost region)."""
+    Returns the scanned lanes: a segment's reduction stands at its
+    tail row (``seg.tail``), the only rows the merges write back."""
     def op(a, b):
         fa, va = a[0], a[1:]
         fb, vb = b[0], b[1:]
@@ -240,17 +195,32 @@ def _seg_scan(seg: _Segments, lanes: tuple, combine) -> tuple:
     return res[1:]
 
 
-def _at_ends(end: jnp.ndarray, lane: jnp.ndarray) -> jnp.ndarray:
-    """Per-slot scan reduction: the scanned lane at each segment's
-    last element (callers mask empty slots via their ``has``)."""
-    lp = jnp.clip(end.astype(jnp.int64) - 1, 0, lane.shape[0] - 1)
-    return lane[lp]
+def _tail_rows(seg: _Segments, wc: int):
+    """(live, at, put) for a batch-domain merge into (W*C,) state
+    lanes: ``live`` marks the rows that end a stats segment; ``at``
+    gathers a lane at every row's flat slot (ghost and dropped rows
+    read slot W*C-1 and are never written); ``put(lane, v)`` writes
+    ``v`` back at the live rows.  Every other row goes to an
+    out-of-range index OF ITS OWN, so the indices are unique as
+    promised to XLA (not sorted: the large targets stand between
+    sorted ones) and ``mode="drop"`` discards them."""
+    n = seg.sslot.shape[0]
+    live = seg.tail & (seg.sslot < wc)
+    tgt = jnp.where(live, seg.sslot, wc + jnp.arange(n, dtype=jnp.int32))
+    at = jnp.minimum(seg.sslot, wc - 1)
+    return live, at, lambda lane, v: lane.at[tgt].set(
+        v, mode="drop", unique_indices=True)
 
 
-def _stats_view(seg: _Segments, wc: int) -> _Segments:
-    """The [0, W*C) stats region of a ghost-extended segment view."""
-    return seg._replace(start=seg.start[:wc], end=seg.end[:wc],
-                        cnt=seg.cnt[:wc], has=seg.has[:wc])
+def _merge_last_at(last_at, idx, times, wc: int):
+    """Fold the batch's times (the ghost region's window-dropped
+    samples included) into the per-slot expiry column, straight from
+    the unsorted batch.  One slot may stand under several windows of a
+    batch: not a unique scatter."""
+    capacity = last_at.shape[0]
+    flat = idx.astype(jnp.int32)
+    slot = jnp.where(flat < wc + capacity, flat % capacity, capacity)
+    return last_at.at[slot].max(times, mode="drop")
 
 
 # ---------------------------------------------------------------------------
@@ -336,54 +306,32 @@ def counter_init(num_windows: int, capacity: int,
     )
 
 
-def _merge_last_at(last_at, d_tmax, num_windows: int, capacity: int):
-    """Fold per-flat-slot batch max-times (including the ghost region's
-    window-dropped samples) into the per-slot expiry column."""
-    return jnp.maximum(
-        last_at,
-        jnp.max(d_tmax.reshape(num_windows + 1, capacity), axis=0))
-
-
-def _counter_sums(seg: _Segments, v: jnp.ndarray):
-    """(d_sum, d_sq, wide flags) for a sorted counter value column."""
-    d_sum = _seg_sum_i64(seg, v)
-    d_sq = _seg_sum_i64(seg, v * v)
+def _counter_scan_lanes(v: jnp.ndarray):
+    """Scan input lanes for a sorted counter value column: (count, sum,
+    sum_sq, wide count, min, max).  The i64 adds wrap mod 2^64 — the
+    scatter path's accumulate, in another (immaterial) order."""
     wide = (v < jnp.int64(_MM_LO)) | (v > jnp.int64(_MM_HI))
-    return d_sum, d_sq, wide
+    return (jnp.ones_like(v), v, v * v, wide.astype(jnp.int64), v, v)
 
 
-def _counter_batch_segments(sview: _Segments, seg: _Segments,
-                            values: jnp.ndarray, times: jnp.ndarray):
-    """Per-dense-slot batch aggregates for a counter-style i64 batch:
-    stats over the (W*C,) region, max-time over the full ghost-extended
-    domain (the last_at column)."""
-    v = values[seg.perm]
-    t = times[seg.perm]
-    d_sum, d_sq, wide = _counter_sums(sview, v)
-    (d_wide,) = _seg_flag_counts(sview, (wide,))
-    s_min, s_max, s_t = _seg_scan(
-        seg, (v, v, t),
-        lambda a, b: (jnp.minimum(a[0], b[0]), jnp.maximum(a[1], b[1]),
-                      jnp.maximum(a[2], b[2])))
-    d_min = jnp.where(sview.has, _at_ends(sview.end, s_min), I64_MAX)
-    d_max = jnp.where(sview.has, _at_ends(sview.end, s_max), I64_MIN)
-    d_tmax = jnp.where(seg.has, _at_ends(seg.end, s_t), I64_MIN)
-    return (sview.cnt, d_sum, d_sq, d_min, d_max, d_wide), d_tmax
+def _counter_scan_combine(a, b):
+    return (a[0] + b[0], a[1] + b[1], a[2] + b[2], a[3] + b[3],
+            jnp.minimum(a[4], b[4]), jnp.maximum(a[5], b[5]))
 
 
-def _counter_merge(state: PackedCounterState, segs, last_at,
-                   num_windows: int, capacity: int, widths: tuple,
-                   promote_k: int):
-    """Dense merge of batch aggregates into the packed counter state,
-    with bounded-K overflow-pool promotion."""
+def _counter_merge(state: PackedCounterState, seg: _Segments, scanned,
+                   last_at, wc: int, widths: tuple, promote_k: int):
+    """Merge the batch's per-segment aggregates (at the tail rows) into
+    the packed counter state, with bounded-K overflow-pool promotion:
+    every flag, index and delta below is a lane over the N rows."""
     cb, sb = widths
-    d_cnt, d_sum, d_sq, d_min, d_max, d_wide = segs
-    wc = num_windows * capacity
+    d_cnt, d_sum, d_sq, d_wide, d_min, d_max = scanned
     K = min(promote_k, wc)
     P = state.pool_cnt.shape[0]
+    live, at, put = _tail_rows(seg, wc)
 
-    b_cnt, b_sum = _unpack_base(state.base, widths)
-    b_min, b_max = _unpack_minmax(state.minmax)
+    b_cnt, b_sum = _unpack_base(state.base[at], widths)
+    b_min, b_max = _unpack_minmax(state.minmax[at])
     # a slot with no base samples holds the int16 NEUTRAL sentinels
     # (32767/-32768) — mask them to the true identities before merging,
     # or a virgin slot promoting on an all-wide first batch would seed
@@ -392,59 +340,62 @@ def _counter_merge(state: PackedCounterState, segs, last_at,
     b_max = jnp.where(b_cnt > 0, b_max, I64_MIN)
     n_cnt = b_cnt + d_cnt
     n_sum = b_sum + d_sum
-    n_sq = state.sq + d_sq  # full-width column: never a promote trigger
+    n_sq = state.sq[at] + d_sq  # full-width column: never a promote trigger
     n_min = jnp.minimum(b_min, d_min)
     n_max = jnp.maximum(b_max, d_max)
 
-    promoted = state.pool_idx >= 0
+    row_pid = state.pool_idx[at]
+    promoted = row_pid >= 0
     lane_over = ((n_cnt >= jnp.int64(1 << cb))
                  | (n_sum >= jnp.int64(1 << (sb - 1)))
                  | (n_sum < jnp.int64(-(1 << (sb - 1))))
                  | (d_wide > 0))
-    seg_has = d_cnt > 0
-    to_pool = seg_has & ~promoted & lane_over
-    active = seg_has & promoted
+    to_pool = live & ~promoted & lane_over
+    active = live & promoted
 
     def with_pool(op):
         (pool_cnt, pool_sum, pool_sq, pool_min, pool_max, pool_owner,
          pool_idx, pool_n, err) = op
-        num_new = to_pool.sum().astype(jnp.int32)
-        kn = jnp.nonzero(to_pool, size=K, fill_value=wc)[0]
-        valid = jnp.arange(K, dtype=jnp.int32) < num_new
+        # each promoting / active row's rank among its kind, in row
+        # (= flat slot) order; a batch serves the first K of either
+        new_rank = jnp.cumsum(to_pool.astype(jnp.int32)) - 1
+        act_rank = jnp.cumsum(active.astype(jnp.int32)) - 1
+        valid = to_pool & (new_rank < K)
         # Allocate from FREE rows (owner < 0): the scan over P reuses
         # rows released by clear_slots, so slot churn cannot
         # permanently exhaust the pool the way a bump pointer did.  A
         # candidate with no free row left keeps pool_idx == -1 (its
         # base lanes clip — flagged by err, but never aliased onto
         # another slot's pool row).
-        free = jnp.nonzero(pool_owner < 0, size=K,
-                           fill_value=P)[0].astype(jnp.int32)
-        room = free < P
-        take = valid & room
-        pids = jnp.where(take, free, jnp.int32(P))
-        pool_idx = pool_idx.at[kn].set(
-            jnp.where(take, pids, jnp.int32(-1)), mode="drop")
-        pool_owner = pool_owner.at[pids].set(kn.astype(jnp.int32),
-                                             mode="drop")
-        kc = jnp.clip(kn, 0, wc - 1)
-        pool_cnt = pool_cnt.at[pids].set(n_cnt[kc], mode="drop")
-        pool_sum = pool_sum.at[pids].set(n_sum[kc], mode="drop")
-        pool_sq = pool_sq.at[pids].set(n_sq[kc], mode="drop")
-        pool_min = pool_min.at[pids].set(n_min[kc], mode="drop")
-        pool_max = pool_max.at[pids].set(n_max[kc], mode="drop")
+        # (the first K free rows by an i32 cumsum and one scatter:
+        # jnp.nonzero's 64-bit bincount cost 4.2 ms at P = 65,536 on a
+        # v5e against 0.3, and its cumsum over a K <= 1,024 was refused
+        # by the TPU compiler inside this cond; PERF.md, PR 28)
+        is_free = pool_owner < 0
+        free = jnp.full(K, P, jnp.int32).at[
+            jnp.where(is_free, jnp.cumsum(is_free.astype(jnp.int32)) - 1,
+                      K)].set(jnp.arange(P, dtype=jnp.int32), mode="drop")
+        pids = jnp.where(valid, free[jnp.clip(new_rank, 0, K - 1)],
+                         jnp.int32(P))
+        take = pids < P
+        pool_idx = pool_idx.at[jnp.where(take, seg.sslot, wc)].set(
+            pids, mode="drop")
+        pool_owner = pool_owner.at[pids].set(seg.sslot, mode="drop")
+        pool_cnt = pool_cnt.at[pids].set(n_cnt, mode="drop")
+        pool_sum = pool_sum.at[pids].set(n_sum, mode="drop")
+        pool_sq = pool_sq.at[pids].set(n_sq, mode="drop")
+        pool_min = pool_min.at[pids].set(n_min, mode="drop")
+        pool_max = pool_max.at[pids].set(n_max, mode="drop")
         # already-promoted slots with batch data: add deltas to rows
-        num_act = active.sum().astype(jnp.int32)
-        ka = jnp.nonzero(active, size=K, fill_value=wc)[0]
-        kac = jnp.clip(ka, 0, wc - 1)
-        pid_a = jnp.where(ka < wc, pool_idx[kac], jnp.int32(P))
-        pool_cnt = pool_cnt.at[pid_a].add(d_cnt[kac], mode="drop")
-        pool_sum = pool_sum.at[pid_a].add(d_sum[kac], mode="drop")
-        pool_sq = pool_sq.at[pid_a].add(d_sq[kac], mode="drop")
-        pool_min = pool_min.at[pid_a].min(d_min[kac], mode="drop")
-        pool_max = pool_max.at[pid_a].max(d_max[kac], mode="drop")
-        err = err | jnp.where(num_new > K, _ERR_PROMOTE_K, 0)
-        err = err | jnp.where(num_act > K, _ERR_PROMOTE_K, 0)
-        err = err | jnp.where((valid & ~room).any(), _ERR_POOL_FULL, 0)
+        pid_a = jnp.where(active & (act_rank < K), row_pid, jnp.int32(P))
+        pool_cnt = pool_cnt.at[pid_a].add(d_cnt, mode="drop")
+        pool_sum = pool_sum.at[pid_a].add(d_sum, mode="drop")
+        pool_sq = pool_sq.at[pid_a].add(d_sq, mode="drop")
+        pool_min = pool_min.at[pid_a].min(d_min, mode="drop")
+        pool_max = pool_max.at[pid_a].max(d_max, mode="drop")
+        over_k = (new_rank[-1] >= K) | (act_rank[-1] >= K)
+        err = err | jnp.where(over_k, _ERR_PROMOTE_K, 0)
+        err = err | jnp.where((valid & ~take).any(), _ERR_POOL_FULL, 0)
         pool_n = (pool_owner >= 0).sum().astype(jnp.int32)
         return (pool_cnt, pool_sum, pool_sq, pool_min, pool_max,
                 pool_owner, pool_idx, pool_n, err.astype(jnp.int32))
@@ -456,8 +407,9 @@ def _counter_merge(state: PackedCounterState, segs, last_at,
      pool_idx, pool_n, err) = jax.lax.cond(
         to_pool.any() | active.any(), with_pool, lambda op: op, pool_ops)
 
-    in_pool = pool_idx >= 0
-    # pooled slots keep a neutral base word; the rest repack
+    # pooled slots keep neutral base lanes (an untouched one already
+    # does); the rest of the touched slots repack
+    in_pool = pool_idx[at] >= 0
     base = jnp.where(
         in_pool, jnp.uint64(_neutral_base(widths)),
         _pack_base(jnp.clip(n_cnt, 0, (1 << cb) - 1),
@@ -469,8 +421,9 @@ def _counter_merge(state: PackedCounterState, segs, last_at,
                      jnp.clip(n_max, _MM_LO, _MM_HI)))
 
     return PackedCounterState(
-        base=base, sq=jnp.where(in_pool, jnp.int64(0), n_sq),
-        minmax=minmax,
+        base=put(state.base, base),
+        sq=put(state.sq, jnp.where(in_pool, jnp.int64(0), n_sq)),
+        minmax=put(state.minmax, minmax),
         pool_cnt=pool_cnt, pool_sum=pool_sum, pool_sq=pool_sq,
         pool_min=pool_min, pool_max=pool_max, pool_owner=pool_owner,
         pool_idx=pool_idx, pool_n=pool_n, err=err, last_at=last_at)
@@ -481,7 +434,7 @@ def _counter_merge(state: PackedCounterState, segs, last_at,
     static_argnames=("num_windows", "capacity", "widths", "promote_k"))
 def counter_ingest(
     state: PackedCounterState,
-    idx: jnp.ndarray,     # i64 (N,) flat window*C+slot; == W*C drops
+    idx: jnp.ndarray,     # i64 (N,) flat window*C+slot; == W*C+C drops
     values: jnp.ndarray,  # i64 (N,)
     times: jnp.ndarray,   # i64 (N,)
     num_windows: int,
@@ -491,11 +444,11 @@ def counter_ingest(
 ) -> PackedCounterState:
     wc = num_windows * capacity
     seg = _segment_view(idx, wc + capacity)
-    d, d_tmax = _counter_batch_segments(_stats_view(seg, wc), seg,
-                                        values, times)
-    last_at = _merge_last_at(state.last_at, d_tmax, num_windows, capacity)
-    return _counter_merge(state, d, last_at, num_windows, capacity,
-                          widths, promote_k)
+    scanned = _seg_scan(seg, _counter_scan_lanes(values[seg.perm]),
+                        _counter_scan_combine)
+    last_at = _merge_last_at(state.last_at, idx, times, wc)
+    return _counter_merge(state, seg, scanned, last_at, wc, widths,
+                          promote_k)
 
 
 def _counter_lanes(state: PackedCounterState, widths: tuple):
@@ -663,68 +616,49 @@ def gauge_init(num_windows: int, capacity: int) -> PackedGaugeState:
 
 
 def _gauge_scan_lanes(v: jnp.ndarray, k: jnp.ndarray, t: jnp.ndarray):
-    """Scan input lanes for a gauge value column and its keys: (sum,
-    sum_sq, min, max, tmax, last).  Sum lanes exclude NaN (count still
-    carries it) but pass +/-inf through — tree-order f64 addition
+    """Scan input lanes for a gauge value column and its keys: (count,
+    sum, sum_sq, min, max, tmax, last).  Sum lanes exclude NaN (count
+    still carries it) but pass +/-inf through — tree-order f64 addition
     reproduces the scatter path's inf/NaN semantics natively and keeps
     the within-segment rounding at ~log2(N) ulps of the segment's own
     magnitude (no cross-segment prefix cancellation)."""
     nan = (k > KEY_PINF) | (k < KEY_NINF)
     safe = jnp.where(nan, 0.0, v)
-    return (safe, safe * safe, jnp.where(nan, KEY_PINF, k),
-            jnp.where(nan, KEY_NINF, k), t, k)
+    return (jnp.ones_like(t), safe, safe * safe,
+            jnp.where(nan, KEY_PINF, k), jnp.where(nan, KEY_NINF, k), t, k)
 
 
 def _gauge_scan_combine(a, b):
-    """(sum, sum_sq, min, max, tmax, last) segmented combine; last
-    is the value of the strictly-greatest time (sorted ties = first
-    arrival wins)."""
+    """(count, sum, sum_sq, min, max, tmax, last) segmented combine;
+    last is the value of the strictly-greatest time (sorted ties =
+    first arrival wins)."""
     return (
         a[0] + b[0],
         a[1] + b[1],
-        jnp.minimum(a[2], b[2]),
-        jnp.maximum(a[3], b[3]),
+        a[2] + b[2],
+        jnp.minimum(a[3], b[3]),
         jnp.maximum(a[4], b[4]),
-        jnp.where(b[4] > a[4], b[5], a[5]),
+        jnp.maximum(a[5], b[5]),
+        jnp.where(b[5] > a[5], b[6], a[6]),
     )
 
 
-def _gauge_gather(sview: _Segments, seg: _Segments, scanned: tuple):
-    """Per-slot gauge aggregates from the raw scanned lanes."""
-    s_sum, s_sq, s_min, s_max, s_t, s_lastk = scanned
-    d_sum = jnp.where(sview.has, _at_ends(sview.end, s_sum), 0.0)
-    d_sq = jnp.where(sview.has, _at_ends(sview.end, s_sq), 0.0)
-    d_min = jnp.where(sview.has, _at_ends(sview.end, s_min), KEY_PINF)
-    d_max = jnp.where(sview.has, _at_ends(sview.end, s_max), KEY_NINF)
-    d_t = jnp.where(sview.has, _at_ends(sview.end, s_t), I64_MIN)
-    d_lastk = _at_ends(sview.end, s_lastk)
-    d_tmax = jnp.where(seg.has, _at_ends(seg.end, s_t), I64_MIN)
-    return (sview.cnt, d_sum, d_sq, d_min, d_max, d_t, d_lastk), d_tmax
-
-
-def _gauge_batch_segments(sview: _Segments, seg: _Segments,
-                          values: jnp.ndarray, keys: jnp.ndarray,
-                          times: jnp.ndarray):
-    scanned = _seg_scan(
-        seg, _gauge_scan_lanes(values[seg.perm], keys[seg.perm],
-                               times[seg.perm]),
-        _gauge_scan_combine)
-    return _gauge_gather(sview, seg, scanned)
-
-
-def _gauge_merge(state: PackedGaugeState, segs, last_at,
-                 num_windows: int, capacity: int) -> PackedGaugeState:
-    d_cnt, d_sum, d_sq, d_min, d_max, d_t, d_lastk = segs
-    has = d_cnt > 0
-    replace = has & (d_t > state.last_time)
+def _gauge_merge(state: PackedGaugeState, seg: _Segments, scanned,
+                 last_at, wc: int) -> PackedGaugeState:
+    d_cnt, d_sum, d_sq, d_min, d_max, d_t, d_lastk = scanned
+    _live, at, put = _tail_rows(seg, wc)
+    last_time = state.last_time[at]
+    replace = d_t > last_time
     return PackedGaugeState(
-        sum=jnp.where(has, state.sum + d_sum, state.sum),
-        sum_sq=jnp.where(has, state.sum_sq + d_sq, state.sum_sq),
-        count=state.count + d_cnt,
-        min_key=jnp.minimum(state.min_key, d_min),
-        max_key=jnp.maximum(state.max_key, d_max),
-        last_key=jnp.where(replace, d_lastk, state.last_key),
-        last_time=jnp.where(replace, d_t, state.last_time),
+        sum=put(state.sum, state.sum[at] + d_sum),
+        sum_sq=put(state.sum_sq, state.sum_sq[at] + d_sq),
+        count=put(state.count, state.count[at] + d_cnt),
+        min_key=put(state.min_key, jnp.minimum(state.min_key[at], d_min)),
+        max_key=put(state.max_key, jnp.maximum(state.max_key[at], d_max)),
+        last_key=put(state.last_key,
+                     jnp.where(replace, d_lastk, state.last_key[at])),
+        last_time=put(state.last_time,
+                      jnp.where(replace, d_t, last_time)),
         last_at=last_at,
     )
 
@@ -734,7 +668,7 @@ def _gauge_merge(state: PackedGaugeState, segs, last_at,
     static_argnames=("num_windows", "capacity"))
 def gauge_ingest(
     state: PackedGaugeState,
-    idx: jnp.ndarray,     # i64 (N,) flat; == W*C drops
+    idx: jnp.ndarray,     # i64 (N,) flat; == W*C+C drops
     values: jnp.ndarray,  # f64 (N,)
     keys: jnp.ndarray,    # i64 (N,) orderable_f64(values), host-made
     times: jnp.ndarray,   # i64 (N,)
@@ -743,10 +677,12 @@ def gauge_ingest(
 ) -> PackedGaugeState:
     wc = num_windows * capacity
     seg = _segment_view(idx, wc + capacity)
-    d, d_tmax = _gauge_batch_segments(_stats_view(seg, wc), seg,
-                                      values, keys, times)
-    last_at = _merge_last_at(state.last_at, d_tmax, num_windows, capacity)
-    return _gauge_merge(state, d, last_at, num_windows, capacity)
+    scanned = _seg_scan(
+        seg, _gauge_scan_lanes(values[seg.perm], keys[seg.perm],
+                               times[seg.perm]),
+        _gauge_scan_combine)
+    last_at = _merge_last_at(state.last_at, idx, times, wc)
+    return _gauge_merge(state, seg, scanned, last_at, wc)
 
 
 @functools.partial(jax.jit, static_argnames=("capacity",))
@@ -834,7 +770,7 @@ def gauge_clear_slots(state: PackedGaugeState, slots: jnp.ndarray,
 def rollup_ingest(
     cstate: PackedCounterState,
     gstate: PackedGaugeState,
-    idx: jnp.ndarray,      # i64 (N,) flat; == W*C drops
+    idx: jnp.ndarray,      # i64 (N,) flat; == W*C+C drops
     cvalues: jnp.ndarray,  # i64 (N,)
     gvalues: jnp.ndarray,  # f64 (N,)
     gkeys: jnp.ndarray,    # i64 (N,) orderable_f64(gvalues), host-made
@@ -846,34 +782,23 @@ def rollup_ingest(
 ):
     wc = num_windows * capacity
     seg = _segment_view(idx, wc + capacity)
-    sview = _stats_view(seg, wc)
-    cv = cvalues[seg.perm]
-    gv = gvalues[seg.perm]
-    gk = gkeys[seg.perm]
-    t = times[seg.perm]
-    c_sum, c_sq, wide = _counter_sums(sview, cv)
-    (d_wide,) = _seg_flag_counts(sview, (wide,))
+    c_lanes = _counter_scan_lanes(cvalues[seg.perm])
+    g_lanes = _gauge_scan_lanes(gvalues[seg.perm], gkeys[seg.perm],
+                                times[seg.perm])
+    nc = len(c_lanes)
 
-    # ONE scan serves both arenas: counter min/max lanes prepended to
-    # the gauge lane set (which shares the time column for last/last_at)
+    # ONE scan serves both arenas: the counter lanes prepended to the
+    # gauge lane set
     def combine(a, b):
-        return (jnp.minimum(a[0], b[0]), jnp.maximum(a[1], b[1])) \
-            + _gauge_scan_combine(a[2:], b[2:])
+        return (_counter_scan_combine(a[:nc], b[:nc])
+                + _gauge_scan_combine(a[nc:], b[nc:]))
 
-    scanned = _seg_scan(seg, (cv, cv) + _gauge_scan_lanes(gv, gk, t),
-                        combine)
-    c_min = jnp.where(sview.has, _at_ends(sview.end, scanned[0]),
-                      I64_MAX)
-    c_max = jnp.where(sview.has, _at_ends(sview.end, scanned[1]),
-                      I64_MIN)
-    gd, d_tmax = _gauge_gather(sview, seg, scanned[2:])
-
-    c_last = _merge_last_at(cstate.last_at, d_tmax, num_windows, capacity)
-    g_last = _merge_last_at(gstate.last_at, d_tmax, num_windows, capacity)
-    cd = (sview.cnt, c_sum, c_sq, c_min, c_max, d_wide)
-    return (_counter_merge(cstate, cd, c_last, num_windows, capacity,
-                           widths, promote_k),
-            _gauge_merge(gstate, gd, g_last, num_windows, capacity))
+    scanned = _seg_scan(seg, c_lanes + g_lanes, combine)
+    return (_counter_merge(cstate, seg, scanned[:nc],
+                           _merge_last_at(cstate.last_at, idx, times, wc),
+                           wc, widths, promote_k),
+            _gauge_merge(gstate, seg, scanned[nc:],
+                         _merge_last_at(gstate.last_at, idx, times, wc), wc))
 
 
 # ---------------------------------------------------------------------------

@@ -9,6 +9,8 @@ sum envelope arbitrarily, so it is compared against a stdev recomputed
 from the packed path's own moments instead of a fixed rtol.
 """
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -493,3 +495,173 @@ class TestStdevClamp:
                   jnp.asarray(vals), jnp.full(4, T0, jnp.int64))
         lanes = np.asarray(ga.consume(0)[0])
         assert np.isfinite(lanes[0, 7]) and lanes[0, 7] >= 0.0
+
+
+# ---------------------------------------------------------------------------
+# The batch domain: an ingest costs its batch, not the arena
+# ---------------------------------------------------------------------------
+
+_SW, _SN = 8, 4096  # W = 8, so that W*C exceeds N at C = 2^10 too
+
+
+def _sub_jaxprs(params):
+    for v in params.values():
+        for j in (v if isinstance(v, (tuple, list)) else (v,)):
+            j = getattr(j, "jaxpr", j)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def _leaf_eqns(jaxpr):
+    """Every equation that computes something: the bodies of pjit /
+    cond / custom calls walked, the containers themselves not counted
+    (a cond hands the untouched pool_idx lane through)."""
+    for eqn in jaxpr.eqns:
+        subs = list(_sub_jaxprs(eqn.params))
+        if subs:
+            for sub in subs:
+                yield from _leaf_eqns(sub)
+        else:
+            yield eqn
+
+
+@functools.lru_cache(maxsize=None)
+def _ingest_census(kind, C):
+    """(leaf equation count, [(primitive, shape) of every output of
+    W*C or more elements]) of one ingest's jaxpr; nothing is allocated."""
+    cs = jax.eval_shape(lambda: packed.counter_init(_SW, C))
+    gs = jax.eval_shape(lambda: packed.gauge_init(_SW, C))
+    i64 = jax.ShapeDtypeStruct((_SN,), jnp.int64)
+    f64 = jax.ShapeDtypeStruct((_SN,), jnp.float64)
+    if kind == "counter":
+        jp = jax.make_jaxpr(lambda s, i, v, t: packed.counter_ingest(
+            s, i, v, t, _SW, C))(cs, i64, i64, i64)
+    elif kind == "gauge":
+        jp = jax.make_jaxpr(lambda s, i, v, k, t: packed.gauge_ingest(
+            s, i, v, k, t, _SW, C))(gs, i64, f64, i64, i64)
+    else:
+        jp = jax.make_jaxpr(
+            lambda c, g, i, cv, gv, k, t: packed.rollup_ingest(
+                c, g, i, cv, gv, k, t, _SW, C))(
+                    cs, gs, i64, i64, f64, i64, i64)
+    eqns = list(_leaf_eqns(jp.jaxpr))
+    big = [(e.primitive.name, o.aval.shape) for e in eqns
+           for o in e.outvars if int(np.prod(o.aval.shape)) >= _SW * C]
+    return len(eqns), big
+
+
+class TestBatchDomain:
+    """The merge never leaves the sorted batch: what keeps an ingest's
+    cost from growing back with the arena."""
+
+    # state lanes of W*C elements: gauge sum / sum_sq / count / min /
+    # max / last_key / last_time; counter base / sq / minmax and, under
+    # the pool's cond, pool_idx
+    STATE_SCATTERS = {"gauge": 7, "counter": 4, "rollup": 11}
+
+    @pytest.mark.parametrize("C", [1 << 10, 1 << 16])
+    @pytest.mark.parametrize("kind", ["counter", "gauge", "rollup"])
+    def test_no_arena_sized_compute(self, kind, C):
+        n_eqns, big = _ingest_census(kind, C)
+        # nothing of W*C or more elements is computed but the scatters
+        # into the state's own lanes (last_at's (C,) max is smaller)
+        assert all(p.startswith("scatter") and s == (_SW * C,)
+                   for p, s in big), big
+        assert len(big) == self.STATE_SCATTERS[kind], big
+        other = (1 << 10) if C == (1 << 16) else (1 << 16)
+        assert n_eqns == _ingest_census(kind, other)[0]
+
+
+def _edge_batches(name):
+    """(W, C, counter-arena kwargs, batches, expected err bits, pool
+    rows) of one batch-domain edge case; a batch is (windows, slots,
+    counter values, gauge values, times)."""
+    rng = np.random.default_rng(len(name))
+    i32 = lambda a: np.asarray(a, np.int32)
+
+    def batch(windows, slots, cvals=None, gvals=None, t0=0):
+        n = len(slots)
+        if cvals is None:
+            cvals = rng.integers(-2000, 2000, n)
+        if gvals is None:
+            gvals = np.round(rng.uniform(-50, 50, n), 3)
+        return (i32(windows), i32(slots), np.asarray(cvals, np.int64),
+                np.asarray(gvals, np.float64),
+                T0 + t0 + rng.integers(0, SEC, n))
+
+    narrow = dict(pool_capacity=8, widths=(4, 6))
+    spread = batch(rng.integers(0, 2, 48), rng.integers(0, 16, 48))
+    if name.startswith("one_slot_n"):
+        n = int(name[len("one_slot_n"):])
+        g = np.round(rng.uniform(-50, 50, n), 3)
+        g[::7] = np.nan
+        return 2, 16, {}, [batch(np.ones(n), np.full(n, 5), gvals=g)], 0, 0
+    if name == "all_dropped":
+        return 2, 16, {}, [spread, batch(
+            rng.choice([-1, 2], 32), rng.choice([-1, 16, 17], 32),
+            t0=5 * SEC)], 0, 0
+    if name == "ghost_rows_only":
+        return 2, 16, {}, [spread, batch(
+            rng.choice([-1, 2], 32), rng.integers(0, 16, 32),
+            t0=5 * SEC)], 0, 0
+    if name == "slot_under_two_windows":
+        return 2, 16, {}, [batch([0, 1, 0, 1, 1, 0], [3, 3, 3, 3, 9, 9])], \
+            0, 0
+    if name == "promotion_and_active_slot":
+        hot = batch(np.zeros(20), np.full(20, 2), cvals=np.ones(20))
+        both = batch(np.zeros(45), [2] * 20 + [9] * 20 + [4] * 5,
+                     cvals=np.ones(45), t0=SEC)
+        return 1, 16, narrow, [hot, both], 0, 2
+    if name == "all_wide_virgin_slot":
+        wide = np.asarray([1 << 40, -(1 << 41), (1 << 40) + 5, 1 << 33])
+        return 1, 16, dict(pool_capacity=8), [
+            batch(np.zeros(4), np.full(4, 7), cvals=wide)], 0, 1
+    if name == "more_than_k_promotions":
+        slots = np.repeat(np.arange(5), 20)
+        return 1, 16, dict(promote_k=2, **narrow), [
+            batch(np.zeros(100), slots, cvals=np.ones(100))], \
+            packed._ERR_PROMOTE_K, 2
+    if name == "pool_full":
+        slots = np.repeat(np.arange(3), 20)
+        return 1, 16, dict(pool_capacity=1, widths=(4, 6)), [
+            batch(np.zeros(60), slots, cvals=np.ones(60))], \
+            packed._ERR_POOL_FULL, 1
+    raise KeyError(name)
+
+
+class TestBatchDomainEdges:
+    @pytest.mark.parametrize("name", [
+        "one_slot_n1", "one_slot_n64", "all_dropped", "ghost_rows_only",
+        "slot_under_two_windows", "promotion_and_active_slot",
+        "all_wide_virgin_slot", "more_than_k_promotions", "pool_full"])
+    def test_edge_case_vs_f64_oracle(self, name):
+        W, C, ckw, batches, err, pool_n = _edge_batches(name)
+        ca, ga = arena.CounterArena(W, C), arena.GaugeArena(W, C)
+        pca = packed.PackedCounterArena(W, C, **ckw)
+        pga = packed.PackedGaugeArena(W, C)
+        for windows, slots, cvals, gvals, times in batches:
+            before = jax.tree.map(np.asarray, (pca.state, pga.state))
+            for a, vals in ((ca, cvals), (pca, cvals),
+                            (ga, gvals), (pga, gvals)):
+                a.ingest(jnp.asarray(windows), jnp.asarray(slots),
+                         vals if a is pga else jnp.asarray(vals),
+                         jnp.asarray(times))
+        assert int(pca.state.err) == err
+        assert int(pca.state.pool_n) == pool_n
+        for f64_arena, packed_arena in ((ca, pca), (ga, pga)):
+            np.testing.assert_array_equal(
+                np.asarray(f64_arena.state.last_at),
+                np.asarray(packed_arena.state.last_at))
+        _assert_gauge_parity(ga, pga, W)
+        if not err:  # an err bit marks the clipped rows as unreliable
+            _assert_counter_parity(ca, pca, W)
+        if name in ("all_dropped", "ghost_rows_only"):
+            # the last batch wrote no statistic: every state lane but
+            # the expiry column keeps its bits
+            after = jax.tree.map(np.asarray, (pca.state, pga.state))
+            for b, a in zip(before, after):
+                for lane in b._fields:
+                    moved = not np.array_equal(getattr(b, lane),
+                                               getattr(a, lane))
+                    assert moved == (lane == "last_at"
+                                     and name == "ghost_rows_only"), lane

@@ -204,13 +204,17 @@ class TestServedAggregatorPrograms:
         shapes = jax.eval_shape(lambda: init(self.W, self.C))
         return jax.tree_util.tree_map(lambda a: A(a.shape, a.dtype), shapes)
 
-    def test_counter_ingest(self, one_chip):
+    # small batches too: a jnp.nonzero(size=K) with K cut to a batch of
+    # N <= 1,024, under the overflow pool's cond, ran the TPU compiler
+    # out of scoped VMEM in its 64-bit cumsum (PR 28: found on the chip)
+    @pytest.mark.parametrize("n", [N, 1024, 256])
+    def test_counter_ingest(self, one_chip, n):
         from m3_tpu.aggregator import packed
 
         _compile(packed.counter_ingest, one_chip,
                  self._state(packed.counter_init),
-                 A((self.N,), jnp.int64), A((self.N,), jnp.int64),
-                 A((self.N,), jnp.int64),
+                 A((n,), jnp.int64), A((n,), jnp.int64),
+                 A((n,), jnp.int64),
                  num_windows=self.W, capacity=self.C)
 
     def test_counter_consume(self, one_chip):
