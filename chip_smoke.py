@@ -712,15 +712,12 @@ def check_impls() -> dict:
     import jax
     import jax.numpy as jnp
 
-    from m3_tpu.aggregator import arena
     from m3_tpu.encoding import m3tsz_jax as mj
     from m3_tpu.parallel import pallas_decode, pallas_encode
 
     chains = mj.resolved_chains()
     out = {"place": mj.resolved_place(), "chains": chains,
            "extract": mj._resolved_extract(chains),
-           "ingest": arena.ingest_impl(),
-           "layout": arena.resolved_arena_layout(),
            # what this process tree compiled from native/*.cc (a failed
            # build raises at first use; nothing is loaded as found)
            "native_built": os.environ.get("M3_NATIVE_BUILT", "")}

@@ -24,12 +24,16 @@ bit-faithful host CM stream lives in ``quantile_cm.py`` for parity tests.
 All 22 aggregation outputs (src/metrics/aggregation/type.go:34-55) are
 computed as lanes of a (C, L) matrix at window drain; the caller masks
 lanes by each slot's compressed AggregationID.
+
+The f64 arenas of this module are the reference the packed arenas
+(``packed.py``, what ``make_arenas`` builds and every served path runs)
+are tested against, not a setting: ``make_arenas(layout="f64")`` builds
+them for the tests and for restoring a checkpoint an f64 list wrote.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 from typing import NamedTuple
 
 import jax
@@ -62,139 +66,10 @@ def raw(jitted):
     return getattr(jitted, "__wrapped__", jitted)
 
 
-# ---------------------------------------------------------------------------
-# Ingest implementation selection, M3_ARENA_INGEST=scatter|pallas
-# or set_ingest_impl():
-#   scatter — XLA scatter ops (default; fastest on XLA-CPU).
-#   pallas  — binned segment reduction kernel (parallel/pallas_ingest.py):
-#             built for TPU, where scatter measured ~1us/element at C=1M
-#             (round 5, window 3), but its formulation does not compile
-#             for one (PR 22), so on a TPU the name is REFUSED with a
-#             clear error; off the chip it runs in interpret mode and
-#             wins on CPU when slot collisions serialize the scatter AND
-#             the flat arena (W*C) is moderate.
-# (A third sort/scan/gather impl — parallel/sorted_ingest.py — was
-# deleted in round 6: round 5 measured it at 0.45-0.50x of scatter on
-# CPU and it was never validated faster on real TPU hardware.  Its
-# generic segmented-scan helpers live on in parallel/segmented.py.)
-# The choice binds at TRACE time, so set_ingest_impl clears the arena
-# jit caches — jits composed elsewhere via raw() keep whatever impl
-# they traced with.
-# ---------------------------------------------------------------------------
-
-INGEST_IMPLS = ("scatter", "pallas")
-_INGEST_IMPL = (os.environ.get("M3_ARENA_INGEST", "").strip().lower()
-                or "scatter")
-if _INGEST_IMPL not in INGEST_IMPLS:
-    raise ValueError(
-        f"M3_ARENA_INGEST={_INGEST_IMPL!r}: must be one of {INGEST_IMPLS} "
-        "(a typo silently running scatter would invalidate the very "
-        "measurement the flag exists to apply)")
+LAYOUTS = ("packed", "f64")
 
 
-def _usable(impl: str) -> str:
-    """`pallas` is refused on a TPU: Mosaic rejects the kernel's (1, N)
-    blocks and has no f64, so there it could only fail or be stepped
-    down from silently."""
-    if impl == "pallas" and jax.default_backend() == "tpu":
-        raise ValueError(
-            "arena ingest impl 'pallas' does not compile for a TPU "
-            "(parallel/pallas_ingest.py: (1, N) blocks, f64 operands); "
-            "use M3_ARENA_INGEST=scatter / coordinator.arena_ingest: "
-            "scatter")
-    return impl
-
-
-def ingest_impl() -> str:
-    """The selected impl (the env's choice is checked here, at use)."""
-    return _usable(_INGEST_IMPL)
-
-
-# Jitted programs that COMPOSE raw(ingest) ops and must be re-traced
-# when the impl flips (e.g. parallel/sharded_agg's sharded programs).
-# Modules register theirs via register_ingest_consumer at import time.
-_INGEST_CONSUMERS: list = []
-
-
-def register_ingest_consumer(jitted) -> None:
-    _INGEST_CONSUMERS.append(jitted)
-
-
-def set_ingest_impl(impl: str) -> None:
-    global _INGEST_IMPL
-    if impl not in INGEST_IMPLS:
-        raise ValueError(f"unknown ingest impl {impl!r}")
-    _INGEST_IMPL = _usable(impl)
-    for f in (counter_ingest, gauge_ingest, timer_ingest,
-              *_INGEST_CONSUMERS):
-        try:
-            f.clear_cache()
-        except AttributeError:  # a raw (un-jitted) function
-            pass
-
-
-# ---------------------------------------------------------------------------
-# Arena layout selection, M3_ARENA_LAYOUT=packed|f64|auto (default auto)
-# or set_arena_layout():
-#   packed — the sort/segment formulation + adaptive-width counter state
-#            (aggregator/packed.py): one u64 key sort per ingest batch,
-#            a merge in the sorted batch's domain (one batch-sized
-#            scatter a state lane).  Counter stats exact,
-#            gauge sum/sum_sq within 1e-6 of the f64 path (segmented
-#            tree adds), timer value lanes at f32 (packed32) precision.
-#   f64    — the original scatter arenas in THIS module: the parity
-#            oracle, bit-exact reference semantics throughout.
-#   auto   — packed (one sort and ~a dozen lane scatters of segment
-#            TAILS a batch, against the oracle's 3-key lex sort and a
-#            scatter per statistic of every sample).
-# Resolution happens on the HOST at arena construction (tracewatch
-# contract: nothing reads the environment under a tracer) — engine
-# arenas bind their layout at __init__, the sharded program takes it as
-# a static argument.
-# ---------------------------------------------------------------------------
-
-LAYOUTS = ("packed", "f64", "auto")
-_LAYOUT = (os.environ.get("M3_ARENA_LAYOUT", "").strip().lower()
-           or "auto")
-if _LAYOUT not in LAYOUTS:
-    raise ValueError(
-        f"M3_ARENA_LAYOUT={_LAYOUT!r}: must be one of {LAYOUTS} "
-        "(a typo silently running the default would invalidate the very "
-        "comparison the flag exists to make)")
-
-
-def arena_layout() -> str:
-    """The CONFIGURED layout (may be 'auto'); see resolved_arena_layout."""
-    return _LAYOUT
-
-
-def resolved_arena_layout() -> str:
-    """'auto' resolves to 'packed' on every backend (the sort/segment
-    formulation: aggregator/packed.py).  'f64' remains the explicit
-    parity-oracle escape hatch."""
-    return "packed" if _LAYOUT == "auto" else _LAYOUT
-
-
-def set_arena_layout(layout: str) -> None:
-    """Host-side layout override (bench/tests).  Arenas bind layout at
-    construction, so this affects arenas built AFTER the call."""
-    global _LAYOUT
-    if layout not in LAYOUTS:
-        raise ValueError(f"unknown arena layout {layout!r}")
-    _LAYOUT = layout
-
-
-def resolve_layout_arg(layout: str | None) -> str:
-    """Resolve a per-call/per-engine layout argument to a CONCRETE
-    layout: None/"" follow the configured seam, an explicit "auto"
-    resolves to packed, and anything else must be a known layout — a
-    typo silently selecting some default would invalidate the very
-    comparison the seam exists to make (the env guard's rationale,
-    applied to the programmatic path too)."""
-    if not layout:
-        return resolved_arena_layout()
-    if layout == "auto":
-        return "packed"
+def check_layout(layout: str) -> str:
     if layout not in LAYOUTS:
         raise ValueError(
             f"unknown arena layout {layout!r}: must be one of {LAYOUTS}")
@@ -203,11 +78,12 @@ def resolve_layout_arg(layout: str | None) -> str:
 
 def make_arenas(num_windows: int, capacity: int, sample_capacity: int,
                 quantiles: tuple, timer_packed32: bool = False,
-                layout: str | None = None):
-    """(counter, gauge, timer) arenas for a layout (None = resolved
-    seam) — the one construction seam engine.py and tests share."""
-    layout = resolve_layout_arg(layout)
-    if layout == "packed":
+                layout: str = "packed"):
+    """(counter, gauge, timer) arenas of one layout: "packed"
+    (aggregator/packed.py, what runs) or "f64" (this module's
+    reference arenas) — the one place an arena's formulation is
+    chosen."""
+    if check_layout(layout) == "packed":
         from m3_tpu.aggregator import packed
 
         return (packed.PackedCounterArena(num_windows, capacity),
@@ -220,24 +96,9 @@ def make_arenas(num_windows: int, capacity: int, sample_capacity: int,
                        quantiles, packed32=timer_packed32))
 
 
-def _seg3(sum_col, sq_col, cnt_col, idx, values, impl: str | None = None):
-    """The sum / sum² / count accumulation every arena shares, routed
-    through the configured implementation.  ``idx`` >= len(sum_col)
-    drops (the sentinel contract) on both paths.  The pallas path
-    computes all three lanes in ONE batch sweep
-    (pallas_segment_moments: the hit mask is shared).  ``impl`` pins
-    the choice explicitly (the arena wrappers thread it as a STATIC
-    jit argument so the device guard's fallback — pallas → scatter —
-    needs no cache clearing and never retraces); None keeps the
-    trace-time resolved seam for raw() composition (sharded_agg)."""
-    if (impl or ingest_impl()) == "pallas":
-        from m3_tpu.parallel import pallas_ingest as pi
-
-        n_out = sum_col.shape[0]
-        s, c, sq = pi.segment_moments_chunked(
-            idx.astype(jnp.int32), values, n_out)
-        return (sum_col + s, sq_col + sq,
-                cnt_col + c.astype(cnt_col.dtype))
+def _seg3(sum_col, sq_col, cnt_col, idx, values):
+    """The sum / sum² / count accumulation every f64 arena shares.
+    ``idx`` >= len(sum_col) drops (the sentinel contract)."""
     return (sum_col.at[idx].add(values, mode="drop"),
             sq_col.at[idx].add(values * values, mode="drop"),
             cnt_col.at[idx].add(1, mode="drop"))
@@ -259,8 +120,8 @@ def flat_window_index(windows, slots, num_windows: int, capacity: int):
     out-of-ring windows AND out-of-range slots map to the drop sentinel
     W*C.  Without the slot check, a valid window with slot >= C would
     compute w*C + slot inside window w+1's region — the exact aliasing
-    timer_ingest was fixed for; sentineling here keeps every ingest
-    impl parity on ANY input (including pad_slots sentinels and
+    timer_ingest was fixed for; sentineling here keeps both layouts
+    at parity on ANY input (including pad_slots sentinels and
     negative slots)."""
     oob = ((windows < 0) | (windows >= num_windows)
            | (slots < 0) | (slots >= capacity))
@@ -273,9 +134,9 @@ def _sanitize_slots(slots, capacity: int):
     """Slots for the last_at scatter: a NEGATIVE slot would numpy-wrap
     under mode='drop' (a lowering artifact — it would bump slot C+s's
     expiry), so map it to the drop sentinel C; slots >= C already fall
-    out of the (C,) column's range and drop.  Keeps the scatter paths
-    on the package-wide contract (invalid indices DROP — also pinned
-    by xla_segment_ingest and the pallas kernel)."""
+    out of the (C,) column's range and drop.  Keeps the scatters on
+    the package-wide contract (invalid indices DROP — also pinned by
+    xla_segment_ingest)."""
     return jnp.where(slots < 0, capacity, slots)
 
 
@@ -342,18 +203,16 @@ def counter_init(num_windows: int, capacity: int) -> CounterState:
     )
 
 
-@functools.partial(jax.jit, donate_argnums=0, static_argnames=("impl",))
+@functools.partial(jax.jit, donate_argnums=0)
 def counter_ingest(
     state: CounterState,
     idx: jnp.ndarray,  # i32 (N,) flattened window*C + slot; >= W*C to drop
     slots: jnp.ndarray,  # i32 (N,)
     values: jnp.ndarray,  # i64 (N,)
     times: jnp.ndarray,  # i64 (N,)
-    impl: str | None = None,  # static ingest impl (None = resolved seam)
 ) -> CounterState:
     """Counter.Update for a batch (reference counter.go:53-76)."""
-    s, sq, c = _seg3(state.sum, state.sum_sq, state.count, idx, values,
-                     impl)
+    s, sq, c = _seg3(state.sum, state.sum_sq, state.count, idx, values)
     slot_safe = _sanitize_slots(slots, state.last_at.shape[0])
     return CounterState(
         sum=s,
@@ -466,14 +325,13 @@ def gauge_init(num_windows: int, capacity: int) -> GaugeState:
     )
 
 
-@functools.partial(jax.jit, donate_argnums=0, static_argnames=("impl",))
+@functools.partial(jax.jit, donate_argnums=0)
 def gauge_ingest(
     state: GaugeState,
     idx: jnp.ndarray,  # i32 (N,) flattened; >= W*C to drop
     slots: jnp.ndarray,  # i32 (N,)
     values: jnp.ndarray,  # f64 (N,)
     times: jnp.ndarray,  # i64 (N,)
-    impl: str | None = None,  # static ingest impl (None = resolved seam)
 ) -> GaugeState:
     """Gauge.Update for a batch (reference gauge.go:53-104).
 
@@ -499,8 +357,7 @@ def gauge_ingest(
     take = is_winner & (s_times > old_time)
     widx = jnp.where(take, s_idx, state.last.shape[0])  # OOB -> dropped
 
-    g_s, g_sq, g_c = _seg3(state.sum, state.sum_sq, state.count, idx, safe,
-                           impl)
+    g_s, g_sq, g_c = _seg3(state.sum, state.sum_sq, state.count, idx, safe)
     slot_safe = _sanitize_slots(slots, state.last_at.shape[0])
     return GaugeState(
         last=state.last.at[widx].set(s_val, mode="drop"),
@@ -639,8 +496,7 @@ def timer_init(num_windows: int, capacity: int, sample_capacity: int) -> TimerSt
     )
 
 
-@functools.partial(jax.jit, donate_argnums=0,
-                   static_argnames=("capacity", "impl"))
+@functools.partial(jax.jit, donate_argnums=0, static_argnames=("capacity",))
 def timer_ingest(
     state: TimerState,
     windows: jnp.ndarray,  # i32 (N,) window ring index per sample; >= W drops
@@ -648,7 +504,6 @@ def timer_ingest(
     values: jnp.ndarray,  # f64 (N,)
     times: jnp.ndarray,  # i64 (N,)
     capacity: int,
-    impl: str | None = None,  # static ingest impl (None = resolved seam)
 ) -> TimerState:
     """Timer.AddBatch for a batch of (slot, value) samples
     (reference timer.go:55-76): moments scatter-add plus sample append.
@@ -669,8 +524,7 @@ def timer_ingest(
     idx = jnp.where(drop, num_w * capacity,
                     windows * capacity + slots)
 
-    t_s, t_sq, t_c = _seg3(state.sum, state.sum_sq, state.count, idx, values,
-                           impl)
+    t_s, t_sq, t_c = _seg3(state.sum, state.sum_sq, state.count, idx, values)
     slot_safe = _sanitize_slots(slots, capacity)
     return TimerState(
         sum=t_s,
@@ -704,9 +558,8 @@ def timer_consume(
     reads at ``ceil(q*n)`` (the reference CM stream targets the same rank
     within eps error — quantile/cm/stream.go:239-247).
 
-    ``packed32`` replaces the two-key (i32 slot, f64 value) lex-sort —
-    the drain's dominant cost, and software-emulated f64 compares on
-    TPU — with ONE i64 key per sample: ``slot << 32 | orderable(f32)``
+    ``packed32`` replaces the two-key (i32 slot, f64 value) lex-sort
+    with ONE i64 key per sample: ``slot << 32 | orderable(f32)``
     (sign-flip trick keeps float order in unsigned bit order).
     Quantile reads decode the f32 back, so quantile/min/max lanes carry
     f32 precision (~1e-7 relative) — four orders tighter than the
@@ -875,23 +728,16 @@ class _TimerLanesMixin:
 
 
 def _guarded_ingest(call):
-    """Run one arena ingest behind the device guard.  The fallback
-    re-issues the call with the scatter (jnp) ingest impl as a STATIC
-    argument — under the pallas impl (off the chip only) that steps
-    down from the kernel with no cache clearing and no retrace of the
-    primary; under scatter primary and fallback coincide and the re-run
-    simply skips the device faultpoints (the injected-fault contract).  A failure that
+    """Run one arena ingest behind the device guard.  An ingest has one
+    formulation, so the fallback is the same program with the device
+    faultpoints skipped (the injected-fault contract).  A failure that
     persists through the fallback raises typed to the engine."""
-    return devguard.run_guarded(
-        "arena.ingest", lambda: call(ingest_impl()),
-        lambda: call("scatter"))
+    return devguard.run_guarded("arena.ingest", call, call)
 
 
 def _guarded_consume(call):
     """Arena window drains re-probe/fall back like ingests; the
-    fallback is the same jnp program with the faultpoints skipped (the
-    consume path has no lower impl to step down to — its lanes are
-    already the jnp formulation)."""
+    fallback is the same program with the faultpoints skipped."""
     def primary():
         out = call()
         devguard.transfer_point("arena.consume")
@@ -910,6 +756,8 @@ def _guarded_state_op(call):
 class CounterArena(_ScalarLanesMixin):
     """Counter slots over a W-window ring (reference counter.go semantics)."""
 
+    layout = "f64"
+
     def __init__(self, num_windows: int, capacity: int):
         self.num_windows = num_windows
         self.capacity = capacity
@@ -921,9 +769,9 @@ class CounterArena(_ScalarLanesMixin):
 
     def ingest(self, windows, slots, values, times):
         idx = flat_window_index(windows, slots, self.num_windows, self.capacity)
-        self.state = _guarded_ingest(lambda impl: counter_ingest(
+        self.state = _guarded_ingest(lambda: counter_ingest(
             self.state, idx, slots, jnp.asarray(values).astype(jnp.int64),
-            times, impl=impl))
+            times))
 
     def consume(self, window: int):
         return _guarded_consume(lambda: counter_consume(
@@ -942,6 +790,8 @@ class CounterArena(_ScalarLanesMixin):
 
 
 class GaugeArena(_ScalarLanesMixin):
+    layout = "f64"
+
     def __init__(self, num_windows: int, capacity: int):
         self.num_windows = num_windows
         self.capacity = capacity
@@ -953,9 +803,9 @@ class GaugeArena(_ScalarLanesMixin):
 
     def ingest(self, windows, slots, values, times):
         idx = flat_window_index(windows, slots, self.num_windows, self.capacity)
-        self.state = _guarded_ingest(lambda impl: gauge_ingest(
+        self.state = _guarded_ingest(lambda: gauge_ingest(
             self.state, idx, slots, jnp.asarray(values).astype(jnp.float64),
-            times, impl=impl))
+            times))
 
     def consume(self, window: int):
         return _guarded_consume(lambda: gauge_consume(
@@ -974,6 +824,7 @@ class GaugeArena(_ScalarLanesMixin):
 
 
 class TimerArena(_TimerLanesMixin):
+    layout = "f64"
     DEFAULT_QUANTILES = (0.5, 0.95, 0.99)
 
     def __init__(
@@ -1021,14 +872,13 @@ class TimerArena(_TimerLanesMixin):
         needed = int(new_n.max())
         if needed > self.sample_capacity:
             self._grow(needed)
-        self.state = _guarded_ingest(lambda impl: timer_ingest(
+        self.state = _guarded_ingest(lambda: timer_ingest(
             self.state,
             jnp.asarray(windows_np.astype(np.int32)),
             slots,
             jnp.asarray(values).astype(jnp.float64),
             times,
             self.capacity,
-            impl=impl,
         ))
         self._sample_n_host = new_n
 

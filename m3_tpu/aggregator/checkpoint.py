@@ -95,8 +95,7 @@ def list_state(ml) -> Tuple[dict, List[Tuple[str, np.ndarray]]]:
             "timer_sample_capacity": ml.timers.sample_capacity,
             "quantiles": tuple(ml.opts.quantiles),
             "timer_packed32": ml.opts.timer_packed32,
-            "layout": ("packed" if type(ml.counters).__name__.startswith(
-                "Packed") else "f64"),
+            "layout": ml.counters.layout,
         },
         "consumed_until": ml.consumed_until,
         "drops": ml.drops,

@@ -76,21 +76,20 @@ class AggregatorOptions:
     num_windows: int = 2  # ring of open resolution windows
     timer_sample_capacity: int = 1 << 24
     quantiles: tuple = (0.5, 0.95, 0.99)
-    # Timer drain sort mode: packed32 sorts ONE i64 (slot<<32 |
-    # orderable-f32) key instead of the (i32, f64) lex pair — ~4x
-    # faster drain on CPU, avoids software-emulated f64 compares on
-    # TPU; quantile/min/max lanes carry f32 precision (~1e-7 rel on
-    # f32's finite normal range — values beyond ±3.4e38 saturate,
-    # below ~1.2e-38 flush; see arena.timer_consume), moments stay
-    # f64-exact.
+    # Timer drain sort mode of the f64 layout (the packed layout
+    # ignores it): packed32 sorts ONE i64 (slot<<32 | orderable-f32)
+    # key instead of the (i32, f64) lex pair; quantile/min/max lanes
+    # carry f32 precision (~1e-7 rel on f32's finite normal range —
+    # values beyond ±3.4e38 saturate, below ~1.2e-38 flush; see
+    # arena.timer_consume), moments stay f64-exact.
     timer_packed32: bool = False
     # Arena layout: "packed" (sort/segment formulation + adaptive-width
-    # counters, aggregator/packed.py), "f64" (the scatter arenas — the
-    # bit-exact parity oracle), or None = the M3_ARENA_LAYOUT seam
-    # (auto -> packed).  Packed counter stats are exact; gauge
-    # sum/sum_sq and timer value lanes carry the documented <=1e-6
-    # envelopes (see arena.resolved_arena_layout).
-    layout: str | None = None
+    # counters, aggregator/packed.py: what runs) or "f64" (arena.py's
+    # scatter arenas: the tests' reference, and what a checkpoint
+    # written by an f64 list restores into).  Packed counter stats are
+    # exact; gauge sum/sum_sq and timer value lanes carry the
+    # documented <=1e-6 envelopes.
+    layout: str = "packed"
     storage_policies: tuple = (StoragePolicy.parse("10s:2d"),)
     # New-metric creation rate cap, entries/sec across the aggregator
     # (reference entry.go rate limits; 0 = unlimited).  Samples whose

@@ -63,9 +63,8 @@ directly.  Moments are recovered at drain from the sorted buffer via
 the same segmented scan (values carry f32 precision, within the
 established packed32 1e-6 bound; counts are exact).
 
-Everything here is jit-pure: the layout choice (M3_ARENA_LAYOUT) is
-resolved on the host in arena.py and selects these ops at arena
-construction — nothing reads the environment under a tracer.
+Everything here is jit-pure; ``arena.make_arenas`` builds these arenas
+by default.
 """
 
 from __future__ import annotations
@@ -975,6 +974,8 @@ def timer_clear_slots(state: PackedTimerState, slots: jnp.ndarray,
 class PackedCounterArena(_ScalarLanesMixin):
     """Packed counter slots: adaptive-width base + overflow pool."""
 
+    layout = "packed"
+
     def __init__(self, num_windows: int, capacity: int,
                  pool_capacity: int | None = None,
                  widths: tuple = DEFAULT_WIDTHS,
@@ -1014,17 +1015,18 @@ class PackedCounterArena(_ScalarLanesMixin):
                 "arena.consume",
                 "packed counter arena overflow-pool error: "
                 + "; ".join(what)
-                + " — grow pool_capacity/promote_k or use the f64 layout"
-                " (M3_ARENA_LAYOUT=f64); stats since the previous "
+                + " — raise the list's capacity (the pool is max(64, "
+                "num_windows * capacity / 16) rows) or send smaller "
+                "batches; stats since the previous "
                 "consume are unreliable (flag cleared: the window ring "
                 "washes the damage out over the next drains)")
 
     def ingest(self, windows, slots, values, times):
+        if len(slots) == 0:  # the segment view needs a row
+            return
         idx = packed_flat_index(jnp.asarray(windows), jnp.asarray(slots),
                                 self.num_windows, self.capacity)
-        # the packed formulation is already the jnp path — the guard's
-        # fallback re-runs it with the faultpoints skipped (impl unused)
-        self.state = _guarded_ingest(lambda impl: counter_ingest(
+        self.state = _guarded_ingest(lambda: counter_ingest(
             self.state, idx, jnp.asarray(values).astype(jnp.int64),
             jnp.asarray(times), self.num_windows, self.capacity,
             self.widths, self.promote_k))
@@ -1047,6 +1049,8 @@ class PackedCounterArena(_ScalarLanesMixin):
 
 
 class PackedGaugeArena(_ScalarLanesMixin):
+    layout = "packed"
+
     def __init__(self, num_windows: int, capacity: int):
         self.num_windows = num_windows
         self.capacity = capacity
@@ -1057,6 +1061,8 @@ class PackedGaugeArena(_ScalarLanesMixin):
         self.state = gauge_init(num_windows, capacity)
 
     def ingest(self, windows, slots, values, times):
+        if len(slots) == 0:  # the segment view needs a row
+            return
         idx = packed_flat_index(jnp.asarray(windows), jnp.asarray(slots),
                                 self.num_windows, self.capacity)
         # host f64 in, so that the keys carry the written bits (pass
@@ -1064,7 +1070,7 @@ class PackedGaugeArena(_ScalarLanesMixin):
         # device image)
         values = np.asarray(values, np.float64)
         keys = jnp.asarray(orderable_f64(values))
-        self.state = _guarded_ingest(lambda impl: gauge_ingest(
+        self.state = _guarded_ingest(lambda: gauge_ingest(
             self.state, idx, jnp.asarray(values), keys,
             jnp.asarray(times), self.num_windows, self.capacity))
 
@@ -1084,6 +1090,7 @@ class PackedGaugeArena(_ScalarLanesMixin):
 
 
 class PackedTimerArena(_TimerLanesMixin):
+    layout = "packed"
     DEFAULT_QUANTILES = (0.5, 0.95, 0.99)
 
     def __init__(self, num_windows: int, capacity: int,
@@ -1115,7 +1122,7 @@ class PackedTimerArena(_TimerLanesMixin):
         needed = int(new_n.max())
         if needed > self.sample_capacity:
             self._grow(needed)
-        self.state = _guarded_ingest(lambda impl: timer_ingest(
+        self.state = _guarded_ingest(lambda: timer_ingest(
             self.state, jnp.asarray(windows_np.astype(np.int32)),
             jnp.asarray(slots_np.astype(np.int32)),
             jnp.asarray(values).astype(jnp.float64),

@@ -453,17 +453,6 @@ class CoordinatorConfig:
     carbon_listen_port: Optional[int] = None  # None = no carbon listener
     admin_listen_port: Optional[int] = None   # None = no admin API
     tracing: bool = False
-    # Aggregation-arena ingest implementation for this process:
-    # "" = leave the global default (M3_ARENA_INGEST env / scatter);
-    # scatter | pallas select explicitly (pallas is refused on a TPU —
-    # see aggregator/arena.py).
-    arena_ingest: str = ""
-    # Aggregation-arena state layout for this process:
-    # "" = leave the global default (M3_ARENA_LAYOUT env / auto);
-    # packed | f64 | auto select explicitly (auto -> packed, the
-    # round-8 sort/segment formulation; f64 = the scatter-arena parity
-    # oracle — see aggregator/arena.py + aggregator/packed.py).
-    arena_layout: str = ""
     # Aggregation-arena checkpointing (aggregator/checkpoint.py): the
     # downsampler's open windows are snapshotted bit-exactly to
     # <db.root>/checkpoint/aggregator.ckpt every N mediator ticks (and
@@ -481,20 +470,6 @@ class CoordinatorConfig:
             v = getattr(self, f)
             if v is not None and not (0 <= v < 65536):
                 errs.append(f"coordinator.{f}: out of range")
-        if self.arena_ingest:
-            from m3_tpu.aggregator import arena
-
-            if self.arena_ingest not in arena.INGEST_IMPLS:
-                errs.append(
-                    f"coordinator.arena_ingest: {self.arena_ingest!r} not "
-                    f"one of {arena.INGEST_IMPLS}")
-        if self.arena_layout:
-            from m3_tpu.aggregator import arena
-
-            if self.arena_layout not in arena.LAYOUTS:
-                errs.append(
-                    f"coordinator.arena_layout: {self.arena_layout!r} not "
-                    f"one of {arena.LAYOUTS}")
 
 
 @dataclasses.dataclass

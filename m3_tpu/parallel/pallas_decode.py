@@ -9,8 +9,7 @@ non-elementwise op is the GATHER: every (series, datapoint) lane needs
 the 3 consecutive int32-packed words covering its bit offset.  On
 XLA-CPU a ``take_along_axis`` is cheap; on TPU per-lane dynamic gathers
 lower to masked reductions whose cost model XLA gets wrong for this
-shape — the exact failure pallas_ingest.py exists for.  THIS module is
-the hand-scheduled alternative, mirroring that file's seam:
+shape.  THIS module is the hand-scheduled alternative:
 
 * ``extract_fields``    — the public entry: (S, P) offsets/widths over
   (S, W32) uint32 words -> (S, P) uint64 field values.  Routes to the

@@ -6,14 +6,9 @@ within segments of an already-sorted batch in one pass, then gather
 each query key's segment total from the last occurrence of the key.
 query/functions.py builds its grouped PromQL aggregations on these.
 
-(The aggregation arenas used to carry a third ingest implementation on
-this idiom — parallel/sorted_ingest.py, built for TPU where scatter
-measured ~1us/element.  round 5 measured it at 0.45-0.50x of the
-scatter path on CPU and it was never validated faster on real TPU
-hardware, so round 6 deleted it; the TPU answer to slow scatters is
-the hand-scheduled Pallas kernel, parallel/pallas_ingest.py.  These
-two helpers are what survived: they are generic and still earn their
-keep under the query engine.)
+(The packed aggregation arenas, aggregator/packed.py, carry their own
+segmented scan over the sorted batch; these two helpers are generic
+and serve the query engine.)
 """
 
 from __future__ import annotations

@@ -58,14 +58,13 @@ def sharded_init(
     num_windows: int,
     capacity: int,
     sample_capacity: int,
-    layout: str | None = None,
+    layout: str = "packed",
 ) -> ShardedAggregatorState:
     """Per-shard arenas, placed: shard axis over the mesh's shard axis,
-    replicated over the replica axis.  ``layout`` follows the
-    M3_ARENA_LAYOUT seam (None = resolved; "auto" -> packed; unknown
-    strings raise — see arena.resolve_layout_arg)."""
+    replicated over the replica axis.  ``layout`` is "packed" or "f64"
+    (arena.make_arenas' two); anything else raises."""
     D = topo.num_shards
-    layout = _arena.resolve_layout_arg(layout)
+    _arena.check_layout(layout)
 
     def rep(state):
         return jax.tree.map(
@@ -104,45 +103,22 @@ class ShardedBatch(NamedTuple):
     times: jnp.ndarray  # i64 (D, N)
 
 
-def sharded_ingest_consume(
-    topo: MeshTopology,
-    state: ShardedAggregatorState,
-    batch: ShardedBatch,
-    window: jnp.ndarray,
-    num_windows: int,
-    capacity: int,
-    quantiles: tuple = (0.5, 0.95, 0.99),
-    timer_packed32: bool = False,
-    layout: str | None = None,
-):
-    """Host wrapper: resolves the arena-layout seam (None = the
-    M3_ARENA_LAYOUT resolution, matching sharded_init's default;
-    "auto" -> packed, unknown strings raise) and rides it into the
-    jitted step as a STATIC argument — a layout flip via
-    set_arena_layout retraces instead of silently running the old
-    trace (the jaxlint retrace-risk / trace-frozen-config contract)."""
-    layout = _arena.resolve_layout_arg(layout)
-    return _sharded_ingest_consume(topo, state, batch, window,
-                                   num_windows, capacity, quantiles,
-                                   timer_packed32, layout)
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("topo", "num_windows", "capacity", "quantiles",
                      "timer_packed32", "layout"),
     donate_argnums=(1,),
 )
-def _sharded_ingest_consume(
+def sharded_ingest_consume(
     topo: MeshTopology,
     state: ShardedAggregatorState,
     batch: ShardedBatch,
     window: jnp.ndarray,  # i32 scalar: ring index to drain after ingest
     num_windows: int,
     capacity: int,
-    quantiles: tuple,
-    timer_packed32: bool,
-    layout: str,
+    quantiles: tuple = (0.5, 0.95, 0.99),
+    timer_packed32: bool = False,
+    layout: str = "packed",
 ):
     """The framework's "training step": ingest a routed batch into every
     shard's arenas, drain one window (then reset its ring row, as the
@@ -326,9 +302,3 @@ def rollup_lanes(lanes: dict):
         out[:, 2:] = _packed.decode_orderable_f64(
             np.asarray(lanes["rollup_sel"]))
     return out
-
-
-# The sharded program composes raw(ingest) ops, whose scatter-vs-pallas
-# choice binds at trace time — register so arena.set_ingest_impl can
-# invalidate this cache too.
-_arena.register_ingest_consumer(_sharded_ingest_consume)

@@ -1,11 +1,11 @@
 """Query compute-precision policy.
 
-Prometheus evaluates in float64 and so does this engine by default.  On
-TPU that default is expensive: v5e-class chips have no native f64 ALU,
-so XLA software-emulates every f64 elementwise op at ~10-20x the f32
-cost — measured here as the PromQL north star (BASELINE config #5)
-running 8x SLOWER on a TPU v5 lite than on the host CPU (47.9s vs 5.7s
-per eval; round 5).
+Prometheus evaluates in float64 and so does this engine: a node always
+runs f64.  The f32 policy is the benchmark's control (``--control f32``
+of ``prom.dashboard_live`` reads ``hq_rel_err`` 6.5e-7 to 1.0e-6 against
+the limit 1e-9: PERF.md section 2) and a subject of
+tests/test_query_precision.py; no environment variable or node file
+selects it.
 
 The policy narrows the BULK stencil math (temporal kernels, the
 histogram-quantile kernel) to f32 when selected, keeping:
@@ -28,25 +28,18 @@ covers.  Counter values above 2^24 lose integer exactness in f32 —
 reset detection on such counters can misfire; deployments with
 billion-count counters should stay on f64.
 
-Selection: ``set_compute_dtype("f32"|"f64")`` or env
-``M3_QUERY_DTYPE`` at import.  The dtype rides the ARRAYS (engine casts
-at the fetch boundary; kernels follow ``vals.dtype``), so jitted
-kernels re-specialize per dtype automatically — no stale-trace hazard.
+Selection: ``set_compute_dtype("f32"|"f64")``.  The dtype rides the
+ARRAYS (engine casts at the fetch boundary; kernels follow
+``vals.dtype``), so jitted kernels re-specialize per dtype
+automatically — no stale-trace hazard.
 """
 
 from __future__ import annotations
 
-import os
-
 import numpy as np
 
 _VALID = {"f32": np.float32, "f64": np.float64}
-_env = os.environ.get("M3_QUERY_DTYPE", "").strip().lower() or "f64"
-if _env not in _VALID:
-    raise ValueError(
-        f"M3_QUERY_DTYPE={_env!r}: must be 'f32' or 'f64' (a typo "
-        "silently running f64 would invalidate a perf comparison)")
-_dtype = _VALID[_env]
+_dtype = np.float64
 
 
 def set_compute_dtype(name: str) -> None:

@@ -219,16 +219,6 @@ def run_node(source, start_mediator: bool | None = None,
         raise ConfigError(
             "coordinator.downsample=true requires run_node(..., ruleset=...)"
         )
-    if cfg.coordinator is not None and cfg.coordinator.arena_ingest:
-        from m3_tpu.aggregator import arena
-
-        arena.set_ingest_impl(cfg.coordinator.arena_ingest)
-    if cfg.coordinator is not None and cfg.coordinator.arena_layout:
-        from m3_tpu.aggregator import arena
-
-        # Must land BEFORE any MetricList is built: arenas bind their
-        # layout at construction (aggregator/arena.py layout seam).
-        arena.set_arena_layout(cfg.coordinator.arena_layout)
     # Device-boundary knobs FIRST: the memory budget must be installed
     # before any arena/buffer reserves against it, and the stage
     # breakers bind their thresholds at first guarded call.

@@ -340,8 +340,7 @@ def _build_arena_f64(kind: str, op: str):
         st = _state_shape(arena.counter_init, W, C)
         if op == "ingest":
             lowered = arena.counter_ingest.lower(
-                st, a["idx"], a["slots"], a["ivals"], a["times"],
-                impl="scatter")
+                st, a["idx"], a["slots"], a["ivals"], a["times"])
         else:
             lowered = arena.counter_consume.lower(st, a["window"],
                                                   capacity=C)
@@ -349,8 +348,7 @@ def _build_arena_f64(kind: str, op: str):
         st = _state_shape(arena.gauge_init, W, C)
         if op == "ingest":
             lowered = arena.gauge_ingest.lower(
-                st, a["idx"], a["slots"], a["fvals"], a["times"],
-                impl="scatter")
+                st, a["idx"], a["slots"], a["fvals"], a["times"])
         else:
             lowered = arena.gauge_consume.lower(st, a["window"], capacity=C)
     else:  # timer
@@ -358,7 +356,7 @@ def _build_arena_f64(kind: str, op: str):
         if op == "ingest":
             lowered = arena.timer_ingest.lower(
                 st, a["windows"], a["slots"], a["fvals"], a["times"],
-                capacity=C, impl="scatter")
+                capacity=C)
         else:
             lowered = arena.timer_consume.lower(
                 st, a["window"], capacity=C,

@@ -27,9 +27,10 @@ already have.  This module is that contract's seam:
   circuit breaker (``x.breaker`` with ``kind="stage"``), and the SAME
   batch re-runs through ``fallback`` — the stage's host/jnp
   implementation riding the already-static seams (``M3_ENCODE_PLACE``,
-  ``M3_DECODE_CHAINS``, ``M3_ARENA_INGEST`` resolve in host wrappers
-  since PR 7, so the fallback choice is a static argument: zero
-  retraces, bit-parity already pinned).  Once the breaker trips open
+  ``M3_DECODE_CHAINS`` resolve in host wrappers since PR 7, so the
+  fallback choice is a static argument: zero retraces, bit-parity
+  already pinned; a stage with one formulation, as the arenas',
+  re-runs the same program with the faultpoints skipped).  Once the breaker trips open
   the primary is skipped entirely; after the cool-down ONE half-open
   probe re-tries the device path and success closes the breaker.
 

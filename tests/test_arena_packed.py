@@ -316,11 +316,11 @@ class TestOverflowPool:
         assert int(st.pool_idx[loser]) == -1
 
     def test_layout_arg_validation(self):
-        with pytest.raises(ValueError, match="unknown arena layout"):
-            arena.make_arenas(1, 8, 32, (0.5,), layout="packd")
-        # explicit "auto" resolves to packed regardless of phrasing
-        c, _g, _t = arena.make_arenas(1, 8, 32, (0.5,), layout="auto")
-        assert isinstance(c, packed.PackedCounterArena)
+        for gone in ("packd", "auto", None):
+            with pytest.raises(ValueError, match="unknown arena layout"):
+                arena.make_arenas(1, 8, 32, (0.5,), layout=gone)
+        c, _g, _t = arena.make_arenas(1, 8, 32, (0.5,), layout="f64")
+        assert type(c) is arena.CounterArena
 
     def test_reset_window_zeroes_promoted_rows(self):
         W, C = 2, 16
@@ -446,12 +446,12 @@ class TestPackedEngine:
                 np.testing.assert_allclose(got, v, rtol=1e-6, atol=1e-12)
 
     def test_default_layout_resolves_packed(self):
-        assert arena.resolved_arena_layout() in ("packed", "f64")
         opts = AggregatorOptions(capacity=8, num_windows=2,
                                  timer_sample_capacity=32)
         ml = MetricList(opts.storage_policies[0], opts)
-        if arena.resolved_arena_layout() == "packed":
-            assert isinstance(ml.counters, packed.PackedCounterArena)
+        assert isinstance(ml.counters, packed.PackedCounterArena)
+        assert isinstance(ml.gauges, packed.PackedGaugeArena)
+        assert isinstance(ml.timers, packed.PackedTimerArena)
 
     def test_expire_recycles_packed_slots(self):
         opts = AggregatorOptions(capacity=16, num_windows=2,
@@ -572,6 +572,12 @@ class TestBatchDomain:
         assert n_eqns == _ingest_census(kind, other)[0]
 
 
+# (slots, rows) of the seeded batches: rows out of range and negative,
+# more rows than slots and fewer
+_SEEDED_SHAPES = {"n100_c257": (100, 257), "n3000_c5000": (3000, 5000),
+                  "n1024_c1024": (1024, 1024), "n17_c10000": (17, 10_000)}
+
+
 def _edge_batches(name):
     """(W, C, counter-arena kwargs, batches, expected err bits, pool
     rows) of one batch-domain edge case; a batch is (windows, slots,
@@ -591,6 +597,27 @@ def _edge_batches(name):
 
     narrow = dict(pool_capacity=8, widths=(4, 6))
     spread = batch(rng.integers(0, 2, 48), rng.integers(0, 16, 48))
+    if name in _SEEDED_SHAPES:
+        C, n = _SEEDED_SHAPES[name]
+        g = np.round(rng.normal(0, 10, n), 6)
+        g[::97] = np.nan  # a NaN is counted by its own slot only
+        return 2, C, {}, [batch(rng.integers(-1, 3, n),
+                                rng.integers(-3, C + 3, n), gvals=g)], 0, 0
+    if name == "empty_batch":
+        return 2, 16, {}, [spread, batch([], [], t0=5 * SEC)], 0, 0
+    if name == "untouched_slots_keep_identity":
+        return 2, 64, {}, [batch([0, 0, 0], [3, 3, 10], cvals=[-7, 9, 2],
+                                 gvals=[-7.5, 9.25, 2.0])], 0, 0
+    if name == "oob_rows_mixed":
+        return 2, 300, {}, [batch(
+            rng.integers(-1, 3, 4000), rng.integers(-3, 305, 4000),
+            gvals=np.round(rng.normal(0, 100, 4000), 3))], 0, 0
+    if name == "two_batches_equal_one":
+        # eighths: a gauge's sums are exact in any order of addition
+        halves = [batch(rng.integers(0, 2, 3500), rng.integers(0, 256, 3500),
+                        gvals=rng.integers(-400, 400, 3500) / 8, t0=t0)
+                  for t0 in (0, SEC // 2)]
+        return 2, 256, {}, halves, 0, 0
     if name.startswith("one_slot_n"):
         n = int(name[len("one_slot_n"):])
         g = np.round(rng.uniform(-50, 50, n), 3)
@@ -633,7 +660,10 @@ class TestBatchDomainEdges:
     @pytest.mark.parametrize("name", [
         "one_slot_n1", "one_slot_n64", "all_dropped", "ghost_rows_only",
         "slot_under_two_windows", "promotion_and_active_slot",
-        "all_wide_virgin_slot", "more_than_k_promotions", "pool_full"])
+        "all_wide_virgin_slot", "more_than_k_promotions", "pool_full",
+        *_SEEDED_SHAPES, "empty_batch", "one_slot_n1024",
+        "untouched_slots_keep_identity", "oob_rows_mixed",
+        "two_batches_equal_one"])
     def test_edge_case_vs_f64_oracle(self, name):
         W, C, ckw, batches, err, pool_n = _edge_batches(name)
         ca, ga = arena.CounterArena(W, C), arena.GaugeArena(W, C)
@@ -655,13 +685,43 @@ class TestBatchDomainEdges:
         _assert_gauge_parity(ga, pga, W)
         if not err:  # an err bit marks the clipped rows as unreliable
             _assert_counter_parity(ca, pca, W)
-        if name in ("all_dropped", "ghost_rows_only"):
+        after = jax.tree.map(np.asarray, (pca.state, pga.state))
+        if name in ("all_dropped", "ghost_rows_only", "empty_batch"):
             # the last batch wrote no statistic: every state lane but
             # the expiry column keeps its bits
-            after = jax.tree.map(np.asarray, (pca.state, pga.state))
             for b, a in zip(before, after):
                 for lane in b._fields:
                     moved = not np.array_equal(getattr(b, lane),
                                                getattr(a, lane))
                     assert moved == (lane == "last_at"
                                      and name == "ghost_rows_only"), lane
+        if name == "untouched_slots_keep_identity":
+            # every flat slot no row touched holds what init wrote, the
+            # min / max identities included, in both layouts
+            quiet = np.ones(W * C, bool)
+            quiet[[3, 10]] = False
+            fresh = jax.tree.map(np.asarray, (
+                packed.counter_init(W, C), packed.gauge_init(W, C),
+                arena.counter_init(W, C), arena.gauge_init(W, C)))
+            for f, a in zip(fresh, after + jax.tree.map(
+                    np.asarray, (ca.state, ga.state))):
+                for lane in f._fields:
+                    if getattr(f, lane).shape == (W * C,):
+                        np.testing.assert_array_equal(
+                            getattr(a, lane)[quiet],
+                            getattr(f, lane)[quiet], err_msg=lane)
+            assert (np.asarray(ca.state.min)[quiet] == arena.I64_MAX).all()
+            assert (np.asarray(ga.state.max)[quiet] == -np.inf).all()
+        if name == "two_batches_equal_one":
+            # the two halves as one batch leave every lane the same bits
+            whole = [np.concatenate(col) for col in zip(*batches)]
+            wca = packed.PackedCounterArena(W, C)
+            wga = packed.PackedGaugeArena(W, C)
+            for a, vals in ((wca, jnp.asarray(whole[2])), (wga, whole[3])):
+                a.ingest(jnp.asarray(whole[0]), jnp.asarray(whole[1]), vals,
+                         jnp.asarray(whole[4]))
+            for split, one in zip(after, (wca.state, wga.state)):
+                for lane in split._fields:
+                    np.testing.assert_array_equal(
+                        getattr(split, lane), np.asarray(getattr(one, lane)),
+                        err_msg=lane)

@@ -12,12 +12,19 @@ expiry, and gauge semantics match a pure-Python reference oracle
 first-arrival tie-break, strictly-newer replacement).
 """
 
+import os
+import pathlib
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
 jnp = pytest.importorskip("jax.numpy")
 
 from m3_tpu.aggregator import arena  # noqa: E402
+
+REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
 class TestScatterSentinels:
@@ -99,22 +106,38 @@ class TestScatterSentinels:
         assert float(np.asarray(st.sum).sum()) == 0.0
 
 
-class TestImplNames:
-    def test_pallas_is_refused_on_a_tpu(self, monkeypatch):
-        """The kernel does not compile for a chip: there the name is an
-        error, at set time and (for the env's choice) at use."""
-        monkeypatch.setattr(arena.jax, "default_backend", lambda: "tpu")
-        with pytest.raises(ValueError, match="does not compile for a TPU"):
-            arena.set_ingest_impl("pallas")
-        assert arena.ingest_impl() == "scatter"  # unchanged by the refusal
-        monkeypatch.setattr(arena, "_INGEST_IMPL", "pallas")
-        with pytest.raises(ValueError, match="does not compile for a TPU"):
-            arena.ingest_impl()
+class TestOneFormulation:
+    """No environment variable, setter or module chooses an arena's
+    device program or the query engine's dtype."""
 
-    def test_sorted_impl_is_gone(self):
-        for gone in ("sorted", "auto"):
-            with pytest.raises(ValueError):
-                arena.set_ingest_impl(gone)
+    @pytest.mark.parametrize("var,value", [
+        ("M3_ARENA_INGEST", "pallas"), ("M3_ARENA_LAYOUT", "f64"),
+        ("M3_QUERY_DTYPE", "f32")])
+    def test_retired_env_var_selects_nothing(self, var, value):
+        code = (
+            "from m3_tpu.aggregator import arena\n"
+            "from m3_tpu.query import precision\n"
+            "print(*(type(a).__name__ for a in "
+            "arena.make_arenas(1, 8, 32, (0.5,))),"
+            " precision.compute_dtype())\n")
+        r = subprocess.run(
+            [sys.executable, "-c", code], capture_output=True, text=True,
+            env={**os.environ, "JAX_PLATFORMS": "cpu", var: value},
+            cwd=REPO)
+        assert r.returncode == 0, r.stderr
+        assert r.stdout.split() == [
+            "PackedCounterArena", "PackedGaugeArena", "PackedTimerArena",
+            "float64"]
+
+    def test_no_module_names_a_retired_seam(self):
+        gone = ("M3_ARENA_INGEST", "M3_ARENA_LAYOUT", "M3_QUERY_DTYPE",
+                "set_ingest_impl", "set_arena_layout", "pallas_ingest")
+        files = [*REPO.joinpath("m3_tpu").rglob("*.py"),
+                 REPO / "chip_smoke.py", REPO / "__graft_entry__.py"]
+        assert len(files) > 100
+        hits = [(str(f.relative_to(REPO)), name) for f in files
+                for name in gone if name in f.read_text()]
+        assert not hits, hits
 
 
 class TestGaugeOracleFuzz:

@@ -135,6 +135,18 @@ class TestBitExactParity:
                 np.testing.assert_array_equal(a._sample_n_host,
                                               b._sample_n_host)
 
+    @pytest.mark.parametrize("layout,cls", [
+        ("packed", "PackedCounterArena"), ("f64", "CounterArena")])
+    def test_meta_names_the_layout_the_arenas_carry(self, layout, cls):
+        """What restore rebuilds a list from: read off the arenas'
+        own attribute, the same for all three of a list."""
+        ml = _make_list(layout)
+        meta, _arrays = checkpoint.list_state(ml)
+        assert meta["opts"]["layout"] == layout
+        assert meta["layout"] == cls
+        assert {ml.counters.layout, ml.gauges.layout,
+                ml.timers.layout} == {layout}
+
     def test_slot_assignment_and_free_list_survive(self, tmp_path):
         ml = _make_list("packed")
         _mixed_batch(ml, 9, R)

@@ -568,8 +568,9 @@ class TestArenaFallback:
                                               np.asarray(getattr(b.state, f)),
                                               err_msg=f"{layout}.{f}")
 
-    def test_consume_guard_covers_window_drain(self):
-        ctl_counter, _, _ = self._ingest("f64")
+    @pytest.mark.parametrize("layout", ["f64", "packed"])
+    def test_consume_guard_covers_window_drain(self, layout):
+        ctl_counter, _, _ = self._ingest(layout)
         devguard.reset_stages()
         reset_registry()
         with fault.armed("device.dispatch", "error"):

@@ -395,46 +395,62 @@ coordinator: {{downsample: true}}
 mediator: {{enabled: false}}
 """)
 
-    def test_arena_ingest_validated(self):
-        with pytest.raises(ConfigError, match="arena_ingest"):
+    @pytest.mark.parametrize("key", ["arena_ingest", "arena_layout"])
+    def test_arena_seam_keys_are_unknown_fields(self, key):
+        """The arenas have one formulation: a node file that still
+        carries a seam's key is refused by name, not ignored."""
+        with pytest.raises(ConfigError, match=f"coordinator.{key}: unknown field"):
             load_config(
-                "db: {root: /tmp/x}\n"
-                "coordinator: {arena_ingest: scattter}\n").validate()
-        cfg = load_config(
-            "db: {root: /tmp/x}\ncoordinator: {arena_ingest: scatter}\n")
-        cfg.validate()
-        assert cfg.coordinator.arena_ingest == "scatter"
+                f"db: {{root: /tmp/x}}\ncoordinator: {{{key}: packed}}\n")
 
-    def test_arena_layout_validated(self):
-        with pytest.raises(ConfigError, match="arena_layout"):
-            load_config(
-                "db: {root: /tmp/x}\n"
-                "coordinator: {arena_layout: packd}\n").validate()
-        cfg = load_config(
-            "db: {root: /tmp/x}\ncoordinator: {arena_layout: f64}\n")
-        cfg.validate()
-        assert cfg.coordinator.arena_layout == "f64"
+    @staticmethod
+    def _assert_packed(lists):
+        from m3_tpu.aggregator import packed
 
-    def test_arena_ingest_applied_at_boot(self, tmp_path):
-        from m3_tpu.aggregator import arena
+        assert lists
+        for ml in lists:
+            assert type(ml.counters) is packed.PackedCounterArena
+            assert type(ml.gauges) is packed.PackedGaugeArena
+            assert type(ml.timers) is packed.PackedTimerArena
 
-        # Snapshot whatever impl is configured (M3_ARENA_INGEST is a
-        # documented knob, and other tests flip the global) and restore
-        # it — asserting a hardcoded 'scatter' here failed spuriously
-        # under env overrides and ordering leaks.
-        prev = arena.ingest_impl()
-        asm = None
-        try:
-            asm = run_node(f"""
+    def test_node_downsampler_holds_packed_arenas(self, tmp_path):
+        from m3_tpu.index.doc import Document
+        from m3_tpu.metrics.filters import TagsFilter
+        from m3_tpu.metrics.policy import StoragePolicy
+        from m3_tpu.metrics.rules import MappingRule, RuleSet
+
+        asm = run_node(f"""
 db: {{root: {tmp_path}}}
-coordinator: {{listen_port: 0, arena_ingest: pallas}}
+coordinator: {{listen_port: 0, downsample: true}}
 mediator: {{enabled: false}}
-""")
-            assert arena.ingest_impl() == "pallas"
+""", ruleset=RuleSet(version=1, mapping_rules=[MappingRule(
+            "cpu", TagsFilter.parse("__name__:cpu.*"),
+            (StoragePolicy.parse("10s:2d"),))], rollup_rules=[]))
+        try:
+            # a list is built at its policy's first sample
+            doc = Document.from_tags(b"cpu.load;h=0", {
+                b"__name__": b"cpu.load", b"host": b"h0"})
+            asm.downsampler.write_batch(
+                [doc], np.asarray([START + 10**9], np.int64),
+                np.asarray([1.5]))
+            self._assert_packed(list(asm.downsampler._lists.values()))
         finally:
-            if asm is not None:
-                asm.close()
-            arena.set_ingest_impl(prev)
+            asm.close()
+
+    def test_served_aggregator_holds_packed_arenas(self, tmp_path):
+        from m3_tpu.server.assembly import run_aggregator
+
+        asm = run_aggregator(f"""
+db: {{root: {tmp_path}}}
+coordinator: null
+aggregator: {{listen_port: 0, capacity: 64, storage_policies: ["1m:2d"]}}
+""")
+        try:
+            self._assert_packed([
+                ml for sh in asm.aggregator.aggregator.shards
+                for ml in sh.lists.values()])
+        finally:
+            asm.close()
 
 
 class TestAssembly:

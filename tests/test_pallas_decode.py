@@ -158,8 +158,7 @@ class TestChainsSeamSubprocess:
     @pytest.mark.slow
     def test_bad_chains_env_rejected(self):
         """M3_DECODE_CHAINS typos must raise, not silently run a
-        default (the measurement-integrity contract M3_ARENA_INGEST
-        pins the same way)."""
+        default (the measurement-integrity contract)."""
         code = (
             "import os; os.environ['M3_DECODE_CHAINS']='magic';"
             "os.environ['JAX_PLATFORMS']='cpu';"

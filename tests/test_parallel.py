@@ -42,7 +42,7 @@ def test_sharded_step_matches_single_device(shards, replicas):
     )
 
     # Oracle: run each shard through the single-device arenas of the
-    # SAME layout the sharded step resolved (the M3_ARENA_LAYOUT seam).
+    # same (default) layout.
     windows = np.asarray(batch.windows)
     slots = np.asarray(batch.slots)
     cvals = np.asarray(batch.counter_values)
@@ -144,5 +144,6 @@ def test_sharded_layout_arg_validated():
                      devices=jax.devices()[:1])
     import pytest
 
-    with pytest.raises(ValueError, match="unknown arena layout"):
-        sharded_init(topo, 2, 8, 32, layout="packd")
+    for gone in ("packd", "auto"):
+        with pytest.raises(ValueError, match="unknown arena layout"):
+            sharded_init(topo, 2, 8, 32, layout=gone)
