@@ -3,7 +3,9 @@
 Equivalent of `src/query/storage/m3` (FetchCompressed
 `m3/storage.go:215-225`: label matchers → index FetchTagged → decoded
 series) without the network hop — the engine and the database share a
-process, as in the reference's embedded coordinator mode.
+process, as in the reference's embedded coordinator mode.  A selector's
+series cross the `DatabaseStorage` -> `Database` seam once, as a batch,
+and arrive as the block's columns (`Database.read_columns`).
 """
 
 from __future__ import annotations
@@ -19,7 +21,7 @@ from m3_tpu.instrument import tracing
 from m3_tpu.instrument.tracing import Tracepoint
 from m3_tpu.query.block import RawBlock, SeriesMeta
 from m3_tpu.query.promql import LabelMatcher
-from m3_tpu.storage.database import Database, ShardNotOwnedError
+from m3_tpu.storage.database import Database
 from m3_tpu.x import deadline as xdeadline
 from m3_tpu.x import fault
 
@@ -74,23 +76,14 @@ class DatabaseStorage:
         q = matchers_to_query(name, matchers)
         docs = self.db.query_ids(self.namespace, q, start_nanos, end_nanos)
         docs.sort(key=lambda d: d.id)
-        pts = []
-        metas = []
-        for i, d in enumerate(docs):
-            if i % 64 == 0:  # per-series read loop: cancellable
-                xdeadline.check_current("fetch series")
-            try:
-                pts.append(
-                    self.db.read(self.namespace, d.id, start_nanos, end_nanos))
-            except ShardNotOwnedError:
-                # "Reads answer only owned shards": the index still
-                # knows series whose shard the placement moved away —
-                # a local query answers from what this node owns, and
-                # the cluster-level union comes from the session's
-                # replica fan-out, not from this handle.
-                continue
-            metas.append(SeriesMeta(tuple(sorted(d.tags().items()))))
-        return RawBlock.from_lists(pts, metas)
+        # cancellable before the batch; the database checks between shards
+        xdeadline.check_current("fetch series")
+        cols = self.db.read_columns(
+            self.namespace, [d.id for d in docs], start_nanos, end_nanos)
+        # rows are the series of the shards this node owns
+        metas = [SeriesMeta(tuple(sorted(docs[i].tags().items())))
+                 for i in cols.index.tolist()]
+        return RawBlock(cols.ts, cols.values, cols.counts, metas)
 
 
 class SessionStorage:

@@ -431,22 +431,3 @@ class ShardBuffer:
         s_slot, s_ts, s_val = snap
         lo, hi = np.searchsorted(s_slot, [slot, slot + 1])
         return s_ts[lo:hi], s_val[lo:hi]
-
-    def read_window_many(self, block_start: int, slots: np.ndarray):
-        """Batched :meth:`read_window`: one sorted snapshot serves every
-        requested slot (the bulk-verify / batched-fetch read path —
-        without this, reading S series out of a window costs S full
-        window sorts).  Returns ``[(ts, vals), ...]`` aligned with
-        ``slots``; a slot < 0 (unknown series) yields empty arrays."""
-        empty = (np.empty(0, np.int64), np.empty(0))
-        snap = self._sorted_window(block_start)
-        if snap is None:
-            return [empty for _ in slots]
-        s_slot, s_ts, s_val = snap
-        slots = np.asarray(slots, np.int64)
-        los = np.searchsorted(s_slot, slots)
-        his = np.searchsorted(s_slot, slots + 1)
-        return [
-            (s_ts[lo:hi], s_val[lo:hi]) if (hi > lo and sl >= 0) else empty
-            for sl, lo, hi in zip(slots.tolist(), los.tolist(), his.tolist())
-        ]

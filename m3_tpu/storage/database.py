@@ -27,7 +27,7 @@ import threading
 from pathlib import Path
 
 from m3_tpu.core.hash import shard_for as hash_shard_for
-from typing import Dict, Iterable, List, Sequence
+from typing import Dict, Iterable, List, NamedTuple, Sequence
 
 import numpy as np
 
@@ -55,6 +55,7 @@ from m3_tpu.instrument.tracing import Tracepoint
 from m3_tpu.storage.limits import NO_LIMITS, NewSeriesLimiter, QueryLimits
 from m3_tpu.storage.buffer import ShardBuffer, dedupe_last_write_wins
 from m3_tpu.storage.series_merge import merge_point_sources
+from m3_tpu.x import deadline as xdeadline
 
 _LOG = logger("storage.database")
 
@@ -136,6 +137,31 @@ def shard_for_id(sid: bytes, num_shards: int) -> int:
     exists; there is no migration path by design).
     """
     return hash_shard_for(sid, num_shards)
+
+
+class SeriesColumns(NamedTuple):
+    """A batch of series as the columns of a query block
+    (`query/block.RawBlock`): what `Database.read_columns` answers."""
+
+    ts: np.ndarray  # (K, P) int64, time-sorted rows; padded tail = i64 max
+    values: np.ndarray  # (K, P) float64; padded tail = NaN
+    counts: np.ndarray  # (K,) int64 real points per row
+    index: np.ndarray  # (K,) int64: row k answers the request's id index[k]
+    columnar: int  # rows whose every source was already arrays
+
+
+def _gather_runs(keys: np.ndarray, ts: np.ndarray, vals: np.ndarray,
+                 slots: np.ndarray):
+    """The runs of ``slots`` out of columns sorted by slot ``keys``, as
+    flat ``(row, ts, val)``: row i is ``slots[i]``'s run, rows in the
+    order asked, each run in the columns' order.  A slot < 0 (an id the
+    shard never saw) has no run."""
+    los = np.searchsorted(keys, slots)
+    lens = np.where(slots >= 0, np.searchsorted(keys, slots + 1) - los, 0)
+    rows = np.repeat(np.arange(len(slots)), lens)
+    # position in the columns: the run's start plus the rank in the run
+    idx = np.arange(len(rows)) - np.repeat(np.cumsum(lens) - lens - los, lens)
+    return rows, ts[idx], vals[idx]
 
 
 class Shard:
@@ -471,58 +497,84 @@ class Shard:
         )
         return [(t, v) for t, v in merged if start_nanos <= t < end_nanos]
 
-    def read_many(self, sids: Sequence[bytes], start_nanos: int,
-                  end_nanos: int) -> list[list[tuple[int, float]]]:
-        """Batched :meth:`read`: one result list per requested id, same
-        merge/range contract as the single-id path.  The win is
-        amortization — per BLOCK this pays one sorted-window snapshot
-        (buffer.read_window_many) and one cold-overflow sort instead of
-        per-id O(window) work, which is what makes verifying a
-        million-series soak ledger (and serving batched fetches under
-        load) feasible.  Fileset sources stay per-id: the block cache
-        already amortizes the disk read across ids."""
+    def read_columns(self, sids: Sequence[bytes], start_nanos: int,
+                     end_nanos: int):
+        """Batched :meth:`read` as flat columns ``(row, ts, vals)``
+        sorted by (row, ts), row i being ``sids[i]``, plus the mask of
+        rows that had a source made of tuples: same sources, same merge
+        and range contract as the single-id path (fileset volume, open
+        warm buffer, cold overflow; a later source wins a timestamp;
+        ``start <= t < end``), with every source as arrays.  Per block
+        this pays one sorted-window snapshot and one cold-overflow sort
+        for all ids, and a series whose only sources are open windows
+        never leaves numpy.  Fileset sources stay per-id lists of
+        tuples from the scalar decoder (the block cache amortizes the
+        disk read across ids): what a batch decoder would replace."""
         bsz = self.opts.block_size_nanos
         lo = start_nanos // bsz * bsz
         filesets = dict(list_filesets(self.root, self.namespace, self.shard_id))
         slots = np.asarray(
             [s if (s := self.slots.get(sid)) is not None else -1
              for sid in sids], np.int64)
-        sources_per: list[list] = [[] for _ in sids]
+        tupled = np.zeros(len(sids), bool)
+        chunks: list[tuple] = []  # (row, ts, vals) in the merge's order
+        windows_only = True
         for bs in range(lo, end_nanos + bsz, bsz):
             if bs in filesets:
                 vol = filesets[bs]
                 for i, sid in enumerate(sids):
                     pts = self._read_fileset_series(bs, sid, volume=vol)
                     if pts:
-                        sources_per[i].append(pts)
+                        tupled[i] = True
+                        windows_only = False
+                        chunks.append((
+                            np.full(len(pts), i),
+                            np.asarray([t for t, _ in pts], np.int64),
+                            np.asarray([v for _, v in pts], np.float64)))
             if bs in self.buffer.open_blocks:
-                for i, (wts, wvals) in enumerate(
-                        self.buffer.read_window_many(bs, slots)):
-                    if len(wts):
-                        sources_per[i].append(
-                            list(zip(wts.tolist(), wvals.tolist())))
+                chunks.append(_gather_runs(*self.buffer.peek(bs), slots))
             if bs in self.buffer.cold:
+                # Cold writes awaiting flush are readable immediately
+                # (the reference reads cold buckets too — versioned
+                # buckets in buffer.go:1016 serve un-flushed cold data).
                 parts = self.buffer.cold[bs]
                 cslots = np.concatenate([p[0] for p in parts]).astype(np.int64)
-                cts = np.concatenate([p[1] for p in parts])
-                cvals = np.concatenate([p[2] for p in parts])
-                # arrival-stable sort by slot so per-id extraction is a
-                # binary search, with arrival order (the cold merge
-                # rule's tie-break input) preserved within each slot
+                # arrival-stable sort by slot: a run keeps arrival order
+                # (the cold merge rule's tie-break input)
                 order = np.argsort(cslots, kind="stable")
-                cslots, cts, cvals = cslots[order], cts[order], cvals[order]
-                los = np.searchsorted(cslots, slots)
-                his = np.searchsorted(cslots, slots + 1)
-                for i, (slo, shi) in enumerate(zip(los.tolist(), his.tolist())):
-                    if shi > slo and slots[i] >= 0:
-                        sources_per[i].append(
-                            list(zip(cts[slo:shi].tolist(),
-                                     cvals[slo:shi].tolist())))
-        return [
-            [(t, v) for t, v in merge_point_sources(srcs)
-             if start_nanos <= t < end_nanos]
-            for srcs in sources_per
-        ]
+                chunks.append(_gather_runs(
+                    cslots[order],
+                    np.concatenate([p[1] for p in parts])[order],
+                    np.concatenate([p[2] for p in parts])[order], slots))
+                windows_only = False
+        if not chunks:
+            return (np.empty(0, np.int64), np.empty(0, np.int64),
+                    np.empty(0), tupled)
+        rows, ts, vals = (np.concatenate(c) for c in zip(*chunks))
+        if len(chunks) > 1 or not windows_only:
+            # one open window's runs are sorted and deduped as they
+            # come; anything else goes through the one merge: a stable
+            # sort by (row, ts) leaves equal timestamps in source
+            # order, and the last of them wins
+            order = np.lexsort((ts, rows))
+            rows, ts, vals = rows[order], ts[order], vals[order]
+            last = np.ones(len(rows), bool)
+            last[:-1] = (rows[1:] != rows[:-1]) | (ts[1:] != ts[:-1])
+            rows, ts, vals = rows[last], ts[last], vals[last]
+        inr = (ts >= start_nanos) & (ts < end_nanos)
+        if not inr.all():
+            rows, ts, vals = rows[inr], ts[inr], vals[inr]
+        return rows, ts, vals, tupled
+
+    def read_many(self, sids: Sequence[bytes], start_nanos: int,
+                  end_nanos: int) -> list[list[tuple[int, float]]]:
+        """:meth:`read_columns` as one point list per requested id (the
+        RPC / session / verification shape)."""
+        rows, ts, vals, _ = self.read_columns(sids, start_nanos, end_nanos)
+        bounds = np.searchsorted(rows, np.arange(len(sids) + 1)).tolist()
+        ts, vals = ts.tolist(), vals.tolist()
+        return [list(zip(ts[a:b], vals[a:b]))
+                for a, b in zip(bounds[:-1], bounds[1:])]
 
 
 class Namespace:
@@ -577,9 +629,7 @@ class Namespace:
 
     def write_batch(self, ids: Sequence[bytes], ts: np.ndarray, vals: np.ndarray,
                     now_nanos: int) -> int:
-        by_shard: Dict[int, List[int]] = {}
-        for i, sid in enumerate(ids):
-            by_shard.setdefault(shard_for_id(sid, self.opts.num_shards), []).append(i)
+        by_shard = self._by_shard(ids)
         # Ownership gate BEFORE any shard buffers a sample.  An
         # ALL-unowned batch rejects atomically with the typed error —
         # the session fans single-shard sub-batches, so that maps to
@@ -623,18 +673,22 @@ class Namespace:
         self.check_owned(shard)
         return self.shards[shard].read(sid, start, end)
 
-    def read_many(self, sids: Sequence[bytes], start: int,
-                  end: int) -> list[list[tuple[int, float]]]:
-        """Batched read: group by shard, amortize the per-window sort
-        (Shard.read_many), return point lists aligned with ``sids``.
-        The ownership gate is per SHARD and atomic like write_batch's
-        all-unowned case: any unowned shard in the batch raises typed
-        (the session fans single-shard sub-batches, so this maps to one
-        routing miss, never a partially-silent read)."""
+    def _by_shard(self, sids: Sequence[bytes]) -> Dict[int, List[int]]:
         by_shard: Dict[int, List[int]] = {}
         for i, sid in enumerate(sids):
             by_shard.setdefault(shard_for_id(sid, self.opts.num_shards),
                                 []).append(i)
+        return by_shard
+
+    def read_many(self, sids: Sequence[bytes], start: int,
+                  end: int) -> list[list[tuple[int, float]]]:
+        """Batched read: group by shard, amortize the per-window sort
+        (Shard.read_columns), return point lists aligned with ``sids``.
+        The ownership gate is per SHARD and atomic like write_batch's
+        all-unowned case: any unowned shard in the batch raises typed
+        (the session fans single-shard sub-batches, so this maps to one
+        routing miss, never a partially-silent read)."""
+        by_shard = self._by_shard(sids)
         for sh in by_shard:
             self.check_owned(sh)
         out: list = [None] * len(sids)
@@ -643,6 +697,47 @@ class Namespace:
                     [sids[i] for i in idxs], start, end)):
                 out[i] = pts
         return out
+
+    def read_columns(self, sids: Sequence[bytes], start: int,
+                     end: int) -> SeriesColumns:
+        """Batched read as a query block's columns, one row per id of
+        an OWNED shard in the order asked ("reads answer only owned
+        shards": the index still knows series whose shard the placement
+        moved away — a local query answers from what this node owns,
+        and ``index`` says which ids those are; the cluster-level union
+        comes from the session's replica fan-out).  A bound deadline is
+        checked between shards, so a cancelled query stops."""
+        by_shard = self._by_shard(sids)
+        if self.owned is not None:
+            by_shard = {sh: idxs for sh, idxs in by_shard.items()
+                        if sh in self.owned}
+        index = np.sort(np.fromiter(
+            (i for idxs in by_shard.values() for i in idxs), np.int64))
+        row_of = np.empty(len(sids), np.int64)  # request position -> row
+        row_of[index] = np.arange(len(index))
+        counts = np.zeros(len(index), np.int64)
+        columnar = len(index)
+        parts = []
+        for sh, idxs in by_shard.items():
+            xdeadline.check_current("fetch series")
+            rows, ts, vals, tupled = self.shards[sh].read_columns(
+                [sids[i] for i in idxs], start, end)
+            columnar -= int(tupled.sum())
+            # a shard's rows are runs in the order asked: the rank in
+            # the run is the column
+            n = np.bincount(rows, minlength=len(idxs))
+            out_rows = row_of[np.asarray(idxs, np.int64)]
+            counts[out_rows] = n
+            parts.append((np.repeat(out_rows, n),
+                          np.arange(len(rows)) - np.repeat(np.cumsum(n) - n, n),
+                          ts, vals))
+        width = max(int(counts.max(initial=0)), 1)
+        ts_out = np.full((len(index), width), np.iinfo(np.int64).max, np.int64)
+        vals_out = np.full((len(index), width), np.nan)
+        for rows, col, ts, vals in parts:
+            ts_out[rows, col] = ts
+            vals_out[rows, col] = vals
+        return SeriesColumns(ts_out, vals_out, counts, index, columnar)
 
     def tick(self, now_nanos: int) -> dict:
         """Seal + warm-flush every open block that has left the warm
@@ -697,7 +792,9 @@ class Database:
         self.limits = limits if limits is not None else NO_LIMITS
         # One engine-wide reentrant lock serializing state mutation:
         # ingest batches (HTTP threads), the mediator's tick/snapshot/
-        # cleanup thread, bootstrap, and reads that walk buffer state.
+        # cleanup thread, bootstrap, and reads: a query's selector or an
+        # RPC batch takes it once for all its series (`read_columns`,
+        # `read_batch`), `read` once for its one.
         # The reference uses fine-grained per-shard/series locks
         # (shard.go RLock ladders); here every operation is already a
         # whole-batch array program, so one coarse lock adds no
@@ -970,10 +1067,12 @@ class Database:
 
     def read_batch(self, namespace: str, sids: Sequence[bytes],
                    start: int, end: int) -> list[list[tuple[int, float]]]:
-        """Batched :meth:`read` (one engine-lock acquisition, one
-        sorted-window snapshot per open block instead of per id): the
-        RPC ``read_batch`` / session ``fetch_batch`` storage entry.
-        Same limits accounting units as the single-id path."""
+        """Batched :meth:`read` as point lists (one engine-lock
+        acquisition, one sorted-window snapshot per open block instead
+        of per id): the RPC ``read_batch`` / session ``fetch_batch``
+        storage entry; an unowned shard raises for the whole batch.
+        The query engine's entry is :meth:`read_columns`.  Same limits
+        accounting units as the single-id path."""
         if self._scope is not None:
             self._scope.counter("reads").inc(len(sids))
         self.limits.inc_series(len(sids))
@@ -983,6 +1082,32 @@ class Database:
             out = self.namespaces[namespace].read_many(sids, start, end)
         self.limits.inc_bytes(16 * sum(len(p) for p in out))
         return out
+
+    def read_columns(self, namespace: str, sids: Sequence[bytes],
+                     start: int, end: int) -> SeriesColumns:
+        """All series of a selector in one call, as the query block's
+        columns (`Namespace.read_columns`): the local query engine's
+        storage entry (`query/storage_adapter.DatabaseStorage`).  One
+        engine-lock acquisition and one ``db.read`` span a fetch, whose
+        tags say how many series were asked (``n``) and how many were
+        answered from arrays alone (``columnar``; also counted on
+        /metrics as ``fetch_series`` / ``fetch_series_columnar``).
+        Series of shards this node does not own are left out, not
+        raised.  Same limits accounting units as :meth:`read`."""
+        n = len(sids)
+        if self._scope is not None:
+            self._scope.counter("reads").inc(n)
+        self.limits.inc_series(n)
+        self.limits.inc_bytes(0)
+        with self._mu, self.tracer.start_span(
+                Tracepoint.DB_READ, {"n": n}) as sp:
+            cols = self.namespaces[namespace].read_columns(sids, start, end)
+            sp.set_tag("columnar", cols.columnar)
+        if self._scope is not None:
+            self._scope.counter("fetch_series").inc(n)
+            self._scope.counter("fetch_series_columnar").inc(cols.columnar)
+        self.limits.inc_bytes(16 * int(cols.counts.sum()))
+        return cols
 
     def tick(self, now_nanos: int) -> dict:
         import time as _time
