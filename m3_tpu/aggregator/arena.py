@@ -699,7 +699,14 @@ class _ScalarLanesMixin:
 
 class _TimerLanesMixin:
     """Quantile-extended lane mapping shared by the f64 and packed
-    timer arenas (requires a ``quantiles`` tuple attribute)."""
+    timer arenas (requires a ``quantiles`` tuple attribute), and the
+    host's view of their sample buffers."""
+
+    def samples_buffered(self, window: int | None = None) -> int:
+        """Samples in one window's buffer, or in the fullest (the host
+        shadow of ``state.sample_n``: no device sync)."""
+        n = self._sample_n_host
+        return int(n.max() if window is None else n[window])
 
     @property
     def lane_types(self):
@@ -849,6 +856,7 @@ class TimerArena(_TimerLanesMixin):
         # Host shadow of state.sample_n: avoids a device sync per ingest
         # batch just to run the overflow check.
         self._sample_n_host = np.zeros(num_windows, np.int64)
+        self.grows = 0  # times _grow padded the buffer (a new shape)
 
     def ingest(self, windows, slots, values, times):
         """Append a batch; grows the per-window sample buffer first if the
@@ -907,6 +915,7 @@ class TimerArena(_TimerLanesMixin):
             last_at=self.state.last_at,
         )
         self.sample_capacity = new_cap
+        self.grows += 1
 
     def consume(self, window: int):
         return _guarded_consume(lambda: timer_consume(

@@ -719,13 +719,7 @@ class MetricList:
                 ts = start + self.resolution  # end-of-window timestamp
                 for mt in (MetricType.COUNTER, MetricType.GAUGE,
                            MetricType.TIMER):
-                    arena = self._arena(mt)
-                    lanes, counts = arena.consume(w)
-                    for flushed in self._emit(mt, arena, lanes, counts, ts):
-                        results.append(flushed)
-                        if flush_handler is not None:
-                            flush_handler(self, flushed)
-                    arena.reset_window(w)
+                    self._drain(mt, w, ts, results, flush_handler)
                 self.consumed_until = start + self.resolution
                 if self._forward_buffer:
                     buf = self._forward_buffer
@@ -815,6 +809,32 @@ class MetricList:
                           if k[0] == mt and k[1] in dead]:
                     del self._tf_state[k]
         return released
+
+    def _drain(self, mt, w: int, ts: int, results: list,
+               flush_handler) -> None:
+        """Drain one arena's window ``w``: consume, lanes to the host,
+        emit (to ``results`` and the handler), reset."""
+        arena = self._arena(mt)
+        name = f"{Tracepoint.AGG_DRAIN}.{mt.name.lower()}"
+        with tracing.span(name) as span:
+            lanes, counts = arena.consume(w)
+            # a program's outputs are ready together: bringing the small
+            # one over waits for the consume program, so `.to_host`
+            # times the copy of the finished lanes alone
+            with tracing.span(name + ".wait"):
+                counts = np.asarray(counts)
+            with tracing.span(name + ".to_host"):
+                lanes = np.asarray(lanes)
+            if span.recording:
+                span.set_tag("slots", int(np.count_nonzero(counts)))
+                span.set_tag("bytes", lanes.nbytes + counts.nbytes)
+                if mt is MetricType.TIMER:
+                    span.set_tag("samples", arena.samples_buffered(w))
+            for flushed in self._emit(mt, arena, lanes, counts, ts):
+                results.append(flushed)
+                if flush_handler is not None:
+                    flush_handler(self, flushed)
+            arena.reset_window(w)
 
     def _emit(self, mt, arena, lanes, counts, ts) -> List[FlushedMetric]:
         """Returns 0, 1, or 2 FlushedMetrics for one drained window:
@@ -1252,9 +1272,18 @@ class Aggregator:
             "timed_rejects_too_far_future": 0,
             "new_series_rejected": 0,
             "passthrough_samples": self.passthrough_samples,
+            # the fullest open timer window of any list against its
+            # timer_sample_capacity, and how often a buffer was padded
+            # past it (each a new shape: a compile on the ingest path)
+            "timer_samples_buffered": 0,
+            "timer_buffer_grows": 0,
         }
         for sh in self.shards:
             for ml in sh.lists.values():
+                out["timer_samples_buffered"] = max(
+                    out["timer_samples_buffered"],
+                    ml.timers.samples_buffered())
+                out["timer_buffer_grows"] += ml.timers.grows
                 out["drops"] += ml.drops
                 out["forward_errors"] += ml.forward_errors
                 out["timed_rejects_too_early"] += (

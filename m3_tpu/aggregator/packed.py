@@ -862,8 +862,8 @@ def timer_consume(
     quantiles: tuple,
 ):
     """Drain one window: sort the packed words (slot asc, value asc in
-    f32 order), then counts from boundaries, sum/sum_sq from an exact
-    fixed-point cumsum of the decoded values (f32 value precision — the
+    f32 order), then counts from boundaries, sum/sum_sq from a sorted
+    segment sum of the decoded values (f32 value precision — the
     packed32 1e-6 envelope), min/max/quantiles from rank positions."""
     num_w, scap = state.sample.shape
     words = jax.lax.dynamic_index_in_dim(state.sample, window,
@@ -878,27 +878,42 @@ def timer_consume(
     seg_n = (seg_end - seg_start).astype(jnp.int64)
     empty = seg_n == 0
 
-    # Moments from a segmented scan over the sorted words: tree-order
-    # f64 adds keep rounding at ~log2(S) ulps of each segment's own
-    # magnitude, and real non-finite samples flow through with the f64
-    # semantics (inf sums, NaN poisons).  Empty-sentinel words decode
-    # to NaN and are masked out.
-    valid = s_slot < capacity
-    v = jnp.where(valid, s_val, 0.0)
-    head = jnp.concatenate(
-        [jnp.ones(1, bool), s_slot[1:] != s_slot[:-1]])
+    # Moments by one sorted segment sum a lane over the sorted words:
+    # f64 adds in ascending value order within a slot (rounding stays
+    # at ~n ulps of the segment's own magnitude), real non-finite
+    # samples flow through with the f64 semantics (inf sums, NaN
+    # poisons).  Empty-sentinel words decode to NaN: they are zeroed
+    # and land in the spare row `capacity`.  (A three-lane segmented
+    # associative_scan stood here until PR 31: compiled for a v5e it
+    # took the TPU compiler 11 minutes over 2^21 words and 22 s over
+    # 2^17; the segment sums compile in seconds at any size.)
+    #
+    # Two things here are the chip's doing (v5e, PERF.md PR 31):
+    # - the moments decode slot and value from the keys THEMSELVES,
+    #   inside the branch, and share neither `s_slot` nor `s_val` with
+    #   the searches and rank gathers: a column that is also a scatter's
+    #   (or a conditional's) operand stays in HBM, and both binary
+    #   searches then pay 27 ns a gather for 7.5 (32 ms a search for
+    #   8.9 at 2^18 words, empty or not);
+    # - a window that buffered nothing skips them: the scatters cost
+    #   every word of the buffer, sentinels included (39 ms at 2^18),
+    #   and the coordinator's downsampler drains an empty timer buffer
+    #   in every pass.
+    def moments(keys):
+        slot = (keys >> jnp.uint64(32)).astype(jnp.int32)
+        val = decode_orderable_f32(keys & jnp.uint64(0xFFFFFFFF))
+        v = jnp.where(slot < capacity, val, 0.0)
+        seg = jnp.minimum(slot, capacity)
+        return tuple(
+            jax.ops.segment_sum(x, seg, num_segments=capacity + 1,
+                                indices_are_sorted=True)[:capacity]
+            for x in (v, v * v))
 
-    def op(a, b):
-        fa, sa, qa = a
-        fb, sb, qb = b
-        return (fa | fb, jnp.where(fb, sb, sa + sb),
-                jnp.where(fb, qb, qa + qb))
-
-    _, s_sums, s_sqs = jax.lax.associative_scan(
-        op, (head, v, v * v))
-    lp = jnp.clip(seg_end.astype(jnp.int64) - 1, 0, scap - 1)
-    s = jnp.where(empty, 0.0, s_sums[lp])
-    ssq = jnp.where(empty, 0.0, s_sqs[lp])
+    zeros = jnp.zeros(capacity, jnp.float64)
+    s, ssq = jax.lax.cond(
+        jax.lax.dynamic_index_in_dim(state.sample_n, window,
+                                     keepdims=False) > 0,
+        moments, lambda keys: (zeros, zeros), keys)
     cntf = seg_n.astype(jnp.float64)
     mean = jnp.where(empty, 0.0, s / jnp.where(empty, 1.0, cntf))
 
@@ -1107,6 +1122,7 @@ class PackedTimerArena(_TimerLanesMixin):
             owner=self)
         self.state = timer_init(num_windows, capacity, sample_capacity)
         self._sample_n_host = np.zeros(num_windows, np.int64)
+        self.grows = 0  # times _grow padded the buffer (a new shape)
 
     def ingest(self, windows, slots, values, times):
         windows_np = np.asarray(windows)
@@ -1144,6 +1160,7 @@ class PackedTimerArena(_TimerLanesMixin):
             last_at=self.state.last_at,
         )
         self.sample_capacity = new_cap
+        self.grows += 1
 
     def consume(self, window: int):
         return _guarded_consume(lambda: timer_consume(

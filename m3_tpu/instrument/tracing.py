@@ -101,6 +101,16 @@ class Tracepoint:
     AGG_ADD = "aggregator.add"                   # window routing, staging
     AGG_FLUSH = "aggregator.flush"               # one flush-manager tick
     AGG_CONSUME = "aggregator.consume"
+    # under it, one span per arena drained, named by metric type:
+    # aggregator.drain.counter|gauge|timer (aggregator/engine.py
+    # MetricList._drain); tags slots (that held a sample), bytes (lanes
+    # copied to the host) and, where the arena knows, samples (the
+    # timer buffer's).  Its own time is _emit's masks and the window
+    # reset; below it stand the guarded device call, `<name>.wait` (the
+    # wait for the arena's consume program, taken by bringing its counts
+    # to the host), `<name>.to_host` (the copy of its finished lanes)
+    # and the emission
+    AGG_DRAIN = "aggregator.drain"
     AGG_FLUSH_EMIT = "aggregator.flush.emit"     # slots -> ids, encode, publish
     AGG_FLUSH_PERSIST = "flush.persist"          # flush times -> KV
     DOWNSAMPLE_LOCK_WAIT = "downsample.lock.wait"
@@ -213,6 +223,7 @@ class Span:
 
 class _ActiveSpan:
     __slots__ = ("_tracer", "span", "_token", "_annotation")
+    recording = True    # a tag that costs something is worth computing
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
@@ -250,6 +261,8 @@ class _ActiveSpan:
 
 
 class _NoopSpan:
+    recording = False
+
     def set_tag(self, key: str, value) -> None:
         pass
 
@@ -275,6 +288,7 @@ class _UnsampledSpan:
     when a profiler session opened, for one)."""
 
     __slots__ = ("_token",)
+    recording = False
 
     def set_tag(self, key: str, value) -> None:
         pass

@@ -110,6 +110,10 @@ def default_baseline_path() -> Path:
 #   (tests/test_arena_packed.py::TestBatchDomain holds the jaxpr to
 #   it); the f64 oracle's slot-update scatters are per-lane,
 #   capacity-bounded;
+# * timer/consume_packed: the two sorted segment sums of the drained
+#   window's moments (sum, sum_sq), each counted twice by the census:
+#   they replaced a three-lane associative_scan that the TPU compiler
+#   needed minutes for at a deployment's buffer (PR 31);
 # * encode/*: the stream-word placement tail — ``place="scatter"`` is
 #   whitelisted by stage name per the costwatch registry, and every
 #   placement variant carries the 2-scatter bounded carry promotion;
@@ -133,7 +137,7 @@ SCATTER_BUDGETS: Dict[str, int] = {
     "arena/gauge_consume_f64": 0,
     "timer/ingest_packed": 4,
     "timer/ingest_f64": 12,
-    "timer/consume_packed": 0,
+    "timer/consume_packed": 4,
     "timer/consume_f64": 0,
 }
 

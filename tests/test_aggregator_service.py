@@ -264,7 +264,16 @@ class TestServedRollup:
         assert "device.arena.ingest" in under[Tracepoint.AGG_ADD]
         assert under[Tracepoint.AGG_FLUSH] >= {
             Tracepoint.AGG_CONSUME, Tracepoint.AGG_FLUSH_PERSIST}
-        assert Tracepoint.AGG_FLUSH_EMIT in under[Tracepoint.AGG_CONSUME]
+        # one drain span per arena under the consume; the device call,
+        # the wait for it, the copy to the host and the emission below it
+        drains = {f"{Tracepoint.AGG_DRAIN}.{kind}"
+                  for kind in ("counter", "gauge", "timer")}
+        assert under[Tracepoint.AGG_CONSUME] >= drains
+        for d in drains:
+            assert under[d] >= {"device.arena.consume", d + ".wait",
+                                d + ".to_host"}
+        assert Tracepoint.AGG_FLUSH_EMIT in under[
+            Tracepoint.AGG_DRAIN + ".gauge"]
         # the wait in the queue begins where decode ended
         f = frames[0]
         kids = [s for s in spans if s.parent_id == f.span_id]

@@ -225,6 +225,40 @@ class TestServedAggregatorPrograms:
                  capacity=self.C)
 
 
+class TestTimerDrain:
+    """packed.timer_consume, every drain's program (the downsampler's
+    passes and m3agg.untimed_rollup drain it empty, m3agg.timer_quantile
+    full): its two binary searches gather from the sorted slot column
+    log2 S times each, so that column has to sit in fast memory.  When
+    the moments' scatters (or their conditional) shared it, the TPU
+    compiler left it in HBM and an empty drain at the downsampler's
+    2^18 words took 80 ms for 25 (PR 31, measured on a v5e; the layouts
+    below read the same here, at any size)."""
+
+    W, S, C = 2, 1 << 14, 1 << 12
+
+    def test_searches_gather_from_fast_memory(self, one_chip):
+        import re
+
+        from m3_tpu.aggregator import packed
+
+        shapes = jax.eval_shape(
+            lambda: packed.timer_init(self.W, self.C, self.S))
+        state = jax.tree_util.tree_map(lambda a: A(a.shape, a.dtype), shapes)
+        compiled = _compile(packed.timer_consume, one_chip, state,
+                            A((), jnp.int32), capacity=self.C,
+                            quantiles=(0.5, 0.95, 0.99))
+        text = compiled.as_text()
+        entry = text[text.index("ENTRY"):]
+        loops = [line.split(" while(")[0] for line in entry.splitlines()
+                 if " while(" in line]
+        assert len(loops) == 2            # searchsorted left and right
+        for carried in loops:
+            (column,) = re.findall(r"s32\[%d\][^,]*" % self.S, carried)
+            assert "S(1)" in column, column
+        assert " conditional(" in entry   # an empty window skips the moments
+
+
 class TestQueryPrograms:
     """rate -> sum by (le) is host-grouped; the device programs are the
     rate stencil and the histogram_quantile kernel."""

@@ -404,7 +404,15 @@ mediator: {{enabled: true, tick_interval: 1h}}
         assert parent["aggregator.consume"] == "downsample.flush"
         assert parent["downsample.writeback"] == "downsample.flush"
         assert parent["downsample.lock.wait"] == "downsample.flush"
-        assert parent["device.arena.consume"] == "aggregator.consume"
+        # one drain span per arena under the consume (PR 31), the
+        # guarded device call, the wait for it and the copy to the host
+        # below it
+        for kind in ("counter", "gauge", "timer"):
+            assert parent[f"aggregator.drain.{kind}"] == "aggregator.consume"
+            for leaf in ("wait", "to_host"):
+                assert (parent[f"aggregator.drain.{kind}.{leaf}"]
+                        == f"aggregator.drain.{kind}")
+        assert parent["device.arena.consume"].startswith("aggregator.drain.")
 
 
 # -- the reducers ---------------------------------------------------------------
@@ -708,7 +716,11 @@ class TestNames:
                     assert any(n.startswith(pat[:-1]) or pat[:-1] == n
                                or pat[:-1].startswith(n) for n in names), pat
                 else:
-                    assert pat in names, (path.name, pat)
+                    # one drain span per arena, named from the metric
+                    # type under the registered AGG_DRAIN (their names:
+                    # test_aggregator_timer_service's drain-span test)
+                    assert (pat in names or pat.startswith(
+                        Tracepoint.AGG_DRAIN + ".")), (path.name, pat)
         assert seen >= 27
         # what the reducers themselves name
         for n in ("api.write", "api.queryRange", "mediator.runOnce",
@@ -746,10 +758,11 @@ def test_benchmark_selftest_passes_whole():
 def test_new_per_layer_entries_are_well_formed():
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     # PR 25's, PR 26's and PR 30's entries (PR 27's `.agg` entries are
-    # held by tests/test_aggregator_service.py)
+    # held by tests/test_aggregator_service.py, PR 31's `.timer` entries
+    # by tests/test_aggregator_timer_service.py)
     new = [m for m in bench["per_layer"] if m["source"] == "program_span"
            and m["name"] != "maintain_ms_per_pass"
-           and not m["name"].endswith(".agg")]
+           and not m["name"].endswith((".agg", ".timer"))]
     assert len(new) == 28
     layers = {m["layer"] for m in bench["per_layer"]
               if m not in new}
