@@ -82,7 +82,9 @@ class Tracepoint:
     DB_SNAPSHOT = "db.snapshot"
     ENGINE_EXECUTE = "query.engine.execute"
     EVAL_CALL = "query.eval.call"                # tag fn: the function
-    EVAL_AGGREGATION = "query.eval.aggregation"  # tag op: the operator
+    # tags op: the operator; one_program of n: the operator is one
+    # jitted program a call (query/engine.Engine._eval)
+    EVAL_AGGREGATION = "query.eval.aggregation"
     FETCH_COMPRESSED = "query.storage.fetchCompressed"
     API_QUERY_RANGE = "api.queryRange"           # the whole read handler
     API_QUERY_RENDER = "api.queryRange.render"   # values loop + JSON

@@ -1,14 +1,16 @@
-"""Generic segmented-reduction primitives over sorted batches.
+"""Generic segmented-reduction primitive over sorted batches.
 
-Sort + head-flag segmented ``associative_scan`` + ``searchsorted``
-gather is the scatter-free reduction idiom on accelerators: reduce
-within segments of an already-sorted batch in one pass, then gather
-each query key's segment total from the last occurrence of the key.
-query/functions.py builds its grouped PromQL aggregations on these.
+Sort + head-flag segmented ``associative_scan`` + a gather at segment
+ends is the scatter-free reduction idiom on accelerators: reduce within
+segments of an already-sorted batch in one pass, then gather each key's
+segment total from the last position of its segment.
+query/device_fns.py runs its grouped PromQL aggregations through it
+(`_segment_reduce_kernel`); the sort and the segment ends are the host's
+there (`_sorted_plan`), since the group ids are a host array.
 
 (The packed aggregation arenas, aggregator/packed.py, carry their own
-segmented scan over the sorted batch; these two helpers are generic
-and serve the query engine.)
+segmented scan over the sorted batch; this helper is generic and serves
+the query engine.)
 """
 
 from __future__ import annotations
@@ -55,15 +57,3 @@ def head_flag_scan(is_start, adds=(), mins=(), maxs=()):
         comb, (is_start,) + tuple(adds) + tuple(mins) + tuple(maxs))
     return (res[1:1 + n_adds], res[1 + n_adds:1 + n_adds + n_mins],
             res[1 + n_adds + n_mins:])
-
-
-def last_occurrence(sorted_keys, queries):
-    """(position, found) of the last occurrence of each query in
-    ``sorted_keys`` — the gather side of the merge.  Positions are
-    clamped valid so callers can gather unconditionally and mask with
-    ``found``."""
-    n = sorted_keys.shape[0]
-    pos = jnp.searchsorted(sorted_keys, queries, side="right") - 1
-    pos_c = jnp.clip(pos, 0, max(n - 1, 0))
-    found = (pos >= 0) & (sorted_keys[pos_c] == queries)
-    return pos_c, found

@@ -10,7 +10,10 @@ Ragged group structure (different bucket/row counts per group) is
 handled the TPU way: the host builds padded gather-index matrices once
 (cheap tag work it owns anyway), and the device kernel runs on dense
 (G, R_max, T) tensors with masks.  jit caches per shape, so repeated
-queries over the same block geometry pay tracing once.
+queries over the same block geometry pay tracing once.  The plain
+reductions (sum, avg, min, ...) need no padding: the host hands the
+permutation that makes groups adjacent and one segmented scan runs over
+the rows (`group_reduce`).
 """
 
 from __future__ import annotations
@@ -21,11 +24,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from m3_tpu.parallel import segmented
+
 NAN = float("nan")
 
 
 # ---------------------------------------------------------------------------
-# Padded group gather plans (host)
+# Group plans (host)
 # ---------------------------------------------------------------------------
 
 
@@ -44,6 +49,90 @@ def group_plan(gids: np.ndarray, num_groups: int):
         idx[g, :c] = order[starts[g] : ends[g]]
         mask[g, :c] = True
     return idx, mask
+
+
+def _sorted_plan(gids: np.ndarray, num_groups: int):
+    """(order (S,), is_start (S,), pos (G,), found (G,)): the STABLE
+    permutation that makes each group's rows adjacent, each segment's
+    head flag, and where each group's segment ends (clamped valid;
+    ``found`` False for a group with no row).  The host owns it: gids
+    is its own array."""
+    order = np.argsort(gids, kind="stable")
+    sorted_g = np.asarray(gids)[order]
+    is_start = np.ones(len(order), bool)
+    is_start[1:] = sorted_g[1:] != sorted_g[:-1]
+    counts = np.bincount(sorted_g, minlength=num_groups)[:num_groups]
+    pos = np.maximum(np.cumsum(counts) - 1, 0).astype(np.int32)
+    return order.astype(np.int32), is_start, pos, counts > 0
+
+
+# ---------------------------------------------------------------------------
+# Grouped sum / count / avg / min / max / stddev / stdvar / group
+# ---------------------------------------------------------------------------
+
+REDUCE_FUNCS = ("sum", "count", "avg", "min", "max", "stddev", "stdvar",
+                "group")
+
+
+@functools.partial(jax.jit, static_argnames=("func",))
+def _segment_reduce_kernel(values, order, is_start, pos, found, func: str):
+    """(S, T) + the host's sorted plan -> (G, T): one segmented scan
+    over the rows in group order, read at each segment's end.  Costs
+    S x T whatever the groups' sizes.  NaN is "absent": a (group, step)
+    with no present value answers NaN under every func."""
+    with jax.named_scope("group_reduce"):
+        v = values.astype(jnp.float64)[order]
+        present = ~jnp.isnan(v)
+        zero = jnp.where(present, v, 0.0)
+        adds = [present.astype(jnp.int32)]  # the count rides every func
+        if func in ("sum", "avg", "stddev", "stdvar"):
+            adds.append(zero)
+        if func in ("stddev", "stdvar"):
+            adds.append(zero * zero)
+        mins = (jnp.where(present, v, jnp.inf),) if func == "min" else ()
+        maxs = (jnp.where(present, v, -jnp.inf),) if func == "max" else ()
+        r_adds, r_mins, r_maxs = segmented.head_flag_scan(
+            is_start, adds=tuple(adds), mins=mins, maxs=maxs)
+        fm = found[:, None]
+
+        def at_ends(seg, fill):
+            return jnp.where(fm, seg[pos], fill)
+
+        cnt = at_ends(r_adds[0], 0).astype(jnp.float64)
+        empty = cnt == 0
+        n = jnp.where(empty, 1.0, cnt)
+        if func == "sum":
+            out = at_ends(r_adds[1], 0.0)
+        elif func == "count":
+            out = cnt
+        elif func == "group":
+            out = jnp.ones_like(cnt)
+        elif func == "avg":
+            out = at_ends(r_adds[1], 0.0) / n
+        elif func in ("stddev", "stdvar"):
+            mean = at_ends(r_adds[1], 0.0) / n
+            var = jnp.maximum(at_ends(r_adds[2], 0.0) / n - mean * mean, 0.0)
+            out = jnp.sqrt(var) if func == "stddev" else var
+        elif func == "min":
+            out = at_ends(r_mins[0], jnp.inf)
+            out = jnp.where(jnp.isposinf(out), NAN, out)
+        else:
+            out = at_ends(r_maxs[0], -jnp.inf)
+            out = jnp.where(jnp.isneginf(out), NAN, out)
+        return jnp.where(empty, NAN, out)
+
+
+def group_reduce(values, gids: np.ndarray, num_groups: int, func: str):
+    """(S, T) + host group ids -> (G, T) f64, device-resident (Block
+    contract), as ONE jitted program: the host turns ``gids`` into the
+    sorted plan in numpy and the device sorts and searches nothing."""
+    if func not in REDUCE_FUNCS:
+        raise ValueError(f"unknown aggregation {func}")
+    v = jnp.asarray(values)  # cast to f64 inside the program
+    if v.shape[0] == 0 or num_groups == 0:
+        return jnp.full((num_groups, v.shape[1]), NAN, jnp.float64)
+    return _segment_reduce_kernel(v, *_sorted_plan(gids, num_groups),
+                                  func=func)
 
 
 # ---------------------------------------------------------------------------

@@ -136,8 +136,12 @@ class Engine:
             with self.tracer.start_span(Tracepoint.EVAL_CALL, {"fn": e.func}):
                 return self._eval_call(e, steps)
         if isinstance(e, Aggregation):
-            with self.tracer.start_span(Tracepoint.EVAL_AGGREGATION,
-                                        {"op": e.op}):
+            # topk/bottomk dispatch the mask kernel and then a select;
+            # every other operator is one jitted program on a host plan
+            with self.tracer.start_span(
+                    Tracepoint.EVAL_AGGREGATION,
+                    {"op": e.op, "n": 1,
+                     "one_program": int(e.op not in ("topk", "bottomk"))}):
                 return self._eval_aggregation(e, steps)
         if isinstance(e, BinaryOp):
             return self._eval_binary(e, steps)
@@ -464,9 +468,6 @@ class Engine:
         if agg.op == "quantile":
             q = self._scalar_arg(agg.param, steps)
             return fn.aggregate(block, "quantile", by, without, q)
-        if agg.op == "group":
-            out = fn.aggregate(block, "count", by, without)
-            return out.with_values(np.where(np.isnan(out.values), np.nan, 1.0))
         return fn.aggregate(block, agg.op, by, without)
 
     def _eval_binary(self, b: BinaryOp, steps: np.ndarray):

@@ -13,7 +13,10 @@ Equivalents of `src/query/functions/{aggregation,linear,binary,tag}`:
 
 All operate on the (S, T) matrix; grouping is a host-computed partition of
 series rows (tag work stays on host) followed by one device segmented
-reduction over the group axis.
+reduction over the group axis: the host sorts the group ids in numpy
+and `device_fns.group_reduce` runs ONE jitted program on that plan (a
+segmented scan over the rows in the host's permutation, read at each
+group's end), the same program on every backend.
 """
 
 from __future__ import annotations
@@ -61,106 +64,14 @@ def group_series(series: list[SeriesMeta], by: set[bytes] | None,
 
 def _segment_reduce(values: np.ndarray, gids: np.ndarray, num_groups: int,
                     func: str, q: float = 0.0) -> np.ndarray:
-    """(S, T) + group ids -> (G, T) via device segment ops.
-
-    Two formulations: XLA segment_* (scatter-based — fast on CPU) and a
-    sort/scan/gather form for TPU, where scatter measured ~1us/element
-    (round 5, window 3) — a 100K-series `sum by (...)`
-    would otherwise scatter S*T elements.  Chosen at trace time by
-    backend; both are pinned equal in tests/test_query_engine.py.
-    """
-    import jax
-    import jax.numpy as jnp
+    """(S, T) + group ids -> (G, T), device-resident (Block contract):
+    one jitted program a call on a plan the host builds from ``gids``
+    (`device_fns.group_reduce`, `device_fns.group_quantile`)."""
+    from m3_tpu.query import device_fns
 
     if func == "quantile":
-        from m3_tpu.query.device_fns import group_quantile
-
-        return group_quantile(values, gids, num_groups, q)
-
-    v = jnp.asarray(values)
-    g = jnp.asarray(gids)
-    nan = jnp.isnan(v)
-    zero = jnp.where(nan, 0.0, v)
-    ones = (~nan).astype(jnp.float64)
-
-    if jax.default_backend() == "tpu" and v.shape[0] > 0:
-        from m3_tpu.parallel import segmented as so
-
-        order = jnp.argsort(g)
-        gs = g[order]
-        is_start = jnp.concatenate(
-            [jnp.ones(1, bool), gs[1:] != gs[:-1]])
-        adds, mins, maxs = [], [], []
-        if func in ("sum", "avg", "stddev", "stdvar"):
-            adds.append(zero[order])
-        if func in ("stddev", "stdvar"):
-            adds.append((zero * zero)[order])
-        if func == "min":
-            mins.append(jnp.where(nan, jnp.inf, v)[order])
-        if func == "max":
-            maxs.append(jnp.where(nan, -jnp.inf, v)[order])
-        adds.append(ones[order])  # count rides every form
-        r_adds, r_mins, r_maxs = so.head_flag_scan(
-            is_start, adds=tuple(adds), mins=tuple(mins), maxs=tuple(maxs))
-        pos, found = so.last_occurrence(
-            gs, jnp.arange(num_groups, dtype=gs.dtype))
-        fm = found[:, None]
-
-        def at_ends(seg):
-            return jnp.where(fm, seg[pos], jnp.zeros((), seg.dtype))
-
-        cnt = at_ends(r_adds[-1])
-        empty = cnt == 0
-        if func == "sum":
-            out = at_ends(r_adds[0])
-        elif func == "count":
-            out = cnt
-        elif func == "avg":
-            out = at_ends(r_adds[0]) / jnp.where(empty, 1.0, cnt)
-        elif func in ("stddev", "stdvar"):
-            s1, s2 = at_ends(r_adds[0]), at_ends(r_adds[1])
-            mean = s1 / jnp.where(empty, 1.0, cnt)
-            var = jnp.maximum(
-                s2 / jnp.where(empty, 1.0, cnt) - mean * mean, 0.0)
-            out = jnp.sqrt(var) if func == "stddev" else var
-        elif func == "min":
-            out = jnp.where(fm, r_mins[0][pos], jnp.inf)
-            out = jnp.where(jnp.isposinf(out), NAN, out)
-        elif func == "max":
-            out = jnp.where(fm, r_maxs[0][pos], -jnp.inf)
-            out = jnp.where(jnp.isneginf(out), NAN, out)
-        else:
-            raise ValueError(f"unknown aggregation {func}")
-        return jnp.where(empty, NAN, out)
-
-    def seg_sum(x):
-        return jax.ops.segment_sum(x, g, num_segments=num_groups)
-
-    cnt = seg_sum(ones)
-    empty = cnt == 0
-    if func == "sum":
-        out = seg_sum(zero)
-    elif func == "count":
-        out = cnt
-    elif func == "avg":
-        out = seg_sum(zero) / jnp.where(empty, 1.0, cnt)
-    elif func in ("stddev", "stdvar"):
-        s1 = seg_sum(zero)
-        s2 = seg_sum(zero * zero)
-        mean = s1 / jnp.where(empty, 1.0, cnt)
-        var = jnp.maximum(s2 / jnp.where(empty, 1.0, cnt) - mean * mean, 0.0)
-        out = jnp.sqrt(var) if func == "stddev" else var
-    elif func == "min":
-        out = jax.ops.segment_min(jnp.where(nan, jnp.inf, v), g,
-                                  num_segments=num_groups)
-        out = jnp.where(jnp.isposinf(out), NAN, out)
-    elif func == "max":
-        out = jax.ops.segment_max(jnp.where(nan, -jnp.inf, v), g,
-                                  num_segments=num_groups)
-        out = jnp.where(jnp.isneginf(out), NAN, out)
-    else:
-        raise ValueError(f"unknown aggregation {func}")
-    return jnp.where(empty, NAN, out)  # device-resident (Block contract)
+        return device_fns.group_quantile(values, gids, num_groups, q)
+    return device_fns.group_reduce(values, gids, num_groups, func)
 
 
 def aggregate(block: Block, func: str, by: set[bytes] | None = None,

@@ -260,8 +260,9 @@ class TestTimerDrain:
 
 
 class TestQueryPrograms:
-    """rate -> sum by (le) is host-grouped; the device programs are the
-    rate stencil and the histogram_quantile kernel."""
+    """rate -> sum by (le) -> histogram_quantile is three device
+    programs: the rate stencil, the group reduction (a segmented scan
+    on the host's sorted plan) and the histogram_quantile kernel."""
 
     def test_rate(self, one_chip):
         from m3_tpu.query import temporal
@@ -269,6 +270,21 @@ class TestQueryPrograms:
         _compile(temporal.rate_family, one_chip,
                  A((S, T), jnp.int64), A((S, T), jnp.float64),
                  A((T,), jnp.int64), A((), jnp.int64), func="rate")
+
+    def test_group_reduce(self, one_chip):
+        """The panel's shape and the node's (S, T): the host owns the
+        permutation, so no sort is compiled for the chip; the scan runs
+        over S rows with T lanes beside it (its compile seconds are the
+        figure PERF.md cites)."""
+        from m3_tpu.query import device_fns
+
+        for rows, groups in ((400, 10), (S, 1024)):
+            c = _compile(device_fns._segment_reduce_kernel, one_chip,
+                         A((rows, T), jnp.float64), A((rows,), jnp.int32),
+                         A((rows,), jnp.bool_), A((groups,), jnp.int32),
+                         A((groups,), jnp.bool_), func="sum")
+            text = c.as_text()
+            assert "sort(" not in text[text.index("ENTRY"):]
 
     def test_histogram_quantile(self, one_chip):
         from m3_tpu.query import device_fns

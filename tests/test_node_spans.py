@@ -757,19 +757,20 @@ def test_benchmark_selftest_passes_whole():
 
 def test_new_per_layer_entries_are_well_formed():
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    # PR 25's, PR 26's and PR 30's entries (PR 27's `.agg` entries are
+    # PR 25's, PR 26's, PR 30's and PR 32's entries (PR 27's `.agg` entries are
     # held by tests/test_aggregator_service.py, PR 31's `.timer` entries
     # by tests/test_aggregator_timer_service.py)
     new = [m for m in bench["per_layer"] if m["source"] == "program_span"
            and m["name"] != "maintain_ms_per_pass"
            and not m["name"].endswith((".agg", ".timer"))]
-    assert len(new) == 28
+    assert len(new) == 29
     layers = {m["layer"] for m in bench["per_layer"]
               if m not in new}
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     for m in new:
         hit_share = m["name"].startswith(("decode_cache_hit_pct",
-                                          "read_columnar_pct"))
+                                          "read_columnar_pct",
+                                          "group_one_program_pct"))
         assert m["layer"] in layers
         assert m["better"] == ("higher" if hit_share else "lower")
         (cell,) = m["workloads"]

@@ -104,6 +104,65 @@ QSTART = START + 10 * 60 * 10**9
 QEND = START + 28 * 60 * 10**9
 
 
+_REDUCE_FUNCS = ["sum", "count", "avg", "stddev", "stdvar", "min", "max",
+                 "group"]
+
+
+def _reduce_case(gids, G):
+    rng = np.random.default_rng(17)
+    vals = np.round(rng.normal(0, 10, (len(gids), 13)), 5)
+    vals[rng.random(vals.shape) < 0.15] = np.nan
+    vals[0, :] = np.nan  # one fully-NaN row
+    return vals, gids.astype(np.int32), G
+
+
+def _balanced_case():
+    rng = np.random.default_rng(18)
+    S, G = 200, 23
+    gids = rng.integers(0, G, S)
+    gids[gids == G - 1] = 0  # leave group G-1 EMPTY
+    return _reduce_case(gids, G)
+
+
+def _skewed_case():
+    rng = np.random.default_rng(19)
+    S, G = 200, 9
+    gids = np.zeros(S, np.int64)          # 150 rows in group 0
+    gids[150:] = rng.integers(1, G - 1, 50)  # group G-1 EMPTY
+    return _reduce_case(rng.permutation(gids), G)
+
+
+_REDUCE_CASES = {"balanced": _balanced_case, "skewed": _skewed_case}
+
+
+def _atol(func):
+    # stddev is the root of E[x^2] - E[x]^2: a cancellation of 1e-15
+    # of the squares reads 3e-8 after the root (a lone value: 0 exactly
+    # in numpy's two-pass form)
+    return 1e-6 if func == "stddev" else 1e-9
+
+
+def _ref_reduce(vals, gids, G, func):
+    """Independent numpy reference: nan-reductions per (group, step),
+    NaN where the group has no present value at the step."""
+    import warnings
+
+    ref = {"sum": np.nansum, "avg": np.nanmean, "min": np.nanmin,
+           "max": np.nanmax, "stddev": np.nanstd, "stdvar": np.nanvar,
+           "count": lambda r, axis: (~np.isnan(r)).sum(axis=axis),
+           "group": lambda r, axis: np.ones(r.shape[1])}[func]
+    out = np.full((G, vals.shape[1]), np.nan)
+    for g in range(G):
+        rows = vals[gids == g]
+        some = (~np.isnan(rows)).any(axis=0) if len(rows) else \
+            np.zeros(vals.shape[1], bool)
+        if some.any():
+            with warnings.catch_warnings():
+                warnings.simplefilter("ignore", RuntimeWarning)
+                out[g, some] = ref(rows[:, some], axis=0)
+    return out
+
+
 class TestEngine:
     def test_instant_selector_lookback(self, engine):
         b = engine.execute_range('http_requests_total{job="api"}', QSTART, QEND, STEP)
@@ -188,29 +247,178 @@ class TestEngine:
         )
         assert b2.num_series == 8
 
-    @pytest.mark.parametrize(
-        "func,q", [("sum", 0.0), ("count", 0.0), ("avg", 0.0),
-                   ("stddev", 0.0), ("stdvar", 0.0), ("min", 0.0),
-                   ("max", 0.0)])
-    def test_segment_reduce_sorted_matches_scatter(self, monkeypatch,
-                                                   func, q):
-        """The TPU (sort/scan/gather) aggregation form must equal the
-        XLA segment_* form — forced on CPU by faking the backend."""
+    def test_group_aggregation(self, engine):
+        """PromQL `group`: 1 for every group with a present value."""
+        cnt = engine.execute_range('count by (job) (http_requests_total)',
+                                   QSTART, QEND, STEP)
+        grp = engine.execute_range('group by (job) (http_requests_total)',
+                                   QSTART, QEND, STEP)
+        assert [m.tags for m in grp.series] == [m.tags for m in cnt.series]
+        assert grp.num_series >= 2 and (np.asarray(cnt.values) > 1).any()
+        np.testing.assert_array_equal(
+            np.asarray(grp.values),
+            np.where(np.isnan(np.asarray(cnt.values)), np.nan, 1.0))
+
+    @pytest.mark.parametrize("func", _REDUCE_FUNCS)
+    @pytest.mark.parametrize("case", list(_REDUCE_CASES))
+    def test_segment_reduce_sorted_matches_scatter(self, case, func):
+        """The group reduction against numpy's nan-reductions per
+        (group, step), on balanced groups and on one group of 150 of
+        200 rows beside small ones and an empty one.  (The name dates
+        from when a scatter form was the reference.)"""
+        from m3_tpu.query import functions as fn_mod
+
+        vals, gids, G = _REDUCE_CASES[case]()
+        got = fn_mod._segment_reduce(vals, gids, G, func)
+        np.testing.assert_allclose(np.asarray(got),
+                                   _ref_reduce(vals, gids, G, func),
+                                   atol=_atol(func), equal_nan=True)
+
+    def test_segment_reduce_no_series(self):
+        from m3_tpu.query import functions as fn_mod
+
+        out = fn_mod._segment_reduce(np.zeros((0, 7)),
+                                     np.zeros(0, np.int32), 0, "sum")
+        assert out.shape == (0, 7)
+        out = fn_mod._segment_reduce(np.zeros((0, 7)),
+                                     np.zeros(0, np.int32), 2, "count")
+        assert out.shape == (2, 7) and np.isnan(np.asarray(out)).all()
+
+    @pytest.mark.parametrize("func", ["sum", "avg", "max"])
+    def test_segment_reduce_one_global_group(self, func):
+        """`sum(x)` with no `by`: G = 1, the whole block one group."""
+        from m3_tpu.query import functions as fn_mod
+
+        vals, _, _ = _balanced_case()
+        gids = np.zeros(len(vals), np.int32)
+        got = np.asarray(fn_mod._segment_reduce(vals, gids, 1, func))
+        assert got.shape == (1, vals.shape[1])
+        np.testing.assert_allclose(got, _ref_reduce(vals, gids, 1, func),
+                                   atol=1e-9)
+
+    @pytest.mark.parametrize("case", ["shared", "alone"])
+    def test_segment_reduce_all_nan_row_and_group(self, case):
+        """A fully-NaN row is absent from its group; a group of only
+        such rows answers NaN under every func, `count` included."""
+        from m3_tpu.query import functions as fn_mod
+
+        if case == "shared":  # the NaN row beside a present one
+            gids = np.asarray([0, 0, 1, 1], np.int32)
+        else:  # the NaN row is all of group 0; groups 1-4 have no row
+            gids = np.asarray([0] + [5] * 9, np.int32)
+        S, G = len(gids), int(gids.max()) + 1
+        vals = np.arange(S * 3, dtype=np.float64).reshape(S, 3) + 1.0
+        vals[0] = np.nan
+        for func in _REDUCE_FUNCS:
+            got = np.asarray(fn_mod._segment_reduce(vals, gids, G, func))
+            np.testing.assert_allclose(
+                got, _ref_reduce(vals, gids, G, func), atol=_atol(func),
+                equal_nan=True, err_msg=func)
+        if case == "alone":
+            cnt = np.asarray(fn_mod._segment_reduce(vals, gids, G, "count"))
+            assert np.isnan(cnt[0]).all() and (cnt[5] == 9).all()
+
+    @pytest.mark.parametrize("case", ["adjacent", "with_gaps"])
+    def test_segment_reduce_min_max_infinities(self, case):
+        """+-Inf are real samples: they win min/max against finite
+        values; an answer that is the NaN-fill's own infinity (all
+        present values +Inf under min, -Inf under max) reads NaN."""
+        from m3_tpu.query import functions as fn_mod
+
+        inf = np.inf
+        rows = [[-inf, 1.0, inf, inf],       # group 0
+                [2.0, np.nan, inf, 5.0],     # group 0
+                [3.0, -inf, np.nan, -inf]]   # group 1
+        if case == "adjacent":
+            vals = np.asarray(rows)
+            gids = np.asarray([0, 0, 1], np.int32)
+        else:  # empty groups 2-4 between them and nine filler rows
+            vals = np.asarray(rows + [[0.0] * 4] * 9)
+            gids = np.asarray([0, 0, 1] + [5] * 9, np.int32)
+        G = int(gids.max()) + 1
+        mn = np.asarray(fn_mod._segment_reduce(vals, gids, G, "min"))
+        mx = np.asarray(fn_mod._segment_reduce(vals, gids, G, "max"))
+        np.testing.assert_array_equal(mn[0], [-inf, 1.0, np.nan, 5.0])
+        np.testing.assert_array_equal(mx[0], [2.0, 1.0, inf, inf])
+        np.testing.assert_array_equal(mn[1], [3.0, -inf, np.nan, -inf])
+        np.testing.assert_array_equal(mx[1], [3.0, np.nan, np.nan, np.nan])
+
+    @pytest.mark.parametrize("func", ["sum", "quantile"])
+    def test_segment_reduce_stays_on_device(self, func):
+        """Block contract: the (G, T) answer is a device array, f64."""
         import jax
 
         from m3_tpu.query import functions as fn_mod
 
-        rng = np.random.default_rng(17)
-        S, T, G = 200, 13, 23
-        vals = np.round(rng.normal(0, 10, (S, T)), 5)
-        vals[rng.random((S, T)) < 0.15] = np.nan
-        vals[0, :] = np.nan  # one fully-NaN row
-        gids = rng.integers(0, G, S).astype(np.int32)
-        gids[gids == G - 1] = 0  # leave group G-1 EMPTY
-        base = np.asarray(fn_mod._segment_reduce(vals, gids, G, func, q))
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        flip = np.asarray(fn_mod._segment_reduce(vals, gids, G, func, q))
-        np.testing.assert_allclose(flip, base, atol=1e-9, equal_nan=True)
+        vals, gids, G = _balanced_case()
+        out = fn_mod._segment_reduce(vals, gids, G, func, 0.5)
+        assert isinstance(out, jax.Array)
+        assert out.dtype == np.float64 and out.shape == (G, vals.shape[1])
+
+    def test_segment_reduce_is_one_program(self):
+        """What the span's one_program tag asserts, measured: at a shape
+        nothing has compiled yet, a call compiles exactly one program,
+        the kernel (an eager op beside it would compile its own), and
+        the same shape again compiles nothing."""
+        import jax
+
+        from m3_tpu.query import functions as fn_mod
+        from m3_tpu.x import tracewatch
+
+        # a shape no other test compiles; f32, so the widening to f64
+        # has to happen inside the program too
+        vals = jax.device_put(np.ones((37, 11), np.float32))
+        more = jax.device_put(np.full((37, 11), 2.0, np.float32))
+        gids = (np.arange(37) % 5).astype(np.int32)
+        was_installed = tracewatch.installed()
+        tracewatch.install(raise_on_violation=False)
+        try:
+            before = dict(tracewatch.compiles())
+            out = fn_mod._segment_reduce(vals, gids, 5, "sum")
+            new = {k: n - before.get(k, 0)
+                   for k, n in tracewatch.compiles().items()
+                   if n != before.get(k, 0)}
+            assert new == {"_segment_reduce_kernel": 1}
+            snap = tracewatch.snapshot()
+            fn_mod._segment_reduce(more, gids[::-1].copy(), 5, "sum")
+            assert tracewatch.retraces_since(snap) == 0
+        finally:
+            if not was_installed:
+                tracewatch.uninstall()
+        np.testing.assert_array_equal(np.asarray(out)[:, 0],
+                                      [8, 8, 7, 7, 7])
+
+    def test_aggregation_span_says_one_program(self, engine):
+        """sum by (le) (rate(x[5m])) leaves a query.eval.aggregation
+        span tagged n = 1, one_program = 1, and a second call of the
+        same shape compiles nothing (tracewatch: what the benchmark's
+        window_compiles.* reads)."""
+        from m3_tpu.instrument.tracing import Tracepoint, Tracer
+        from m3_tpu.x import tracewatch
+
+        tracer = Tracer(enabled=True)
+        eng = Engine(engine.storage, tracer=tracer)
+        q = 'sum by (le) (rate(latency_bucket[5m]))'
+        first = eng.execute_range(q, QSTART, QEND, STEP)
+        (sp,) = tracer.finished(Tracepoint.EVAL_AGGREGATION)
+        assert sp.tags["op"] == "sum"
+        assert sp.tags["n"] == 1 and sp.tags["one_program"] == 1
+        was_installed = tracewatch.installed()
+        tracewatch.install(raise_on_violation=False)
+        try:
+            snap = tracewatch.snapshot()
+            again = eng.execute_range(q, QSTART, QEND, STEP)
+            assert tracewatch.retraces_since(snap) == 0, \
+                tracewatch.compiles()
+        finally:
+            if not was_installed:
+                tracewatch.uninstall()
+        np.testing.assert_array_equal(again.values, first.values)
+        assert len(tracer.finished(Tracepoint.EVAL_AGGREGATION)) == 2
+        # topk dispatches its mask kernel and then a select: not one
+        eng.execute_range('topk(1, http_requests_total)', QSTART, QEND, STEP)
+        sp = tracer.finished(Tracepoint.EVAL_AGGREGATION)[-1]
+        assert sp.tags["n"] == 1 and sp.tags["one_program"] == 0
 
     def test_scalar_derived_parameter_collapses(self, engine):
         """scalar()-derived parameters must collapse to a float even

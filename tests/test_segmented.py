@@ -1,11 +1,11 @@
-"""Direct coverage for parallel/segmented.py (head_flag_scan,
-last_occurrence) — property tests against numpy oracles.
+"""Direct coverage for parallel/segmented.py (head_flag_scan) and the
+host plan that feeds it (query/device_fns._sorted_plan) — property
+tests against numpy oracles.
 
-The two helpers moved in round 6 and were only exercised transitively
-through the query engine's group-by; these tests pin their contracts
-directly: inclusive within-segment prefix reductions for +/min/max
-(with trailing lane dims), and clamped last-occurrence gather
-positions with a found mask."""
+Both are otherwise exercised only transitively through the query
+engine's group-by; these tests pin their contracts directly: inclusive
+within-segment prefix reductions for +/min/max (with trailing lane
+dims), and clamped segment-end gather positions with a found mask."""
 
 from __future__ import annotations
 
@@ -14,7 +14,8 @@ import pytest
 
 import jax.numpy as jnp
 
-from m3_tpu.parallel.segmented import head_flag_scan, last_occurrence
+from m3_tpu.parallel.segmented import head_flag_scan
+from m3_tpu.query.device_fns import _sorted_plan
 
 
 def _oracle_prefix(is_start: np.ndarray, x: np.ndarray, op):
@@ -87,7 +88,7 @@ class TestHeadFlagScan:
 
     def test_segment_totals_at_last_position(self):
         """The documented consumption pattern: the LAST position of a
-        segment holds the full segment total (what last_occurrence
+        segment holds the full segment total (what the plan's ``pos``
         gathers)."""
         is_start = np.array([1, 0, 0, 1, 0, 1], bool)
         x = np.array([1, 2, 3, 10, 20, 100], np.int64)
@@ -97,40 +98,51 @@ class TestHeadFlagScan:
         assert s[2] == 6 and s[4] == 30 and s[5] == 100
 
 
-class TestLastOccurrence:
+class TestSortedPlan:
+    """The gather side of the scan, owned by the host since the group
+    ids are its own array (`query/device_fns._sorted_plan`): where each
+    group's segment ends in the stable-sorted order, clamped valid, with
+    a found mask."""
+
+    @staticmethod
+    def _plan(gids, num_groups):
+        return _sorted_plan(np.asarray(gids, np.int32), num_groups)
+
     @pytest.mark.parametrize("seed", [0, 1, 2])
     def test_vs_numpy_oracle(self, seed):
         rng = np.random.default_rng(seed)
         n = int(rng.integers(1, 100))
-        keys = np.sort(rng.integers(0, 40, n)).astype(np.int64)
-        queries = rng.integers(-5, 50, 32).astype(np.int64)
-        pos, found = last_occurrence(jnp.asarray(keys), jnp.asarray(queries))
-        pos, found = np.asarray(pos), np.asarray(found)
-        for q, p, f in zip(queries, pos, found):
-            hits = np.nonzero(keys == q)[0]
-            assert f == bool(hits.size), (q, f)
+        G = 50
+        gids = rng.integers(0, 40, n)
+        order, is_start, pos, found = self._plan(gids, G)
+        keys = gids[order]
+        assert (np.diff(keys) >= 0).all()
+        # stable: equal keys keep their input order
+        assert all(a < b for a, b, same in
+                   zip(order[:-1], order[1:], keys[1:] == keys[:-1]) if same)
+        np.testing.assert_array_equal(
+            is_start, np.r_[True, keys[1:] != keys[:-1]])
+        for g, p, f in zip(range(G), pos, found):
+            hits = np.nonzero(keys == g)[0]
+            assert f == bool(hits.size), (g, f)
             if hits.size:
-                assert p == hits[-1], (q, p, hits)
+                assert p == hits[-1], (g, p, hits)
             else:
                 assert 0 <= p < n  # clamped valid for unconditional gather
 
-    def test_empty_queries(self):
-        keys = jnp.asarray(np.array([1, 2, 2, 7], np.int64))
-        pos, found = last_occurrence(keys, jnp.asarray(np.empty(0, np.int64)))
-        assert pos.shape == (0,) and found.shape == (0,)
+    def test_no_groups(self):
+        _, is_start, pos, found = self._plan([], 0)
+        assert is_start.shape == pos.shape == found.shape == (0,)
 
     def test_single_key(self):
-        keys = jnp.asarray(np.array([4], np.int64))
-        pos, found = last_occurrence(
-            keys, jnp.asarray(np.array([3, 4, 5], np.int64)))
-        np.testing.assert_array_equal(np.asarray(found),
-                                      [False, True, False])
-        assert np.asarray(pos)[1] == 0
-        assert ((np.asarray(pos) >= 0) & (np.asarray(pos) < 1)).all()
+        order, is_start, pos, found = self._plan([4], 6)
+        np.testing.assert_array_equal(
+            found, [False, False, False, False, True, False])
+        assert pos[4] == 0 and ((pos >= 0) & (pos < 1)).all()
+        assert order.tolist() == [0] and is_start.tolist() == [True]
 
     def test_duplicates_pick_last(self):
-        keys = jnp.asarray(np.array([2, 2, 2, 5, 5], np.int64))
-        pos, found = last_occurrence(
-            keys, jnp.asarray(np.array([2, 5], np.int64)))
-        np.testing.assert_array_equal(np.asarray(pos), [2, 4])
-        assert np.asarray(found).all()
+        _, is_start, pos, found = self._plan([2, 5, 2, 5, 2], 6)
+        assert pos[2] == 2 and pos[5] == 4
+        assert found.tolist() == [False, False, True, False, False, True]
+        assert is_start.tolist() == [True, False, False, True, False]
