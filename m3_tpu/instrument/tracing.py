@@ -76,9 +76,21 @@ class Tracepoint:
     DB_COMMITLOG_WRITE = "db.commitlog.write"
     DB_LOCK_WAIT = "db.lock.wait"            # acquisition of Database._mu
     DB_READ = "db.read"
+    # under db.read, the sealed part of a batch fetch: one span a fetch
+    # and flushed block (storage/database.py Namespace._decode_block);
+    # tags n (series asked), device / scalar (series the device decoded
+    # / rows through the scalar iterator), words, points, and the
+    # decode's padded shape: rows, steps.  Below it
+    # `.segments` (reader lookups and packing), the guarded
+    # device.decode, `.to_host` (the copy and the values' bits)
+    DB_READ_FILESET = "db.read.fileset"
+    DB_READ_FILESET_SEGMENTS = "db.read.fileset.segments"
+    DB_READ_FILESET_TO_HOST = "db.read.fileset.to_host"
     DB_QUERY_IDS = "db.queryIDs"
     DB_BOOTSTRAP = "db.bootstrap"
     DB_TICK = "db.tick"
+    DB_FLUSH_ENCODE = "db.flush.encode"      # a warm flush's batch encode
+    DB_FLUSH_WRITE = "db.flush.write"        # ... and its fileset volume
     DB_SNAPSHOT = "db.snapshot"
     ENGINE_EXECUTE = "query.engine.execute"
     EVAL_CALL = "query.eval.call"                # tag fn: the function

@@ -249,6 +249,10 @@ class DataFileSetReader:
             self._sum_offs.append(struct.unpack_from("<Q", raw, pos)[0])
             pos += 8
         self.bloom = BloomFilter.from_bytes(p("bloom").read_bytes())
+        # Most datapoints a stream of this volume was seen to hold: the
+        # batch decoder's scan length (storage/database.py learns it
+        # from the streams; the format does not record it).
+        self.max_points: int | None = None
 
     def _mm(self, path: Path, attr_f: str, attr_mm: str):
         if getattr(self, attr_mm) is None:
@@ -343,29 +347,63 @@ class DataFileSetReader:
         e = self._lookup(sid)
         if e is None:
             return None
-        seg = bytes(self._data()[e.offset : e.offset + e.length])
+        return self._segment(self._data(), sid, e.offset, e.length, e.checksum)
+
+    def _segment(self, data, sid: bytes, offset: int, length: int,
+                 checksum: int) -> bytes:
+        """One series' segment out of the data file, checksum-verified."""
+        seg = bytes(data[offset : offset + length])
         # ``fileset.read`` faultpoint: corrupt mode flips one byte of
         # the segment BEFORE the checksum verify, so dtest can exercise
         # the detect→quarantine→repair loop without touching disk.
         _, seg = fault.mangle("fileset.read", seg)
-        if digest(seg) != e.checksum:
+        if digest(seg) != checksum:
             raise ChecksumMismatch(
                 f"segment checksum mismatch for {sid!r}",
                 path=self._data_path, component="fileset",
                 check="segment-checksum")
         return seg
 
+    def read_many(self, sids) -> list[bytes | None]:
+        """:meth:`read` for many ids in ONE pass over the index: one
+        vectorized bloom probe, then the ids in sorted order walk the
+        summaries ladder forward, each index entry between two asked
+        ids looked at once and only its id bytes parsed.  Segment i
+        answers ``sids[i]`` (None where the volume has no entry for
+        it); every segment is checksum-verified as in :meth:`read`."""
+        out: list[bytes | None] = [None] * len(sids)
+        if not len(sids):
+            return out
+        maybe = self.bloom.contains_batch(list(sids))
+        order = sorted((i for i in range(len(sids)) if maybe[i]),
+                       key=sids.__getitem__)
+        raw, data = self._index_raw(), self._data()
+        bucket, pos, end = -1, 0, 0
+        for i in order:
+            sid = sids[i]
+            j = bisect_right(self._sum_ids, sid) - 1
+            if j < 0:
+                continue
+            if j != bucket:
+                bucket, pos = j, self._sum_offs[j]
+                end = (self._sum_offs[j + 1] if j + 1 < len(self._sum_offs)
+                       else len(raw))
+            while pos < end:
+                (idlen,) = struct.unpack_from("<I", raw, pos)
+                eid = raw[pos + 4 : pos + 4 + idlen]
+                if eid < sid:
+                    pos += 20 + idlen
+                    continue
+                if eid == sid:  # the cursor stays: an id asked twice
+                    out[i] = self._segment(data, sid, *struct.unpack_from(
+                        "<QII", raw, pos + 4 + idlen))
+                break
+        return out
+
     def read_all(self) -> Iterator[tuple[bytes, bytes]]:
         mm = self._data()
         for e in self.entries():  # index entries are offset-ordered
-            seg = bytes(mm[e.offset : e.offset + e.length])
-            _, seg = fault.mangle("fileset.read", seg)
-            if digest(seg) != e.checksum:
-                raise ChecksumMismatch(
-                    f"segment checksum mismatch for {e.id!r}",
-                    path=self._data_path, component="fileset",
-                    check="segment-checksum")
-            yield e.id, seg
+            yield e.id, self._segment(mm, e.id, e.offset, e.length, e.checksum)
 
     def __len__(self) -> int:
         return self.info.num_series

@@ -15,9 +15,13 @@ TPU-shaped substitutions:
   volume's streams with the cold points and write volume+1);
 * commit log → WAL appends per ingest batch before buffering.
 
-Reads serve from sealed filesets (scalar/batched decode) merged with the
-open in-memory window — the same two-source merge the reference does with
-`series buffer streams` + `block retriever` (`shard.go:1079`).
+Reads serve from sealed filesets merged with the open in-memory window —
+the same two-source merge the reference does with `series buffer streams`
++ `block retriever` (`shard.go:1079`).  A batch read (`read_columns`,
+`read_batch`) decodes a flushed block's segments of all its ids in one
+batched device decode per fetch (`Namespace._decode_block`) and keeps no
+decoded point afterwards; the single-id `read` goes through the scalar
+iterator and the block cache's decoded-series LRU.
 """
 
 from __future__ import annotations
@@ -36,7 +40,9 @@ from m3_tpu.index.doc import Document, decode_tags, encode_tags
 from m3_tpu.index.namespace_index import NamespaceIndex
 from m3_tpu.index.search import Query
 from m3_tpu.encoding.m3tsz import decode_series, encode_series
-from m3_tpu.encoding.m3tsz_jax import decode_batch, encode_batch
+from m3_tpu.encoding.m3tsz_jax import (
+    decode_batch_device, encode_batch, pack_streams, payload_value_bits,
+)
 from m3_tpu.persist.commitlog import (
     CommitLogEntry, CommitLogWriter, commitlog_seq, list_commitlogs,
     read_commitlog,
@@ -137,6 +143,46 @@ def shard_for_id(sid: bytes, num_shards: int) -> int:
     exists; there is no migration path by design).
     """
     return hash_shard_for(sid, num_shards)
+
+
+# A batch decode's shapes, computed from what a fetch asks for and never
+# set by a user: every distinct (rows, words, points) is one compile of a
+# scan as long as the block has points, so each is rounded up.  Rows to
+# the TPU's lane width; words above the fetch's longest stream; points
+# above the most a stream of the volume was seen to hold.  A fetch of
+# more rows than _DECODE_MAX_ROWS is cut into calls of that many.
+_ROW_BUCKET, _WORD_BUCKET, _POINT_BUCKET = 128, 64, 16
+_DECODE_MAX_ROWS = 4096
+
+
+def _round_up(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
+def _decode_streams(streams: list[bytes], steps: int):
+    """One guarded device decode of whole M3TSZ streams -> their
+    datapoints as flat columns ``(stream, ts, value)`` sorted by
+    (stream, time), the mask of streams the device flagged (``err |
+    prec | ann``: their points are left out, the scalar iterator must
+    read them) and the padded ``(rows, words)`` handed to the device.
+    ``steps`` is the scan's length: a stream with more points is
+    flagged.  Values are rebuilt from the payload's BITS on the host
+    (`payload_value_bits`): the device's f64 never touches a stored
+    value."""
+    n = len(streams)
+    width = _round_up(max(map(len, streams)) // 8 + 2, _WORD_BUCKET)
+    pad = _round_up(n, _ROW_BUCKET) - n
+    words, nbits = pack_streams(streams + [b""] * pad, pad_words=width)
+    ts, payload, meta, err, prec, ann = decode_batch_device(
+        words, nbits, max_points=steps, chains="auto", scan_major=True)
+    with tracing.span(Tracepoint.DB_READ_FILESET_TO_HOST):
+        flagged = (np.asarray(err) | np.asarray(prec) | np.asarray(ann))[:n]
+        meta = np.asarray(meta)[:, :n]                 # scan-major (P, n)
+        keep = (((meta & 16) != 0) & ~flagged).T       # (n, P)
+        bits = payload_value_bits(np.asarray(payload)[:, :n], meta)
+        rows = np.repeat(np.arange(n), keep.sum(axis=1))
+        return (rows, np.asarray(ts)[:, :n].T[keep],
+                bits.T[keep].view(np.float64), flagged, words.shape)
 
 
 class SeriesColumns(NamedTuple):
@@ -270,11 +316,13 @@ class Shard:
         every sample buffered and readable, and the next tick retries
         the flush against whatever space the cleanup freed."""
         slots, ts, vals = self.buffer.peek(block_start)
-        series = self._encode_runs(slots, ts, vals, block_start)
-        DataFileSetWriter(
-            self.root, self.namespace, self.shard_id, block_start,
-            self.opts.block_size_nanos, volume=0,
-        ).write_all(series)
+        with tracing.span(Tracepoint.DB_FLUSH_ENCODE, {"n": len(slots)}):
+            series = self._encode_runs(slots, ts, vals, block_start)
+        with tracing.span(Tracepoint.DB_FLUSH_WRITE, {"n": len(series)}):
+            DataFileSetWriter(
+                self.root, self.namespace, self.shard_id, block_start,
+                self.opts.block_size_nanos, volume=0,
+            ).write_all(series)
         self.buffer.discard(block_start)
         self.flushed_blocks.add(block_start)
         return len(series)
@@ -420,12 +468,12 @@ class Shard:
                 continue
         return None
 
-    def _read_fileset_series(self, block_start: int, sid: bytes,
-                             volume: int | None = None):
-        """Points for ``sid`` from the highest INTACT volume of a block,
-        or None.  A corrupt volume is quarantined and the next-lower
-        volume tried — corruption degrades this one source (buffers and
-        replicas still answer), it never fails the read (the reference's
+    def _from_intact_volume(self, block_start: int, volume: int | None,
+                            consume):
+        """``consume`` on the highest INTACT volume of a block, or None.
+        A corrupt volume is quarantined and the next-lower volume tried
+        — corruption degrades this one source (buffers and replicas
+        still answer), it never fails the read (the reference's
         checksum-verify-and-skip read path, persist/fs/read.go +
         repair.go's expected-corruption contract).
 
@@ -433,19 +481,6 @@ class Shard:
         path reads it directly (no extra directory glob); only a
         corrupt/vanished volume falls back to enumerating what remains
         on disk."""
-        def consume(vol):
-            if self.block_cache is not None:
-                return self.block_cache.read_series(
-                    self.root, self.namespace, self.shard_id,
-                    block_start, vol, sid,
-                )
-            r = DataFileSetReader(
-                self.root, self.namespace, self.shard_id, block_start, vol
-            )
-            seg = r.read(sid)
-            return ([(d.timestamp, d.value) for d in decode_series(seg)]
-                    if seg else None)
-
         if volume is not None:
             try:
                 return consume(volume)
@@ -455,6 +490,44 @@ class Shard:
                 self.quarantine_volume(block_start, volume, e)
             # quarantined/vanished: whatever remains on disk, if anything
         return self._fold_intact_volumes(block_start, consume)
+
+    def _reader(self, block_start: int, volume: int) -> DataFileSetReader:
+        if self.block_cache is not None:
+            return self.block_cache.reader(
+                self.root, self.namespace, self.shard_id, block_start, volume)
+        return DataFileSetReader(
+            self.root, self.namespace, self.shard_id, block_start, volume)
+
+    def _read_fileset_series(self, block_start: int, sid: bytes,
+                             volume: int | None = None):
+        """Points for ``sid`` from the highest intact volume of a block
+        (`_from_intact_volume`), or None: the single-id path, through
+        the scalar iterator and the block cache's decoded-series LRU."""
+        def consume(vol):
+            if self.block_cache is not None:
+                return self.block_cache.read_series(
+                    self.root, self.namespace, self.shard_id,
+                    block_start, vol, sid,
+                )
+            seg = self._reader(block_start, vol).read(sid)
+            return ([(d.timestamp, d.value) for d in decode_series(seg)]
+                    if seg else None)
+
+        return self._from_intact_volume(block_start, volume, consume)
+
+    def read_fileset_segments(self, block_start: int, sids: Sequence[bytes],
+                              volume: int | None = None):
+        """``(reader, [M3TSZ segment or None per id])`` from the highest
+        intact volume of a block (`_from_intact_volume`), or ``(None,
+        ())``: the batch path's disk read, one open reader from the
+        block cache's pool and one pass over its index for all ids.
+        Nothing decoded, nothing kept."""
+        def consume(vol):
+            reader = self._reader(block_start, vol)
+            return reader, reader.read_many(sids)
+
+        return (self._from_intact_volume(block_start, volume, consume)
+                or (None, ()))
 
     # -- read path ---------------------------------------------------------
 
@@ -472,7 +545,7 @@ class Shard:
         lo = start_nanos // bsz * bsz
         filesets = dict(list_filesets(self.root, self.namespace, self.shard_id))
         sources: list[list[tuple[int, float]]] = []
-        for bs in range(lo, end_nanos + bsz, bsz):
+        for bs in range(lo, end_nanos, bsz):  # a block at `end` holds no t < end
             if bs in filesets:
                 pts = self._read_fileset_series(bs, sid, volume=filesets[bs])
                 if pts:
@@ -498,7 +571,7 @@ class Shard:
         return [(t, v) for t, v in merged if start_nanos <= t < end_nanos]
 
     def read_columns(self, sids: Sequence[bytes], start_nanos: int,
-                     end_nanos: int):
+                     end_nanos: int, fileset):
         """Batched :meth:`read` as flat columns ``(row, ts, vals)``
         sorted by (row, ts), row i being ``sids[i]``, plus the mask of
         rows that had a source made of tuples: same sources, same merge
@@ -507,30 +580,21 @@ class Shard:
         ``start <= t < end``), with every source as arrays.  Per block
         this pays one sorted-window snapshot and one cold-overflow sort
         for all ids, and a series whose only sources are open windows
-        never leaves numpy.  Fileset sources stay per-id lists of
-        tuples from the scalar decoder (the block cache amortizes the
-        disk read across ids): what a batch decoder would replace."""
+        never leaves numpy.  ``fileset`` is this shard's part of
+        `Namespace._fileset_sources`: the flushed blocks' ``(row, ts,
+        vals)`` chunks, decoded with the other shards' in one device
+        call, and the mask of rows the scalar iterator had to read."""
         bsz = self.opts.block_size_nanos
         lo = start_nanos // bsz * bsz
-        filesets = dict(list_filesets(self.root, self.namespace, self.shard_id))
         slots = np.asarray(
             [s if (s := self.slots.get(sid)) is not None else -1
              for sid in sids], np.int64)
-        tupled = np.zeros(len(sids), bool)
-        chunks: list[tuple] = []  # (row, ts, vals) in the merge's order
-        windows_only = True
-        for bs in range(lo, end_nanos + bsz, bsz):
-            if bs in filesets:
-                vol = filesets[bs]
-                for i, sid in enumerate(sids):
-                    pts = self._read_fileset_series(bs, sid, volume=vol)
-                    if pts:
-                        tupled[i] = True
-                        windows_only = False
-                        chunks.append((
-                            np.full(len(pts), i),
-                            np.asarray([t for t, _ in pts], np.int64),
-                            np.asarray([v for _, v in pts], np.float64)))
+        # (row, ts, vals) in the merge's order.  The fileset chunks of
+        # every block stand before the buffers': what the merge orders
+        # is equal (row, ts), and those share a block
+        chunks, tupled = list(fileset[0]), fileset[1]
+        windows_only = not chunks
+        for bs in range(lo, end_nanos, bsz):  # a block at `end` holds no t < end
             if bs in self.buffer.open_blocks:
                 chunks.append(_gather_runs(*self.buffer.peek(bs), slots))
             if bs in self.buffer.cold:
@@ -555,35 +619,37 @@ class Shard:
             # one open window's runs are sorted and deduped as they
             # come; anything else goes through the one merge: a stable
             # sort by (row, ts) leaves equal timestamps in source
-            # order, and the last of them wins
-            order = np.lexsort((ts, rows))
-            rows, ts, vals = rows[order], ts[order], vals[order]
-            last = np.ones(len(rows), bool)
-            last[:-1] = (rows[1:] != rows[:-1]) | (ts[1:] != ts[:-1])
-            rows, ts, vals = rows[last], ts[last], vals[last]
+            # order, and the last of them wins.  Columns that already
+            # rise strictly in (row, ts) (one flushed block's decode)
+            # are their own merge
+            rises = (rows[1:] > rows[:-1]) | (
+                (rows[1:] == rows[:-1]) & (ts[1:] > ts[:-1]))
+            if not rises.all():
+                order = np.lexsort((ts, rows))
+                rows, ts, vals = rows[order], ts[order], vals[order]
+                last = np.ones(len(rows), bool)
+                last[:-1] = (rows[1:] != rows[:-1]) | (ts[1:] != ts[:-1])
+                rows, ts, vals = rows[last], ts[last], vals[last]
         inr = (ts >= start_nanos) & (ts < end_nanos)
         if not inr.all():
             rows, ts, vals = rows[inr], ts[inr], vals[inr]
         return rows, ts, vals, tupled
 
-    def read_many(self, sids: Sequence[bytes], start_nanos: int,
-                  end_nanos: int) -> list[list[tuple[int, float]]]:
-        """:meth:`read_columns` as one point list per requested id (the
-        RPC / session / verification shape)."""
-        rows, ts, vals, _ = self.read_columns(sids, start_nanos, end_nanos)
-        bounds = np.searchsorted(rows, np.arange(len(sids) + 1)).tolist()
-        ts, vals = ts.tolist(), vals.tolist()
-        return [list(zip(ts[a:b], vals[a:b]))
-                for a, b in zip(bounds[:-1], bounds[1:])]
-
 
 class Namespace:
     def __init__(self, name: str, opts: NamespaceOptions, root: str,
                  block_cache=None, new_series_limiter=None,
-                 corruption_cb=None):
+                 corruption_cb=None, scope=None):
         self.name = name
         self.opts = opts
         self.root = root
+        # what the batch read's fileset part counts on /metrics (the
+        # Database's `db` scope): series the device decoded, rows the
+        # scalar iterator had to read, datapoints decoded
+        self._fileset_counters = None if scope is None else tuple(
+            scope.counter("fileset_" + c) for c in (
+                "series_device_decoded", "series_scalar_decoded",
+                "decode_points"))
         self.shards = [
             Shard(name, i, opts, root, block_cache,
                   new_series_limiter=new_series_limiter,
@@ -680,22 +746,135 @@ class Namespace:
                                 []).append(i)
         return by_shard
 
+    def _fileset_sources(self, by_shard: Dict[int, List[int]],
+                         sids: Sequence[bytes], start: int, end: int) -> dict:
+        """The sealed part of a batch read, ``{shard: (chunks, tupled)}``
+        for `Shard.read_columns`: per flushed block the range touches,
+        the segments of the asked ids from ALL the shards in one batch
+        (`_decode_block`) — not a decode per shard: four shards give
+        four row counts a selector, and every distinct shape is a
+        compile of a scan as long as the block."""
+        bsz = self.opts.block_size_nanos
+        out = {sh: ([], np.zeros(len(idxs), bool))
+               for sh, idxs in by_shard.items()}
+        filesets = {sh: dict(list_filesets(self.root, self.name, sh))
+                    for sh in by_shard}
+        for bs in range(start // bsz * bsz, end, bsz):
+            parts = [(sh, filesets[sh][bs]) for sh in by_shard
+                     if bs in filesets[sh]]
+            if parts:
+                xdeadline.check_current("fetch series")
+                self._decode_block(bs, parts, by_shard, sids, out)
+        return out
+
+    def _decode_block(self, block_start: int, parts: list, by_shard: dict,
+                      sids: Sequence[bytes], out: dict) -> None:
+        """One flushed block of one fetch: the asked ids' segments from
+        each shard's open reader, packed once, decoded in one guarded
+        device call (`_decode_streams`; a fetch over `_DECODE_MAX_ROWS`
+        in several), each shard's rows appended to ``out`` as ``(row,
+        ts, value)`` arrays.  A stream the device flags goes through the
+        scalar iterator, is counted and marks its row tupled.  Nothing
+        decoded outlives the fetch: what a node keeps between fetches is
+        the encoded block (the reader's page-cache-backed mmap), as
+        upstream's series cache policies do."""
+        asked = sum(len(by_shard[sh]) for sh, _ in parts)
+        with tracing.span(Tracepoint.DB_READ_FILESET, {"n": asked}) as sp:
+            with tracing.span(Tracepoint.DB_READ_FILESET_SEGMENTS):
+                streams, home = [], []  # home: a stream's (shard, row, reader)
+                for sh, vol in parts:
+                    reader, segs = self.shards[sh].read_fileset_segments(
+                        block_start, [sids[i] for i in by_shard[sh]], vol)
+                    found = [(r, seg) for r, seg in enumerate(segs) if seg]
+                    if not found:
+                        continue
+                    if reader.max_points is None:
+                        # the format records no count: the volume's
+                        # longest stream asked for says how long a scan
+                        # its decode needs (a longer one is flagged,
+                        # read by the scalar iterator and raises this)
+                        reader.max_points = len(decode_series(
+                            max((seg for _, seg in found), key=len)))
+                    streams.extend(seg for _, seg in found)
+                    home.extend((sh, r, reader) for r, _ in found)
+            if not streams:
+                return
+            row_of = np.fromiter((r for _, r, _ in home), np.int64, len(home))
+            steps = _round_up(max(h[2].max_points for h in home),
+                              _POINT_BUCKET)
+            n_points = n_scalar = n_rows = n_words = 0
+            for a in range(0, len(streams), _DECODE_MAX_ROWS):
+                b = min(a + _DECODE_MAX_ROWS, len(streams))
+                rows, ts, vals, flagged, shape = _decode_streams(
+                    streams[a:b], steps)
+                n_rows += shape[0]
+                n_words += shape[0] * shape[1]
+                n_points += len(rows)
+                # streams stand shard by shard: a shard's are a run
+                edges = [a] + [g for g in range(a + 1, b)
+                               if home[g][0] != home[g - 1][0]] + [b]
+                cuts = np.searchsorted(rows, np.asarray(edges) - a)
+                for g, lo, hi in zip(edges, cuts[:-1], cuts[1:]):
+                    out[home[g][0]][0].append(
+                        (row_of[rows[lo:hi] + a], ts[lo:hi], vals[lo:hi]))
+                for g in (np.nonzero(flagged)[0] + a).tolist():
+                    sh, r, reader = home[g]
+                    pts = decode_series(streams[g])
+                    reader.max_points = max(reader.max_points, len(pts))
+                    out[sh][0].append((
+                        np.full(len(pts), r),
+                        np.fromiter((d.timestamp for d in pts), np.int64,
+                                    len(pts)),
+                        np.fromiter((d.value for d in pts), np.float64,
+                                    len(pts))))
+                    out[sh][1][r] = True
+                    n_scalar += 1
+                    n_points += len(pts)
+            sp.set_tag("device", len(streams) - n_scalar)
+            sp.set_tag("scalar", n_scalar)
+            sp.set_tag("words", n_words)
+            sp.set_tag("points", n_points)
+            # the decode's padded shape: rows handed to the device (all
+            # calls), the scan's length
+            sp.set_tag("rows", n_rows)
+            sp.set_tag("steps", steps)
+        if self._fileset_counters is not None:
+            for c, v in zip(self._fileset_counters,
+                            (len(streams) - n_scalar, n_scalar, n_points)):
+                c.inc(v)
+
+    def _read_shards(self, sids: Sequence[bytes], by_shard: dict,
+                     start: int, end: int):
+        """The one batch read: (positions in ``sids``, `Shard.
+        read_columns` of them) per shard of ``by_shard``, the flushed
+        blocks decoded for all of them together first.  A bound
+        deadline is checked between shards, so a cancelled query
+        stops."""
+        fileset = self._fileset_sources(by_shard, sids, start, end)
+        for sh, idxs in by_shard.items():
+            xdeadline.check_current("fetch series")
+            yield idxs, self.shards[sh].read_columns(
+                [sids[i] for i in idxs], start, end, fileset[sh])
+
     def read_many(self, sids: Sequence[bytes], start: int,
                   end: int) -> list[list[tuple[int, float]]]:
-        """Batched read: group by shard, amortize the per-window sort
-        (Shard.read_columns), return point lists aligned with ``sids``.
-        The ownership gate is per SHARD and atomic like write_batch's
-        all-unowned case: any unowned shard in the batch raises typed
-        (the session fans single-shard sub-batches, so this maps to one
-        routing miss, never a partially-silent read)."""
+        """Batched read as one point list per requested id (the RPC /
+        session / verification shape): `_read_shards`' columns cut into
+        lists.  The ownership gate is per SHARD and atomic like
+        write_batch's all-unowned case: any unowned shard in the batch
+        raises typed (the session fans single-shard sub-batches, so
+        this maps to one routing miss, never a partially-silent
+        read)."""
         by_shard = self._by_shard(sids)
         for sh in by_shard:
             self.check_owned(sh)
         out: list = [None] * len(sids)
-        for sh, idxs in by_shard.items():
-            for i, pts in zip(idxs, self.shards[sh].read_many(
-                    [sids[i] for i in idxs], start, end)):
-                out[i] = pts
+        for idxs, (rows, ts, vals, _) in self._read_shards(
+                sids, by_shard, start, end):
+            bounds = np.searchsorted(rows, np.arange(len(idxs) + 1)).tolist()
+            ts, vals = ts.tolist(), vals.tolist()
+            for i, a, b in zip(idxs, bounds[:-1], bounds[1:]):
+                out[i] = list(zip(ts[a:b], vals[a:b]))
         return out
 
     def read_columns(self, sids: Sequence[bytes], start: int,
@@ -705,8 +884,7 @@ class Namespace:
         shards": the index still knows series whose shard the placement
         moved away — a local query answers from what this node owns,
         and ``index`` says which ids those are; the cluster-level union
-        comes from the session's replica fan-out).  A bound deadline is
-        checked between shards, so a cancelled query stops."""
+        comes from the session's replica fan-out)."""
         by_shard = self._by_shard(sids)
         if self.owned is not None:
             by_shard = {sh: idxs for sh, idxs in by_shard.items()
@@ -718,10 +896,8 @@ class Namespace:
         counts = np.zeros(len(index), np.int64)
         columnar = len(index)
         parts = []
-        for sh, idxs in by_shard.items():
-            xdeadline.check_current("fetch series")
-            rows, ts, vals, tupled = self.shards[sh].read_columns(
-                [sids[i] for i in idxs], start, end)
+        for idxs, (rows, ts, vals, tupled) in self._read_shards(
+                sids, by_shard, start, end):
             columnar -= int(tupled.sum())
             # a shard's rows are runs in the order asked: the rank in
             # the run is the column
@@ -819,7 +995,7 @@ class Database:
             self.namespaces[name] = Namespace(
                 name, nopts, self.opts.root, self.block_cache,
                 new_series_limiter=self.new_series_limiter,
-                corruption_cb=self._note_corruption,
+                corruption_cb=self._note_corruption, scope=self._scope,
             )
         self.commitlog = (
             CommitLogWriter(
@@ -941,7 +1117,7 @@ class Database:
                     name, opts or NamespaceOptions(), self.opts.root,
                     self.block_cache,
                     new_series_limiter=self.new_series_limiter,
-                    corruption_cb=self._note_corruption,
+                    corruption_cb=self._note_corruption, scope=self._scope,
                 )
                 tpl = self._ownership_template
                 if tpl is not None and tpl[0] == ns.opts.num_shards:

@@ -134,6 +134,29 @@ class TestCodecPrograms:
                      scan_major=True, extract=extract)
         assert _has_kernel(c) == (extract == "pallas")
 
+    @pytest.mark.parametrize("words", [128, 192])
+    def test_decode_a_fetch_of_a_sealed_block(self, one_chip, words,
+                                              monkeypatch):
+        """The served read path's decode (storage/database.py
+        `_decode_streams`) at `prom.dashboard_flushed`'s shape: 390
+        series a panel in a row bucket of 512, a full 2 h block of 720
+        points, counter streams in a word bucket of 128 or 192, the
+        tail `chains="auto"` resolves to on a TPU."""
+        from m3_tpu.encoding import m3tsz_jax as mj
+        from m3_tpu.parallel import pallas_decode
+        from m3_tpu.storage import database
+
+        monkeypatch.setattr(pallas_decode, "auto_interpret", lambda: False)
+        assert 512 % database._ROW_BUCKET == 0
+        assert words % database._WORD_BUCKET == 0
+        assert 720 % database._POINT_BUCKET == 0
+        c = _compile(mj._decode_batch_device, one_chip,
+                     A((512, words), jnp.uint64), A((512,), jnp.int64),
+                     A((1 << 18,), jnp.uint32),
+                     max_points=720, default_unit=1, chains="gather",
+                     scan_major=True, extract="pallas")
+        assert _has_kernel(c)
+
 
 class TestStoragePrograms:
     # a 2-window ring of 1M samples per shard (chip_smoke's node at

@@ -338,10 +338,10 @@ class TestSeamSemantics:
         read = []
         real = Shard.read_columns
 
-        def cancel_after_first(self, sids, start, end):
+        def cancel_after_first(self, *args):
             read.append(self.shard_id)
             dl.cancel()
-            return real(self, sids, start, end)
+            return real(self, *args)
 
         monkeypatch.setattr(Shard, "read_columns", cancel_after_first)
         with xdeadline.bind(dl), pytest.raises(DeadlineExceeded):
@@ -387,15 +387,26 @@ class TestMechanismEngages:
                     Tracepoint.DB_QUERY_IDS, Tracepoint.DB_READ]
         db.close()
 
-    def test_a_fileset_source_is_not_columnar(self, tmp_path):
-        """The one merge takes every source as arrays; a series the
-        scalar decoder handed over as tuples is counted out of
-        ``columnar``, a cold part (arrays as it arrived) is not."""
+    def test_a_fileset_source_is_columnar(self, tmp_path):
+        """The one merge takes every source as arrays: a flushed block
+        arrives from the batch device decode as arrays too (a series the
+        device flags and the scalar iterator reads is counted out of
+        ``columnar``: tests/test_flushed_read.py), and so does a cold
+        part (arrays as it arrived)."""
+        from m3_tpu.instrument import tracing
+
         db, tracer = self._traced(tmp_path)
         start, end = cold_overflow(db)
-        DatabaseStorage(db).fetch_raw(b"m", (), start, end)
+        tracing.install(tracer)  # as run_node does: the spans below db.read
+        try:
+            DatabaseStorage(db).fetch_raw(b"m", (), start, end)
+        finally:
+            tracing.uninstall(tracer)
         (span,) = tracer.finished(Tracepoint.DB_READ)
-        assert span.tags["n"] == N and span.tags["columnar"] == 0
+        assert span.tags["n"] == N and span.tags["columnar"] == N
+        (fs,) = tracer.finished(Tracepoint.DB_READ_FILESET)
+        assert fs.parent_id == span.span_id
+        assert (fs.tags["n"], fs.tags["device"], fs.tags["scalar"]) == (N, N, 0)
         # the open block alone, beside cold parts: arrays only
         DatabaseStorage(db).fetch_raw(b"m", (), T0 + BLOCK, T0 + BLOCK + MIN)
         span = tracer.finished(Tracepoint.DB_READ)[-1]
@@ -413,6 +424,9 @@ class TestMechanismEngages:
         st.fetch_raw(b"m", (), T0 + BLOCK, T0 + BLOCK + MIN)
         text = reg.render_prometheus()
         assert f"m3tpu_db_fetch_series {2 * N}" in text
-        assert f"m3tpu_db_fetch_series_columnar {N}" in text
+        assert f"m3tpu_db_fetch_series_columnar {2 * N}" in text
+        assert f"m3tpu_db_fileset_series_device_decoded {N}" in text
+        assert "m3tpu_db_fileset_series_scalar_decoded 0" in text
+        assert f"m3tpu_db_fileset_decode_points {6 * N}" in text
         assert f"m3tpu_db_reads {2 * N}" in text
         db.close()

@@ -759,10 +759,11 @@ def test_new_per_layer_entries_are_well_formed():
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     # PR 25's, PR 26's, PR 30's and PR 32's entries (PR 27's `.agg` entries are
     # held by tests/test_aggregator_service.py, PR 31's `.timer` entries
-    # by tests/test_aggregator_timer_service.py)
+    # by tests/test_aggregator_timer_service.py, PR 33's `.flushed`
+    # entries by tests/test_flushed_read.py)
     new = [m for m in bench["per_layer"] if m["source"] == "program_span"
            and m["name"] != "maintain_ms_per_pass"
-           and not m["name"].endswith((".agg", ".timer"))]
+           and not m["name"].endswith((".agg", ".timer", ".flushed"))]
     assert len(new) == 29
     layers = {m["layer"] for m in bench["per_layer"]
               if m not in new}
