@@ -52,7 +52,7 @@ def decode_to_step_series(words, nbits, max_points: int, ctrl_tbl,
                           chains: str = "fused", extract: str = "jnp"):
     """Device decode of packed streams -> padded (ts, float64 values)
     ready for the temporal stencils: invalid slots carry ts = i64 max
-    (excluded by the window searchsorted) and NaN values.
+    (beyond every window edge the bounds compare against) and NaN values.
 
     Query math runs in the backend's native f64 (emulated on TPU):
     range-function output is not part of the bit-exactness contract the

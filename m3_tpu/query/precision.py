@@ -9,7 +9,7 @@ selects it.
 
 The policy narrows the BULK stencil math (temporal kernels, the
 histogram-quantile kernel) to f32 when selected, keeping:
-- window *bounds* exact (i64 searchsorted, unaffected);
+- window *bounds* exact (counts of i64 comparisons, unaffected);
 - times recentered at the first step before narrowing, so f32 holds
   window-relative nanos (<=hours, ~0.4ms resolution) instead of epoch
   nanos;

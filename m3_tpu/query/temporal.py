@@ -7,10 +7,14 @@ deriv/predict_linear (`linear_regression.go`).  The reference walks each
 series' datapoints per step with per-series goroutine batches
 (`base.go:172-230`); here every (series, step) window is computed at once:
 
-* window boundaries via two vmapped `searchsorted`s over the sorted
-  per-series timestamps → (S, T) lo/hi index matrices;
-* sum/count/avg/stddev + the rate family read **prefix sums** and
-  boundary gathers — O(S·(P+T)) with no window materialization;
+* window boundaries by COUNTING: lo/hi[s, t] = #{p : ts[s, p] <= edge[t]},
+  a comparison of the sorted per-series timestamps against the window
+  edges fused into a sum over P → (S, T) lo/hi index matrices;
+* sum/count/avg/stddev + the rate family read **prefix sums** and the
+  window's end samples by a select against iota(P) fused into a max
+  over P — S·P·T lane operations, nothing of that size stored, and no
+  binary search or per-element gather (on a TPU those are dependent
+  rounds at ~10 ns an element: PERF.md section 6, PR 34);
 * min/max/quantile gather a bounded (S, T, W) window tensor (W = max
   points per window, a static pad) — the stencil form.
 
@@ -33,11 +37,20 @@ NAN = jnp.nan  # weak-typed: jnp.where keeps the value operand dtype
 
 def _window_bounds(ts, step_times, range_nanos):
     """(S, T) lo/hi: half-open [lo, hi) indices of samples in
-    (step - range, step] per series."""
-    starts = step_times - range_nanos  # (T,)
-    lo = jax.vmap(lambda row: jnp.searchsorted(row, starts, side="right"))(ts)
-    hi = jax.vmap(lambda row: jnp.searchsorted(row, step_times, side="right"))(ts)
-    return lo.astype(jnp.int32), hi.astype(jnp.int32)
+    (step - range, step] per series.
+
+    A bound is a COUNT, ``#{p : ts[s, p] <= edge[t]}``: on sorted rows
+    that is ``searchsorted(row, edge, side="right")`` for every row the
+    callers hand over (duplicates, the i64-max padded tail, an all-pad
+    row).  The (S, P, T) comparison fuses into its reduction over P, so
+    nothing of that size is stored; a binary search would be log2(P)
+    dependent rounds of per-element gathers, which a TPU runs at ~10 ns
+    an element (PERF.md section 6, PR 34)."""
+    def count_le(edges):  # (T,) -> (S, T) i32
+        return jnp.sum(ts[:, :, None] <= edges[None, None, :], axis=1,
+                       dtype=jnp.int32)
+
+    return count_le(step_times - range_nanos), count_le(step_times)
 
 
 def _prefix(vals):
@@ -48,8 +61,18 @@ def _prefix(vals):
 
 
 def _gather_rows(a, idx):
-    """a (S, P), idx (S, T) -> a[s, idx[s, t]]."""
-    return jnp.take_along_axis(a, idx, axis=1)
+    """a (S, P), idx (S, T) in [0, P) -> a[s, idx[s, t]].
+
+    A select against iota(P) under a max over P, fused like the bounds
+    and for the same reason (``take_along_axis`` is a per-element
+    gather, two for a 64-bit operand).  A SELECTION, so the chosen
+    element arrives with its bits, whatever the device's f64 and i64
+    are; a masked sum would turn -0.0 into +0.0."""
+    floating = jnp.issubdtype(a.dtype, jnp.floating)
+    lowest = -jnp.inf if floating else jnp.iinfo(a.dtype).min
+    pos = jnp.arange(a.shape[1], dtype=jnp.int32)[None, :, None]
+    hit = idx[:, None, :] == pos  # (S, P, T), never stored
+    return jnp.max(jnp.where(hit, a[:, :, None], lowest), axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("func",))
@@ -156,9 +179,9 @@ def rate_family(ts, vals, step_times, range_nanos, func: str,
     # differences: sampled / dur_start / dur_end are bounded by the
     # range window, so they fit any float dtype regardless of where the
     # query sits on the epoch axis or how long its span is (epoch nanos
-    # themselves fit neither f32 nor even f64 exactly).  Gathered pad
-    # entries (i64 max) wrap to garbage — every lane that can read one
-    # is masked below (has2 / sampled>0 / dt>0).
+    # themselves fit neither f32 nor even f64 exactly).  Pad entries
+    # read at an end (i64 max) wrap to garbage — every lane that can
+    # read one is masked below (has2 / sampled>0 / dt>0).
     v_first = _gather_rows(adj, first_i)
     v_last = _gather_rows(adj, last_i)
     ti_first = _gather_rows(ts, first_i)  # i64 (S, T)
@@ -256,7 +279,7 @@ def transitions_family(ts, vals, step_times, range_nanos, func: str):
     count the transitions between CONSECUTIVE samples inside each
     window — resets counts v[i] < v[i-1] (counter restarts), changes
     counts v[i] != v[i-1].  Prefix-summed over the adjacent-pair
-    indicator, so the windowed count is two gathers: pairs (i-1, i)
+    indicator, so the windowed count is two reads: pairs (i-1, i)
     with both ends inside [lo, hi) are those with i in [lo+1, hi)."""
     lo, hi = _window_bounds(ts, step_times, range_nanos)
     prev = jnp.concatenate([vals[:, :1], vals[:, :-1]], axis=1)

@@ -287,12 +287,32 @@ class TestQueryPrograms:
     programs: the rate stencil, the group reduction (a segmented scan
     on the host's sorted plan) and the histogram_quantile kernel."""
 
-    def test_rate(self, one_chip):
+    @pytest.mark.parametrize("series,points,steps", [
+        (S, T, T),            # the node's width
+        (395, 256, T),        # a panel of prom.dashboard_live
+        (390, 720, T),        # a panel of prom.dashboard_flushed
+        (6250, 256, T),       # every bucket series of the fleet at once
+    ])
+    def test_rate(self, one_chip, series, points, steps):
+        """Window ends by comparison (PR 34): no binary search (a
+        `while` of log2(P) dependent gather rounds) and no per-element
+        gather is left in the program.  Both steps of the issue ship:
+        the bounds count, the five end reads are a select against
+        iota(P) and a max over P (measured on the chip at all three
+        shapes and kept: 1.4 / 2.5 / 24.8 ms a call against 10.0 / 9.9
+        / 161 with the ten gathers; PERF.md section 6).  The (S, P, T)
+        comparison is fused into its reduction, never stored."""
         from m3_tpu.query import temporal
 
-        _compile(temporal.rate_family, one_chip,
-                 A((S, T), jnp.int64), A((S, T), jnp.float64),
-                 A((T,), jnp.int64), A((), jnp.int64), func="rate")
+        c = _compile(temporal.rate_family, one_chip,
+                     A((series, points), jnp.int64),
+                     A((series, points), jnp.float64),
+                     A((steps,), jnp.int64), A((), jnp.int64), func="rate")
+        text = c.as_text()
+        assert " while(" not in text
+        assert " gather(" not in text
+        assert (c.memory_analysis().temp_size_in_bytes
+                < series * points * steps)
 
     def test_group_reduce(self, one_chip):
         """The panel's shape and the node's (S, T): the host owns the
