@@ -15,6 +15,18 @@ gauges right before every exposition:
 * ``process_open_fds``              — ``/proc/self/fd`` entry count
 * ``process_uptime_seconds``        — wall seconds since process start
 
+and, from the process's tracer (``instrument/tracing.py``: its runtime
+hooks), what says that a node is out of interpreter or compiling, not
+out of chip:
+
+* ``runtime_gil_probes_total``, ``runtime_gil_probes_contended_total``,
+  ``runtime_gil_wait_seconds_total`` — the interpreter-lock probe's
+  account (it runs only while spans record: ``coordinator.tracing`` or
+  a profiler session; flat otherwise)
+* ``jit_compiles_total``, ``jit_compile_seconds_total{phase=trace|
+  lower|compile|cache_read}``, ``jit_compile_cache_hits_total``,
+  ``jit_compile_cache_misses_total`` — the compile log's totals
+
 Gauges are interned ONCE at install (metric-hygiene: no per-scrape
 name build), values that cannot be read on this platform (non-procfs)
 simply keep their last value — the scrape stays strict-parse green
@@ -27,6 +39,7 @@ import os
 import threading
 import time
 
+from m3_tpu.instrument import tracing
 from m3_tpu.instrument.debug import _START_TIME
 
 __all__ = ["ProcessCollector", "install_process_collector"]
@@ -60,6 +73,23 @@ class ProcessCollector:
         self._g_threads = scope.gauge("process_threads")
         self._g_fds = scope.gauge("process_open_fds")
         self._g_uptime = scope.gauge("process_uptime_seconds")
+        self._g_gil_probes = scope.gauge("runtime_gil_probes_total")
+        self._g_gil_contended = scope.gauge(
+            "runtime_gil_probes_contended_total")
+        self._g_gil_wait = scope.gauge("runtime_gil_wait_seconds_total")
+        self._g_compiles = scope.gauge("jit_compiles_total")
+        self._g_compile_hits = scope.gauge("jit_compile_cache_hits_total")
+        self._g_compile_misses = scope.gauge("jit_compile_cache_misses_total")
+        self._g_compile_seconds = {
+            "trace": scope.tagged({"phase": "trace"}).gauge(
+                "jit_compile_seconds_total"),
+            "lower": scope.tagged({"phase": "lower"}).gauge(
+                "jit_compile_seconds_total"),
+            "compile": scope.tagged({"phase": "compile"}).gauge(
+                "jit_compile_seconds_total"),
+            "cache_read": scope.tagged({"phase": "cache_read"}).gauge(
+                "jit_compile_seconds_total"),
+        }
 
     def __call__(self) -> None:
         rss = _rss_bytes()
@@ -72,6 +102,15 @@ class ProcessCollector:
         if fds is not None:
             self._g_fds.update(fds)
         self._g_uptime.update(time.time() - _START_TIME)
+        tracer = tracing.process_tracer()
+        self._g_gil_probes.update(tracer.gil_probes)
+        self._g_gil_contended.update(tracer.gil_contended)
+        self._g_gil_wait.update(tracer.gil_wait_ns / 1e9)
+        self._g_compiles.update(tracer.compile_count)
+        self._g_compile_hits.update(tracer.compile_cache_hits)
+        self._g_compile_misses.update(tracer.compile_cache_misses)
+        for phase, seconds in tracer.compile_seconds.items():
+            self._g_compile_seconds[phase].update(seconds)
 
 
 def install_process_collector(registry, scope) -> ProcessCollector:

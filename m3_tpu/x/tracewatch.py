@@ -51,10 +51,14 @@ Honesty notes:
 
 * Budgets are per *function name* as jax reports it: two same-named
   lambdas share a count.  Name real hot-path functions.
-* A persistent-compilation-cache hit still counts as a compile: the
-  trace ran and a new executable was installed — exactly the per-shape
-  cost the sanitizer exists to surface (only the XLA backend time was
-  saved).
+* A persistent-compilation-cache hit still counts as a compile here:
+  the trace ran and a new executable was installed — the per-shape
+  event the sanitizer exists to surface.  This module counts events
+  and keeps no times: what a program cost (seconds of trace, lower,
+  backend compile or cache read), whether the cache answered, and which
+  request waited for it is in the compile log of the process's tracer
+  (``m3_tpu/instrument/tracing.py``: ``Tracer.compile_log()``, the
+  ``runtime.compile`` span, ``jit_compile_*`` on ``/metrics``).
 * The ``__array__`` patch is process-global while installed but checks
   a thread-local arm flag, so only threads inside ``no_transfers()``
   are guarded.

@@ -436,7 +436,9 @@ class TestBenchmarkEntries:
         assert conf["source"] == cfg["source"]
         assert sorted(conf["reduced"]) == sorted(cfg["reduced"])
         assert conf["dataset"]["scale"] * 16 == conf["reduced"]["series"]["here"]
-        agg = [m for m in bench["per_layer"] if m["name"].endswith(".agg")]
+        agg = [m for m in bench["per_layer"] if m["name"].endswith(".agg")
+               # PR 35's two are held by tests/test_node_spans.py
+               and not m["name"].startswith("gil_")]
         assert len(agg) == 17
         layers = {m["layer"] for m in bench["per_layer"]}
         (rate,) = [m for m in bench["end_to_end"]

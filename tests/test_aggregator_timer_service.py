@@ -335,7 +335,9 @@ class TestBenchmarkEntries:
             "bf16", "lost_frame", "rank_plus_one"}
         node = (REPO / "benchmark" / "configs" / conf["node"]).read_text()
         assert "timer: [P50, P95, P99]" in node
-        timer = [m for m in bench["per_layer"] if m["name"].endswith(".timer")]
+        timer = [m for m in bench["per_layer"] if m["name"].endswith(".timer")
+                 # PR 35's two are held by tests/test_node_spans.py
+                 and not m["name"].startswith("gil_")]
         assert len(timer) == 20
         layers = {m["layer"] for m in bench["per_layer"]
                   if not m["name"].endswith(".timer")}

@@ -370,7 +370,9 @@ def test_the_cells_per_layer_entries_are_well_formed():
     repo = Path(__file__).resolve().parent.parent
     bench = json.loads((repo / "BENCHMARK.json").read_text())
     cell = "prom.dashboard_flushed"
-    mine = [m for m in bench["per_layer"] if m["name"].endswith(".flushed")]
+    mine = [m for m in bench["per_layer"] if m["name"].endswith(".flushed")
+            # PR 35's two are held by tests/test_node_spans.py
+            and not m["name"].startswith("gil_")]
     assert len(mine) == 22
     (e2e,) = [m for m in bench["end_to_end"] if m["name"] == "queries_per_s"]
     assert cell in e2e["workloads"]

@@ -35,8 +35,9 @@ sys.path.insert(0, str(REPO))
 
 from benchmark import selftest, stats  # noqa: E402
 from benchmark.reducers import (  # noqa: E402
-    node_span_ms, node_span_slice_pct, node_span_tag_pct,
-    node_span_unnamed_pct, node_spans, trace_idle_unnamed_pct, tracefile,
+    compile_log, node_span_ms, node_span_slice_pct, node_span_tag_mean,
+    node_span_tag_pct, node_span_unnamed_pct, node_spans,
+    trace_idle_unnamed_pct, tracefile,
 )
 
 
@@ -225,6 +226,14 @@ class TestRing:
         tracing.install(tr)
         per_thread, threads = 400, 8
         collections = []
+        # the interpreter-lock probe's roots go through the same ring
+        probes, record = [], tr.record
+
+        def counting(name, *a, **kw):
+            probes.append(name)
+            record(name, *a, **kw)
+
+        tr.record = counting
 
         def count(phase, info):
             if phase == "stop" and info["generation"] >= 1:
@@ -254,8 +263,9 @@ class TestRing:
             tracing.uninstall(tr)
         held = tr.finished()
         assert len(held) == 64 and collections
+        assert set(probes) <= {Tracepoint.RUNTIME_GIL_PROBE}
         assert len(held) + tr.dropped == (
-            2 * per_thread * threads + len(collections))
+            2 * per_thread * threads + len(collections) + len(probes))
         assert 0 < tr.dropped_until_ns <= max(s.end_ns for s in held)
         # no stack was left unbalanced: a new span is a root again
         with tr.start_span("after") as sp:
@@ -310,7 +320,8 @@ class TestSpanSites:
             tracing.uninstall(tr)
             devguard.reset_stages()
             devguard.reset_counters()
-        names = [s.name for s in tr.finished()]
+        names = [s.name for s in tr.finished()
+                 if s.name != Tracepoint.RUNTIME_GIL_PROBE]
         assert names == ["device.t.stage", "device.t.stage"]
         assert tracing.span("after uninstall") is NOOP_SPAN
 
@@ -413,6 +424,275 @@ mediator: {{enabled: true, tick_interval: 1h}}
                 assert (parent[f"aggregator.drain.{kind}.{leaf}"]
                         == f"aggregator.drain.{kind}")
         assert parent["device.arena.consume"].startswith("aggregator.drain.")
+
+
+# -- the runtime beneath the spans: the interpreter lock, the compiler -----------
+
+
+def _probe_threads() -> list:
+    return [t for t in threading.enumerate() if t.name == tracing.PROBE_THREAD]
+
+
+@pytest.fixture
+def hooked():
+    """A tracer installed as the process's, as run_node installs its."""
+    tr = Tracer()
+    tracing.install(tr)
+    yield tr
+    tracing.uninstall(tr)
+    assert _probe_threads() == []
+
+
+def _spin(stop):
+    while not stop.is_set():
+        for _ in range(1000):
+            pass                          # pure Python: holds the lock
+
+
+def _sort(stop):
+    a = np.random.default_rng(0).random(1_000_000)
+    while not stop.is_set():
+        np.sort(a)                        # native: releases it
+
+
+def _contended_share(tr, target, seconds=1.0) -> float:
+    """`contended / n` over the probe's spans while `target` runs."""
+    stop = threading.Event()
+    worker = threading.Thread(target=target, args=(stop,), daemon=True)
+    worker.start()
+    time.sleep(0.05)
+    tr.clear()
+    with tr.start_span("x"):
+        pass                              # a recorded span: the probe is up
+    time.sleep(seconds)
+    stop.set()
+    worker.join(timeout=10)
+    assert not worker.is_alive()
+    probes = tr.finished(Tracepoint.RUNTIME_GIL_PROBE)
+    assert probes
+    return (sum(s.tags["contended"] for s in probes)
+            / sum(s.tags["n"] for s in probes))
+
+
+class TestGilProbe:
+    def test_not_recording_starts_no_thread(self):
+        tr = Tracer(enabled=False)
+        tracing.install(tr)
+        try:
+            for _ in range(1000):
+                with tr.start_span(Tracepoint.API_WRITE):
+                    with tr.start_span(Tracepoint.DB_WRITE_BATCH):
+                        pass
+            assert _probe_threads() == [] and tr._probe is None
+        finally:
+            tracing.uninstall(tr)
+        assert tr.finished() == [] and tr.gil_probes == 0
+
+    def test_a_tracer_that_is_not_the_processes_has_no_probe(self):
+        tr = Tracer()
+        for _ in range(10):
+            with tr.start_span("a"):
+                pass
+        assert _probe_threads() == [] and tr._probe is None
+
+    # best of three: a loaded machine makes a probe late for a core, not
+    # for the interpreter, and an idle moment lets a spinner's probe in
+    @pytest.mark.parametrize("target, holds", [(_spin, True), (_sort, False)],
+                             ids=["python_spinner", "numpy_sort"])
+    def test_share_of_late_probes_is_the_share_the_lock_is_held(
+            self, hooked, target, holds):
+        shares = []
+        for _ in range(3):
+            shares.append(_contended_share(hooked, target))
+            if (shares[-1] >= 0.9) if holds else (shares[-1] <= 0.2):
+                break
+        else:
+            pytest.fail(f"contended / n read {shares}")
+        assert hooked.gil_probes >= sum(
+            s.tags["n"] for s in hooked.finished(Tracepoint.RUNTIME_GIL_PROBE))
+
+    def test_probe_ends_by_itself_once_nothing_records(self, hooked):
+        with hooked.start_span("a"):
+            pass
+        (probe,) = _probe_threads()
+        assert probe.daemon
+        time.sleep(0.25)
+        hooked.enabled = False
+        probe.join(timeout=1.0)
+        assert not probe.is_alive() and hooked._probe is None
+        seen = len(hooked.finished(Tracepoint.RUNTIME_GIL_PROBE))
+        assert seen >= 1
+        # and the next span that records starts another
+        hooked.enabled = True
+        with hooked.start_span("b"):
+            pass
+        assert len(_probe_threads()) == 1
+
+    def test_probe_spans_are_roots_that_name_no_idle_gap_and_no_work(
+            self, hooked):
+        with hooked.start_span(Tracepoint.API_WRITE, {"n": 1000}):
+            with hooked.start_span(Tracepoint.DB_WRITE_BATCH):
+                time.sleep(0.3)
+        probes = hooked.finished(Tracepoint.RUNTIME_GIL_PROBE)
+        assert probes and all(s.parent_id is None for s in probes)
+        assert all(s.tags.keys() == {"n", "contended", "wait_us", "max_us"}
+                   and s.tags["n"] >= s.tags["contended"] for s in probes)
+        assert all(90e6 <= s.duration_ns < 200e6 for s in probes)
+        cell = _cell(hooked, slice_=(0.0, time.monotonic() + 1))
+        spans = node_spans.load(cell)
+        assert spans.work("ksample") == 1.0
+        assert spans.work("query") == 0 and spans.work("pass") == 0
+        assert Tracepoint.RUNTIME_GIL_PROBE in spans.by_name()
+        # the table's own rule for what may name a gap of the device
+        tr = tracefile.Trace({"d": [("a", 0.0, 0.01), ("b", 5.0, 0.01)]},
+                             {}, [("req", 0.0, 1.0)] * 3, 5.01)
+        t0 = min(n.t0 for n in spans.touching)
+        cell = _cell(hooked, slice_=(t0, t0 + 5.01), trace_events=tr,
+                     rows=[stats.Request("write", t0, t0 + 1.0, True, 1, i)
+                           for i in range(3)])
+        by_span = trace_idle_unnamed_pct.table(cell, node_spans.load(cell))
+        assert set(by_span) == {Tracepoint.DB_WRITE_BATCH, "nothing_due"}
+
+
+class TestCompileLog:
+    def test_a_fresh_jit_is_one_row_and_a_child_of_what_waited(self, hooked):
+        def fresh_program(x):
+            return (x * 3.0 + 1.0).sum()
+
+        x = jax.numpy.arange(64.0)
+        before = len(hooked.compile_log())
+        with hooked.start_span("outer") as outer:
+            jax.jit(fresh_program)(x).block_until_ready()
+        (row,) = [r for r in hooked.compile_log()[before:]
+                  if "fresh_program" in r.fn]
+        assert row.trace_s > 0 and row.lower_s > 0 and row.compile_s > 0
+        assert row.thread == threading.current_thread().name
+        assert row.seconds <= (row.end_ns - row.start_ns) / 1e9 + 1e-3
+        (child,) = [s for s in hooked.finished(Tracepoint.RUNTIME_COMPILE)
+                    if s.tags["fn"] == row.fn]
+        assert child.parent_id == outer.span.span_id
+        assert child.trace_id == outer.span.trace_id
+        assert (outer.span.start_ns <= child.start_ns
+                and child.end_ns <= outer.span.end_ns)
+        assert child.tags["cache"] == row.cache
+        assert child.tags["compile_s"] == row.compile_s
+        assert hooked.compile_count >= 1
+        assert hooked.compile_seconds["compile"] >= row.compile_s
+        # the same call again compiles nothing
+        n = len(hooked.compile_log())
+        jax.jit(fresh_program)
+        assert len(hooked.compile_log()) == n
+
+    def test_outside_a_span_the_row_is_kept_and_no_span_opens(self, hooked):
+        jax.jit(lambda x: x * 5.0 - 2.0)(jax.numpy.arange(9.0))
+        assert hooked.compile_log()
+        assert hooked.finished(Tracepoint.RUNTIME_COMPILE) == []
+
+    def test_second_compile_of_a_program_reads_the_persistent_cache(
+            self, hooked, tmp_path):
+        from jax.experimental.compilation_cache import compilation_cache as cc
+
+        keys = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_compile_time_secs",
+                "jax_persistent_cache_min_entry_size_bytes")
+        was = {k: getattr(jax.config, k) for k in keys}
+        jax.config.update(keys[0], str(tmp_path))
+        jax.config.update(keys[1], 0)
+        jax.config.update(keys[2], -1)
+        cc.reset_cache()
+        try:
+            def twice_compiled(x):
+                return jax.numpy.cumsum(x * 7.0)[-1]
+
+            x = jax.numpy.arange(33.0)
+            jax.jit(twice_compiled)(x).block_until_ready()
+            jax.clear_caches()
+            jax.jit(twice_compiled)(x).block_until_ready()
+        finally:
+            for k, v in was.items():
+                jax.config.update(k, v)
+            cc.reset_cache()
+        first, second = [r for r in hooked.compile_log()
+                         if "twice_compiled" in r.fn]
+        assert first.cache == "miss" and first.cache_read_s == 0
+        assert second.cache == "hit" and second.cache_read_s > 0
+        assert hooked.compile_cache_hits >= 1
+        assert hooked.compile_cache_misses >= 1
+
+    def test_after_uninstall_nothing_is_logged_and_no_span_opens(self):
+        tr = Tracer()
+        tracing.install(tr)
+        tracing.uninstall(tr)
+        with tr.start_span("outer"):
+            jax.jit(lambda x: x / 3.0 + 11.0)(jax.numpy.arange(5.0))
+        assert tr.compile_log() == [] and tr.compile_count == 0
+        assert [s.name for s in tr.finished()] == ["outer"]
+        assert _probe_threads() == []
+
+    def test_the_log_keeps_the_newest_rows_and_counts_the_rest(
+            self, monkeypatch):
+        monkeypatch.setattr(tracing, "COMPILE_LOG_MAX", 3)
+        tr = Tracer()
+        for i in range(5):
+            tr._on_compile_duration(
+                "/jax/core/compile/jaxpr_trace_duration", 0.25, fun_name="f")
+            tr._on_compile_duration(
+                "/jax/core/compile/backend_compile_duration", 0.5,
+                fun_name=f"jit(f{i})")
+        assert [r.fn for r in tr.compile_log()] == [
+            "jit(f2)", "jit(f3)", "jit(f4)"]
+        assert tr.compiles_dropped == 2 and tr.compile_count == 5
+        assert tr.compile_seconds["trace"] == pytest.approx(1.25)
+        assert tr.compile_seconds["compile"] == pytest.approx(2.5)
+
+    def test_an_outer_trace_takes_its_inner_traces_place(self):
+        tr = Tracer()
+        dur = tr._on_compile_duration
+        trace = "/jax/core/compile/jaxpr_trace_duration"
+        dur(trace, 5.0, fun_name="never_compiled")    # an eval_shape
+        time.sleep(0.01)
+        dur(trace, 0.001, fun_name="inner_a")
+        dur(trace, 0.001, fun_name="inner_b")
+        dur(trace, 0.004, fun_name="outer")           # began before both
+        dur("/jax/core/compile/jaxpr_to_mlir_module_duration", 0.002,
+            fun_name="jit_outer")
+        tr._on_compile_event("/jax/compilation_cache/cache_hits")
+        dur("/jax/compilation_cache/compile_time_saved_sec", 1.5)
+        dur("/jax/compilation_cache/cache_retrieval_time_sec", 0.001)
+        dur("/jax/core/compile/backend_compile_duration", 0.003,
+            fun_name="jit(outer)")
+        dur("/jax/some/other/duration", 9.0)
+        (row,) = tr.compile_log()
+        assert row.fn == "jit(outer)" and row.cache == "hit"
+        assert (row.trace_s, row.lower_s, row.cache_read_s, row.saved_s) == (
+            0.004, 0.002, 0.001, 1.5)
+        assert row.compile_s == pytest.approx(0.002)
+        assert row.seconds == pytest.approx(0.009)
+
+
+def test_runtime_counters_are_on_metrics(node):
+    _write(node.port)
+    _query(node.port)
+    assert _probe_threads() == []       # tracing off: served with no probe
+    jax.jit(lambda x: x * 13.0 + 0.5)(jax.numpy.arange(11.0))
+    text = urllib.request.urlopen(
+        f"http://127.0.0.1:{node.port}/metrics").read().decode()
+    values = {}
+    for line in text.splitlines():
+        if line.startswith(("m3tpu_runtime_gil_", "m3tpu_jit_compile")):
+            name, value = line.rsplit(" ", 1)
+            values[name] = float(value)
+    assert values["m3tpu_jit_compiles_total"] >= 1
+    assert values['m3tpu_jit_compile_seconds_total{phase="compile"}'] > 0
+    assert {'m3tpu_jit_compile_seconds_total{phase="%s"}' % p
+            for p in ("trace", "lower", "compile", "cache_read")} <= set(values)
+    assert {"m3tpu_jit_compile_cache_hits_total",
+            "m3tpu_jit_compile_cache_misses_total",
+            "m3tpu_runtime_gil_probes_total",
+            "m3tpu_runtime_gil_probes_contended_total",
+            "m3tpu_runtime_gil_wait_seconds_total"} <= set(values)
+    # tracing is off in this node: no probe ran
+    assert values["m3tpu_runtime_gil_probes_total"] == 0
 
 
 # -- the reducers ---------------------------------------------------------------
@@ -551,6 +831,73 @@ class TestReducers:
             "spans": ["api.write.decode"], "tag": "hits",
             "of": "series"}) is None
         assert trace_idle_unnamed_pct.read(cell, {}) is None
+        assert node_span_tag_mean.read(cell, {
+            "spans": ["runtime.gil.probe"], "tag": "wait_us", "of": "n",
+            "scale": 0.001}) is None
+        for what in ("seconds", "programs", "cache_hit_pct"):
+            # none of these tracers keeps a compile log
+            assert compile_log.read(cell, {"read": what}) is None
+
+    def test_tag_mean_is_one_tags_sum_over_anothers(self):
+        probe = "runtime.gil.probe"
+        rows = [(1, None, probe, 0.0, 0.1,
+                 {"n": 20, "contended": 1, "wait_us": 700, "max_us": 700}),
+                (2, None, probe, 0.1, 0.2,
+                 {"n": 6, "contended": 6, "wait_us": 90300, "max_us": 24000}),
+                (3, None, "api.write", 0.0, 10.0, {"n": 2000}),
+                (4, None, probe, 29.95, 30.05,     # ends past the slice
+                 {"n": 9, "contended": 9, "wait_us": 9000, "max_us": 1000}),
+                (5, None, probe, 0.2, 0.3, {})]    # an older probe: no tags
+        cell = _cell(_FakeTracer(node_spans.build(rows)))
+        params = {"spans": [probe], "tag": "wait_us", "of": "n",
+                  "scale": 0.001}
+        assert node_span_tag_mean.read(cell, params) == pytest.approx(
+            91.0 / 26)
+        assert node_span_tag_mean.read(
+            cell, {**params, "scale": 1}) == pytest.approx(91000 / 26)
+        assert node_span_tag_pct.read(cell, {
+            "spans": [probe], "tag": "contended", "of": "n"}) == \
+            pytest.approx(100 * 7 / 26)
+        # the probe's roots count as nobody's work
+        assert node_spans.load(cell).work("ksample") == pytest.approx(2.0)
+        assert node_span_tag_mean.read(
+            cell, {**params, "spans": ["api.write"]}) is None
+        assert node_span_tag_mean.read(
+            _cell(_FakeTracer(node_spans.build(rows[2:3]))), params) is None
+
+    def test_compile_log_reads_the_rows_that_ended_before_the_window(self):
+        row = tracing.CompileRow
+        log = [row("jit(a)", "t", 1 * 10**9, 3 * 10**9, trace_s=0.5,
+                   lower_s=0.25, compile_s=1.0, cache="miss"),
+               row("jit(b)", "t", 3 * 10**9, 4 * 10**9, trace_s=0.125,
+                   lower_s=0.125, compile_s=0.25, cache_read_s=0.5,
+                   saved_s=7.0, cache="hit"),
+               row("jit(c)", "u", 4 * 10**9, 5 * 10**9, compile_s=0.0625),
+               row("jit(in_window)", "t", 9 * 10**9, 11 * 10**9,
+                   compile_s=2.0, cache="miss")]
+        tracer = SimpleNamespace(compile_log=lambda: list(log),
+                                 compiles_dropped=0)
+        cell = SimpleNamespace(asm=SimpleNamespace(tracer=tracer),
+                               window=(10.0, 50.0))
+        read = compile_log.read
+        assert read(cell, {"read": "seconds"}) == pytest.approx(2.8125)
+        assert read(cell, {"read": "programs"}) == 3
+        assert read(cell, {"read": "cache_hit_pct"}) == pytest.approx(50.0)
+        with pytest.raises(ValueError):
+            read(cell, {"read": "something_else"})
+        # the cache took part in none; nothing before the window; a log
+        # that pushed rows out; a tracer without one
+        cell.window = (4.5, 50.0)
+        log[:2] = []
+        assert read(cell, {"read": "programs"}) is None
+        cell.window = (5.0, 50.0)
+        assert read(cell, {"read": "programs"}) == 1
+        assert read(cell, {"read": "cache_hit_pct"}) is None
+        tracer.compiles_dropped = 1
+        assert read(cell, {"read": "programs"}) is None
+        cell.asm.tracer = Tracer()
+        cell.window = (time.monotonic() + 1, None)
+        assert read(cell, {"read": "programs"}) is None      # an empty log
 
     def test_overflow_before_the_slice_is_still_a_whole_account(self):
         cell = _cell(_FakeTracer(_tree(), dropped=5, dropped_until_ns=10**9),
@@ -763,7 +1110,8 @@ def test_new_per_layer_entries_are_well_formed():
     # entries by tests/test_flushed_read.py)
     new = [m for m in bench["per_layer"] if m["source"] == "program_span"
            and m["name"] != "maintain_ms_per_pass"
-           and not m["name"].endswith((".agg", ".timer", ".flushed"))]
+           and not m["name"].endswith((".agg", ".timer", ".flushed"))
+           and not m["name"].startswith("gil_")]   # PR 35's: below
     assert len(new) == 29
     layers = {m["layer"] for m in bench["per_layer"]
               if m not in new}
@@ -781,3 +1129,38 @@ def test_new_per_layer_entries_are_well_formed():
         assert spec["reducer"] in ("node_span_ms", "node_span_unnamed_pct",
                                    "node_span_slice_pct", "node_span_tag_pct",
                                    "trace_idle_unnamed_pct")
+
+
+def test_runtime_entries_are_well_formed():
+    """PR 35's fifteen: two readings of the interpreter-lock probe a
+    cell, and the first three metrics under setup_s, in every cell."""
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    cells = [w["name"] for w in bench["workloads"]]
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    gil = [m for m in bench["per_layer"] if m["name"].startswith("gil_")]
+    assert len(gil) == 12
+    for m in gil:
+        kind, suffix = m["name"].split(".")
+        gc_twin = by_name["gc_pause_pct." + suffix]
+        assert (m["layer"], m["moves"], m["workloads"], m["source"]) == (
+            "guards", gc_twin["moves"], gc_twin["workloads"], "program_span")
+        assert m["better"] == "lower"
+        assert m["unit"] == {"gil_contended_pct": "%", "gil_wait_ms": "ms"}[kind]
+        spec = json.loads((REPO / "benchmark" / "metrics"
+                           / (m["name"] + ".json")).read_text())
+        assert spec["reducer"] == {"gil_contended_pct": "node_span_tag_pct",
+                                   "gil_wait_ms": "node_span_tag_mean"}[kind]
+        assert spec["params"]["spans"] == [Tracepoint.RUNTIME_GIL_PROBE]
+    assert sorted(c for m in gil for c in m["workloads"]) == sorted(cells * 2)
+    under_setup = [m for m in bench["per_layer"] if m["moves"] == "setup_s"]
+    assert [m["name"] for m in under_setup] == [
+        "setup_compile_s", "setup_programs", "setup_cache_hit_pct"]
+    assert "workloads" not in e2e["setup_s"]
+    for m in under_setup:
+        assert (m["layer"], m["source"], m["workloads"]) == (
+            "guards", "program_counter", cells)
+        spec = json.loads((REPO / "benchmark" / "metrics"
+                           / (m["name"] + ".json")).read_text())
+        assert spec["reducer"] == "compile_log"
+    assert bench["per_layer"][-15:] == gil + under_setup

@@ -131,9 +131,14 @@ mediator: {{enabled: false}}
             admin = json.loads(_get(
                 f"http://127.0.0.1:{asm.admin_port}/api/v1/debug/traces"))
             assert admin["status"] == "success"
-            # same ring: identical span ids through either port
-            assert ({s["span_id"] for s in admin["data"]}
-                    == {s["span_id"] for s in main["data"]})
+            # same ring: identical span ids through either port (the
+            # interpreter-lock probe adds a root about every 100 ms
+            # while tracing is on: one may land between the two reads)
+            def ids(doc):
+                return {s["span_id"] for s in doc["data"]
+                        if s["name"] != "runtime.gil.probe"}
+
+            assert ids(admin) == ids(main) != set()
         finally:
             asm.close()
 
