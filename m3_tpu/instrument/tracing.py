@@ -132,12 +132,17 @@ class Tracepoint:
     DB_COMMITLOG_WRITE = "db.commitlog.write"
     DB_LOCK_WAIT = "db.lock.wait"            # acquisition of Database._mu
     DB_READ = "db.read"
-    # under db.read, the sealed part of a batch fetch: one span a fetch
-    # and flushed block (storage/database.py Namespace._decode_block);
+    # under db.read, the part of a batch fetch that holds Database._mu
+    # (storage/database.py Database._fetch): the read plan's segments
+    # (`db.read.fileset.segments`, one a flushed block: reader lookups,
+    # bloom, index walk, checksums), slots and buffer snapshots; tags n
+    # (series asked), streams (segments read)
+    DB_READ_LOCKED = "db.read.locked"
+    # under db.read, after the release, the sealed part of a batch
+    # fetch: one span a fetch and flushed block (Namespace._decode_block);
     # tags n (series asked), device / scalar (series the device decoded
     # / rows through the scalar iterator), words, points, and the
-    # decode's padded shape: rows, steps.  Below it
-    # `.segments` (reader lookups and packing), the guarded
+    # decode's padded shape: rows, steps.  Below it the guarded
     # device.decode, `.to_host` (the copy and the values' bits)
     DB_READ_FILESET = "db.read.fileset"
     DB_READ_FILESET_SEGMENTS = "db.read.fileset.segments"

@@ -20,12 +20,16 @@ the same two-source merge the reference does with `series buffer streams`
 + `block retriever` (`shard.go:1079`).  A batch read (`read_columns`,
 `read_batch`) decodes a flushed block's segments of all its ids in one
 batched device decode per fetch (`Namespace._decode_block`) and keeps no
-decoded point afterwards; the single-id `read` goes through the scalar
-iterator and the block cache's decoded-series LRU.
+decoded point afterwards; it holds the engine lock only while it takes
+its read plan (segments, slots, buffer snapshots) and decodes, merges
+and cuts after the release.  The single-id `read` goes through the
+scalar iterator and the block cache's decoded-series LRU, under the
+lock.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 from pathlib import Path
@@ -196,12 +200,46 @@ class SeriesColumns(NamedTuple):
     columnar: int  # rows whose every source was already arrays
 
 
+class _BlockSegments(NamedTuple):
+    """One flushed block of a batch fetch as read under the lock
+    (`Namespace._block_segments`), decoded after it
+    (`Namespace._decode_block`)."""
+
+    asked: int  # ids asked of the shards that have a volume of the block
+    streams: list  # M3TSZ segments, shard by shard
+    home: list  # a stream's (shard, row in the shard's ids, reader)
+    steps: int  # the decode scan's length
+
+
+class _ReadPlan(NamedTuple):
+    """What a batch fetch takes under `Database._mu` (phase 1,
+    `Namespace._plan`): bytes copied out of immutable volumes and
+    references to buffer arrays that are never changed in place.  The
+    decode, merge and cut run on it after the release (phase 2,
+    `Namespace._read_shards`), so the answer is the database as it
+    stood when the lock was let go."""
+
+    n: int  # ids asked
+    start: int
+    end: int
+    by_shard: dict  # shard -> positions in the ids asked
+    blocks: list  # [_BlockSegments] in block order
+    buffered: dict  # shard -> `Shard.buffer_sources`
+
+    @property
+    def streams(self) -> int:
+        return sum(len(b.streams) for b in self.blocks)
+
+
 def _gather_runs(keys: np.ndarray, ts: np.ndarray, vals: np.ndarray,
                  slots: np.ndarray):
     """The runs of ``slots`` out of columns sorted by slot ``keys``, as
     flat ``(row, ts, val)``: row i is ``slots[i]``'s run, rows in the
     order asked, each run in the columns' order.  A slot < 0 (an id the
     shard never saw) has no run."""
+    # in the keys' dtype: a window's slots are i32, and searchsorted
+    # would otherwise cast the whole window to i64 on every call
+    slots = slots.astype(keys.dtype, copy=False)
     los = np.searchsorted(keys, slots)
     lens = np.where(slots >= 0, np.searchsorted(keys, slots + 1) - los, 0)
     rows = np.repeat(np.arange(len(slots)), lens)
@@ -570,38 +608,59 @@ class Shard:
         )
         return [(t, v) for t, v in merged if start_nanos <= t < end_nanos]
 
-    def read_columns(self, sids: Sequence[bytes], start_nanos: int,
-                     end_nanos: int, fileset):
-        """Batched :meth:`read` as flat columns ``(row, ts, vals)``
-        sorted by (row, ts), row i being ``sids[i]``, plus the mask of
-        rows that had a source made of tuples: same sources, same merge
-        and range contract as the single-id path (fileset volume, open
-        warm buffer, cold overflow; a later source wins a timestamp;
-        ``start <= t < end``), with every source as arrays.  Per block
-        this pays one sorted-window snapshot and one cold-overflow sort
-        for all ids, and a series whose only sources are open windows
-        never leaves numpy.  ``fileset`` is this shard's part of
-        `Namespace._fileset_sources`: the flushed blocks' ``(row, ts,
-        vals)`` chunks, decoded with the other shards' in one device
-        call, and the mask of rows the scalar iterator had to read."""
+    def buffer_sources(self, sids: Sequence[bytes], start_nanos: int,
+                       end_nanos: int):
+        """What :meth:`read_columns` needs of this shard's mutable
+        state, taken under `Database._mu`: the slots of ``sids`` (-1 for
+        an id never seen) and, per block of the range in order, the
+        open window's sorted snapshot (`ShardBuffer.peek`: its arrays
+        are never changed in place, a write makes the next reader build
+        new ones) as ``(False, (slots, ts, vals))`` and a copy of the
+        cold-overflow list (its parts are copies as they arrived) as
+        ``(True, parts)``."""
         bsz = self.opts.block_size_nanos
-        lo = start_nanos // bsz * bsz
         slots = np.asarray(
             [s if (s := self.slots.get(sid)) is not None else -1
              for sid in sids], np.int64)
+        sources = []
+        for bs in range(start_nanos // bsz * bsz, end_nanos, bsz):
+            # a block at `end` holds no t < end
+            if bs in self.buffer.open_blocks:
+                sources.append((False, self.buffer.peek(bs)))
+            if bs in self.buffer.cold:
+                # Cold writes awaiting flush are readable immediately
+                # (the reference reads cold buckets too — versioned
+                # buckets in buffer.go:1016 serve un-flushed cold data).
+                sources.append((True, list(self.buffer.cold[bs])))
+        return slots, sources
+
+    def read_columns(self, buffered, start_nanos: int, end_nanos: int,
+                     fileset):
+        """Batched :meth:`read` as flat columns ``(row, ts, vals)``
+        sorted by (row, ts), row i being the i-th id asked, plus the
+        mask of rows that had a source made of tuples: same sources,
+        same merge and range contract as the single-id path (fileset
+        volume, open warm buffer, cold overflow; a later source wins a
+        timestamp; ``start <= t < end``), with every source as arrays.
+        Per block this pays one sorted-window snapshot and one
+        cold-overflow sort for all ids, and a series whose only sources
+        are open windows never leaves numpy.  ``buffered`` is what
+        :meth:`buffer_sources` took under the lock; ``fileset`` is this
+        shard's part of what `Namespace._read_shards` decoded: the
+        flushed blocks' ``(row, ts, vals)`` chunks, decoded with the
+        other shards' in one device call, and the mask of rows the
+        scalar iterator had to read.  Reads nothing of the shard: it runs
+        after the lock is released."""
+        slots, sources = buffered
         # (row, ts, vals) in the merge's order.  The fileset chunks of
         # every block stand before the buffers': what the merge orders
         # is equal (row, ts), and those share a block
         chunks, tupled = list(fileset[0]), fileset[1]
         windows_only = not chunks
-        for bs in range(lo, end_nanos, bsz):  # a block at `end` holds no t < end
-            if bs in self.buffer.open_blocks:
-                chunks.append(_gather_runs(*self.buffer.peek(bs), slots))
-            if bs in self.buffer.cold:
-                # Cold writes awaiting flush are readable immediately
-                # (the reference reads cold buckets too — versioned
-                # buckets in buffer.go:1016 serve un-flushed cold data).
-                parts = self.buffer.cold[bs]
+        for cold, parts in sources:
+            if not cold:
+                chunks.append(_gather_runs(*parts, slots))
+            else:
                 cslots = np.concatenate([p[0] for p in parts]).astype(np.int64)
                 # arrival-stable sort by slot: a run keeps arrival order
                 # (the cold merge rule's tie-break input)
@@ -746,62 +805,72 @@ class Namespace:
                                 []).append(i)
         return by_shard
 
-    def _fileset_sources(self, by_shard: Dict[int, List[int]],
-                         sids: Sequence[bytes], start: int, end: int) -> dict:
-        """The sealed part of a batch read, ``{shard: (chunks, tupled)}``
-        for `Shard.read_columns`: per flushed block the range touches,
-        the segments of the asked ids from ALL the shards in one batch
-        (`_decode_block`) — not a decode per shard: four shards give
-        four row counts a selector, and every distinct shape is a
-        compile of a scan as long as the block."""
+    def _fileset_segments(self, by_shard: Dict[int, List[int]],
+                          sids: Sequence[bytes], start: int,
+                          end: int) -> list:
+        """The sealed part of a batch read as phase 1 takes it, under
+        `Database._mu`: per flushed block the range touches, the
+        segments of the asked ids from ALL the shards (`_BlockSegments`,
+        decoded together by `_decode_block` after the release) — not a
+        decode per shard: four shards give four row counts a selector,
+        and every distinct shape is a compile of a scan as long as the
+        block."""
         bsz = self.opts.block_size_nanos
-        out = {sh: ([], np.zeros(len(idxs), bool))
-               for sh, idxs in by_shard.items()}
         filesets = {sh: dict(list_filesets(self.root, self.name, sh))
                     for sh in by_shard}
+        blocks = []
         for bs in range(start // bsz * bsz, end, bsz):
             parts = [(sh, filesets[sh][bs]) for sh in by_shard
                      if bs in filesets[sh]]
             if parts:
                 xdeadline.check_current("fetch series")
-                self._decode_block(bs, parts, by_shard, sids, out)
-        return out
+                blocks.append(self._block_segments(bs, parts, by_shard, sids))
+        return blocks
 
-    def _decode_block(self, block_start: int, parts: list, by_shard: dict,
-                      sids: Sequence[bytes], out: dict) -> None:
-        """One flushed block of one fetch: the asked ids' segments from
-        each shard's open reader, packed once, decoded in one guarded
-        device call (`_decode_streams`; a fetch over `_DECODE_MAX_ROWS`
-        in several), each shard's rows appended to ``out`` as ``(row,
-        ts, value)`` arrays.  A stream the device flags goes through the
-        scalar iterator, is counted and marks its row tupled.  Nothing
-        decoded outlives the fetch: what a node keeps between fetches is
-        the encoded block (the reader's page-cache-backed mmap), as
-        upstream's series cache policies do."""
+    def _block_segments(self, block_start: int, parts: list, by_shard: dict,
+                        sids: Sequence[bytes]) -> _BlockSegments:
+        """One flushed block of one fetch, read under the lock: the
+        asked ids' segments from each shard's open reader (copies out of
+        an immutable volume; a corrupt volume is quarantined here and
+        the next lower one read) and the scan's length, from what the
+        readers learned of their volumes."""
         asked = sum(len(by_shard[sh]) for sh, _ in parts)
-        with tracing.span(Tracepoint.DB_READ_FILESET, {"n": asked}) as sp:
-            with tracing.span(Tracepoint.DB_READ_FILESET_SEGMENTS):
-                streams, home = [], []  # home: a stream's (shard, row, reader)
-                for sh, vol in parts:
-                    reader, segs = self.shards[sh].read_fileset_segments(
-                        block_start, [sids[i] for i in by_shard[sh]], vol)
-                    found = [(r, seg) for r, seg in enumerate(segs) if seg]
-                    if not found:
-                        continue
-                    if reader.max_points is None:
-                        # the format records no count: the volume's
-                        # longest stream asked for says how long a scan
-                        # its decode needs (a longer one is flagged,
-                        # read by the scalar iterator and raises this)
-                        reader.max_points = len(decode_series(
-                            max((seg for _, seg in found), key=len)))
-                    streams.extend(seg for _, seg in found)
-                    home.extend((sh, r, reader) for r, _ in found)
+        with tracing.span(Tracepoint.DB_READ_FILESET_SEGMENTS):
+            streams, home = [], []  # home: a stream's (shard, row, reader)
+            for sh, vol in parts:
+                reader, segs = self.shards[sh].read_fileset_segments(
+                    block_start, [sids[i] for i in by_shard[sh]], vol)
+                found = [(r, seg) for r, seg in enumerate(segs) if seg]
+                if not found:
+                    continue
+                if reader.max_points is None:
+                    # the format records no count: the volume's
+                    # longest stream asked for says how long a scan
+                    # its decode needs (a longer one is flagged,
+                    # read by the scalar iterator and raises this)
+                    reader.max_points = len(decode_series(
+                        max((seg for _, seg in found), key=len)))
+                streams.extend(seg for _, seg in found)
+                home.extend((sh, r, reader) for r, _ in found)
+            steps = _round_up(max((h[2].max_points for h in home), default=0),
+                              _POINT_BUCKET)
+        return _BlockSegments(asked, streams, home, steps)
+
+    def _decode_block(self, blk: _BlockSegments, out: dict) -> None:
+        """One flushed block of one fetch, after the lock is released:
+        its segments packed once, decoded in one guarded device call
+        (`_decode_streams`; a fetch over `_DECODE_MAX_ROWS` in several),
+        each shard's rows appended to ``out`` as ``(row, ts, value)``
+        arrays.  A stream the device flags goes through the scalar
+        iterator, is counted and marks its row tupled.  Nothing decoded
+        outlives the fetch: what a node keeps between fetches is the
+        encoded block (the reader's page-cache-backed mmap), as
+        upstream's series cache policies do."""
+        streams, home, steps = blk.streams, blk.home, blk.steps
+        with tracing.span(Tracepoint.DB_READ_FILESET, {"n": blk.asked}) as sp:
             if not streams:
                 return
             row_of = np.fromiter((r for _, r, _ in home), np.int64, len(home))
-            steps = _round_up(max(h[2].max_points for h in home),
-                              _POINT_BUCKET)
             n_points = n_scalar = n_rows = n_words = 0
             for a in range(0, len(streams), _DECODE_MAX_ROWS):
                 b = min(a + _DECODE_MAX_ROWS, len(streams))
@@ -820,6 +889,8 @@ class Namespace:
                 for g in (np.nonzero(flagged)[0] + a).tolist():
                     sh, r, reader = home[g]
                     pts = decode_series(streams[g])
+                    # unlocked: two fetches may race here, and the
+                    # update lost costs one more scalar decode
                     reader.max_points = max(reader.max_points, len(pts))
                     out[sh][0].append((
                         np.full(len(pts), r),
@@ -843,61 +914,84 @@ class Namespace:
                             (len(streams) - n_scalar, n_scalar, n_points)):
                 c.inc(v)
 
-    def _read_shards(self, sids: Sequence[bytes], by_shard: dict,
-                     start: int, end: int):
-        """The one batch read: (positions in ``sids``, `Shard.
-        read_columns` of them) per shard of ``by_shard``, the flushed
-        blocks decoded for all of them together first.  A bound
-        deadline is checked between shards, so a cancelled query
-        stops."""
-        fileset = self._fileset_sources(by_shard, sids, start, end)
-        for sh, idxs in by_shard.items():
+    def _plan(self, sids: Sequence[bytes], by_shard: dict, start: int,
+              end: int) -> _ReadPlan:
+        """Phase 1 of the one batch read, under `Database._mu`: the
+        flushed blocks' segments and each shard's slots and buffer
+        snapshots — everything `_read_shards` reads of the namespace.
+        A bound deadline is checked between blocks."""
+        return _ReadPlan(
+            len(sids), start, end, by_shard,
+            self._fileset_segments(by_shard, sids, start, end),
+            {sh: self.shards[sh].buffer_sources(
+                [sids[i] for i in idxs], start, end)
+             for sh, idxs in by_shard.items()})
+
+    def _read_shards(self, plan: _ReadPlan):
+        """Phase 2 of the one batch read, after the release: (positions
+        in the ids asked, `Shard.read_columns` of them) per shard of the
+        plan, the flushed blocks decoded for all of them together
+        first.  A bound deadline is checked between shards, so a
+        cancelled query stops."""
+        fileset = {sh: ([], np.zeros(len(idxs), bool))
+                   for sh, idxs in plan.by_shard.items()}
+        for blk in plan.blocks:
+            self._decode_block(blk, fileset)
+        for sh, idxs in plan.by_shard.items():
             xdeadline.check_current("fetch series")
             yield idxs, self.shards[sh].read_columns(
-                [sids[i] for i in idxs], start, end, fileset[sh])
+                plan.buffered[sh], plan.start, plan.end, fileset[sh])
 
-    def read_many(self, sids: Sequence[bytes], start: int,
-                  end: int) -> list[list[tuple[int, float]]]:
-        """Batched read as one point list per requested id (the RPC /
-        session / verification shape): `_read_shards`' columns cut into
-        lists.  The ownership gate is per SHARD and atomic like
-        write_batch's all-unowned case: any unowned shard in the batch
-        raises typed (the session fans single-shard sub-batches, so
-        this maps to one routing miss, never a partially-silent
-        read)."""
+    def plan_many(self, sids: Sequence[bytes], start: int,
+                  end: int) -> _ReadPlan:
+        """:meth:`read_many`'s phase 1.  The ownership gate is per
+        SHARD and atomic like write_batch's all-unowned case: any
+        unowned shard in the batch raises typed (the session fans
+        single-shard sub-batches, so this maps to one routing miss,
+        never a partially-silent read)."""
         by_shard = self._by_shard(sids)
         for sh in by_shard:
             self.check_owned(sh)
-        out: list = [None] * len(sids)
-        for idxs, (rows, ts, vals, _) in self._read_shards(
-                sids, by_shard, start, end):
+        return self._plan(sids, by_shard, start, end)
+
+    def read_many(self, plan: _ReadPlan) -> list[list[tuple[int, float]]]:
+        """Batched read as one point list per requested id (the RPC /
+        session / verification shape): `_read_shards`' columns cut into
+        lists."""
+        out: list = [None] * plan.n
+        for idxs, (rows, ts, vals, _) in self._read_shards(plan):
             bounds = np.searchsorted(rows, np.arange(len(idxs) + 1)).tolist()
             ts, vals = ts.tolist(), vals.tolist()
             for i, a, b in zip(idxs, bounds[:-1], bounds[1:]):
                 out[i] = list(zip(ts[a:b], vals[a:b]))
         return out
 
-    def read_columns(self, sids: Sequence[bytes], start: int,
-                     end: int) -> SeriesColumns:
-        """Batched read as a query block's columns, one row per id of
-        an OWNED shard in the order asked ("reads answer only owned
-        shards": the index still knows series whose shard the placement
-        moved away — a local query answers from what this node owns,
-        and ``index`` says which ids those are; the cluster-level union
-        comes from the session's replica fan-out)."""
+    def plan_columns(self, sids: Sequence[bytes], start: int,
+                     end: int) -> _ReadPlan:
+        """:meth:`read_columns`' phase 1, over the ids of OWNED shards
+        ("reads answer only owned shards": the index still knows
+        series whose shard the placement moved away — a local query
+        answers from what this node owns; the cluster-level union comes
+        from the session's replica fan-out)."""
         by_shard = self._by_shard(sids)
         if self.owned is not None:
             by_shard = {sh: idxs for sh, idxs in by_shard.items()
                         if sh in self.owned}
+        return self._plan(sids, by_shard, start, end)
+
+    def read_columns(self, plan: _ReadPlan) -> SeriesColumns:
+        """Batched read as a query block's columns, one row per id of
+        the plan in the order asked; ``index`` says which ids those
+        are."""
+        by_shard = plan.by_shard
         index = np.sort(np.fromiter(
             (i for idxs in by_shard.values() for i in idxs), np.int64))
-        row_of = np.empty(len(sids), np.int64)  # request position -> row
+        row_of = np.empty(plan.n, np.int64)  # request position -> row
         row_of[index] = np.arange(len(index))
         counts = np.zeros(len(index), np.int64)
         columnar = len(index)
         parts = []
-        for idxs, (rows, ts, vals, tupled) in self._read_shards(
-                sids, by_shard, start, end):
+        for idxs, (rows, ts, vals, tupled) in self._read_shards(plan):
             columnar -= int(tupled.sum())
             # a shard's rows are runs in the order asked: the rank in
             # the run is the column
@@ -970,7 +1064,8 @@ class Database:
         # ingest batches (HTTP threads), the mediator's tick/snapshot/
         # cleanup thread, bootstrap, and reads: a query's selector or an
         # RPC batch takes it once for all its series (`read_columns`,
-        # `read_batch`), `read` once for its one.
+        # `read_batch`), and only while it takes its read plan (`_fetch`);
+        # `read` once for its one, through its whole read.
         # The reference uses fine-grained per-shard/series locks
         # (shard.go RLock ladders); here every operation is already a
         # whole-batch array program, so one coarse lock adds no
@@ -1247,15 +1342,17 @@ class Database:
         acquisition, one sorted-window snapshot per open block instead
         of per id): the RPC ``read_batch`` / session ``fetch_batch``
         storage entry; an unowned shard raises for the whole batch.
-        The query engine's entry is :meth:`read_columns`.  Same limits
-        accounting units as the single-id path."""
+        The query engine's entry is :meth:`read_columns`; both hold the
+        lock only while `_fetch` plans.  Same limits accounting units
+        as the single-id path."""
         if self._scope is not None:
             self._scope.counter("reads").inc(len(sids))
         self.limits.inc_series(len(sids))
         self.limits.inc_bytes(0)
-        with self._mu, self.tracer.start_span(
-                Tracepoint.DB_READ, {"n": len(sids)}):
-            out = self.namespaces[namespace].read_many(sids, start, end)
+        ns = self.namespaces[namespace]
+        with self._fetch(len(sids),
+                         lambda: ns.plan_many(sids, start, end)) as (_, plan):
+            out = ns.read_many(plan)
         self.limits.inc_bytes(16 * sum(len(p) for p in out))
         return out
 
@@ -1267,23 +1364,52 @@ class Database:
         engine-lock acquisition and one ``db.read`` span a fetch, whose
         tags say how many series were asked (``n``) and how many were
         answered from arrays alone (``columnar``; also counted on
-        /metrics as ``fetch_series`` / ``fetch_series_columnar``).
-        Series of shards this node does not own are left out, not
-        raised.  Same limits accounting units as :meth:`read`."""
+        /metrics as ``fetch_series`` / ``fetch_series_columnar``; the
+        series merged and cut after the release, `_fetch`, as
+        ``fetch_series_unlocked``).  Series of shards this node does
+        not own are left out, not raised.  Same limits accounting units
+        as :meth:`read`."""
         n = len(sids)
         if self._scope is not None:
             self._scope.counter("reads").inc(n)
         self.limits.inc_series(n)
         self.limits.inc_bytes(0)
-        with self._mu, self.tracer.start_span(
-                Tracepoint.DB_READ, {"n": n}) as sp:
-            cols = self.namespaces[namespace].read_columns(sids, start, end)
+        ns = self.namespaces[namespace]
+        with self._fetch(n, lambda: ns.plan_columns(sids, start, end)) as (
+                sp, plan):
+            cols = ns.read_columns(plan)
             sp.set_tag("columnar", cols.columnar)
         if self._scope is not None:
             self._scope.counter("fetch_series").inc(n)
             self._scope.counter("fetch_series_columnar").inc(cols.columnar)
+            self._scope.counter("fetch_series_unlocked").inc(n)
         self.limits.inc_bytes(16 * int(cols.counts.sum()))
         return cols
+
+    @contextlib.contextmanager
+    def _fetch(self, n: int, plan):
+        """One batch fetch's ``db.read`` span, ``(span, plan())``, with
+        `_mu` held only while ``plan()`` takes the fetch's read plan
+        (`Namespace._plan`, under a ``db.read.locked`` span, tags ``n``
+        and ``streams``: the segments read): the decode, merge and cut
+        that the ``with`` body runs on the plan follow the release, so
+        fetches decode side by side and a write does not wait for them.
+        The acquisition's ``db.lock.wait`` stands before ``db.read``,
+        not in it."""
+        self._mu.acquire()
+        held = True
+        try:
+            with self.tracer.start_span(Tracepoint.DB_READ, {"n": n}) as sp:
+                with self.tracer.start_span(
+                        Tracepoint.DB_READ_LOCKED, {"n": n}) as locked:
+                    p = plan()
+                    locked.set_tag("streams", p.streams)
+                held = False
+                self._mu.release()
+                yield sp, p
+        finally:
+            if held:
+                self._mu.release()
 
     def tick(self, now_nanos: int) -> dict:
         import time as _time

@@ -353,6 +353,72 @@ class TestSeamSemantics:
         db.close()
 
 
+def _fetched(db, how, start, end):
+    """A fetch's answer as bits: the columns, or the point lists."""
+    ids = [d.id for d in _docs()] + [b"never-written"]
+    if how == "columns":
+        c = db.read_columns("default", ids, start, end)
+        return (c.ts.tolist(), c.values.view(np.uint64).tolist(),
+                c.counts.tolist(), c.index.tolist(), c.columnar)
+    return [[(t, struct.pack("<d", v)) for t, v in pts]
+            for pts in db.read_batch("default", ids, start, end)]
+
+
+def _late_write(db):
+    """New points in the open block, a cold one over the flushed block."""
+    now = T0 + BLOCK + 11 * MIN
+    _scrape(db, _docs(), T0 + BLOCK + 30 * SEC, now=now, salt=0.125)
+    _scrape(db, _docs(), T0 + 11 * 15 * SEC, now=now, salt=0.375)
+
+
+def _seal_and_flush(db):
+    """The open block seals and flushes; the cold parts become volume 1."""
+    db.tick(T0 + 2 * BLOCK + 11 * MIN)
+
+
+def _expire(db):
+    """Every fileset volume out of retention, removed from disk."""
+    opts = db.namespaces["default"].opts
+    db.cleanup(T0 + 2 * BLOCK + opts.retention_nanos + opts.block_size_nanos)
+
+
+class TestAnswerIsTheLockReleased:
+    @pytest.mark.parametrize("how", ["columns", "batch"])
+    @pytest.mark.parametrize("between", [_late_write, _seal_and_flush,
+                                         _expire],
+                             ids=["write", "tick", "cleanup"])
+    def test_what_lands_after_the_release_leaves_the_answer(
+            self, tmp_path, monkeypatch, between, how):
+        """A write, a seal and flush, or a cleanup that runs on another
+        thread between the plan and its decode, merge and cut leaves the
+        answer, by bits, what it was before: the plan holds bytes and
+        arrays nothing changes in place.  A later fetch sees the
+        change."""
+        import threading
+
+        from m3_tpu.storage.database import Namespace
+
+        db = _db(tmp_path, 4)
+        start, end = cold_overflow(db)
+        want = _fetched(db, how, start, end)
+        ran = []
+        real = Namespace._read_shards
+
+        def after_release(self, plan):
+            t = threading.Thread(target=lambda: ran.append(between(db)))
+            t.start()
+            t.join(60)
+            assert not t.is_alive() and ran  # the lock was free
+            yield from real(self, plan)
+
+        monkeypatch.setattr(Namespace, "_read_shards", after_release)
+        assert _fetched(db, how, start, end) == want
+        monkeypatch.setattr(Namespace, "_read_shards", real)
+        later = _fetched(db, how, start, end)
+        assert (later == want) == (between is _seal_and_flush)
+        db.close()
+
+
 class TestMechanismEngages:
     def _traced(self, tmp_path, num_shards=4):
         tracer = Tracer(enabled=True)
@@ -384,7 +450,15 @@ class TestMechanismEngages:
         assert len(waits) == 2
         assert [s.name for s in spans if s.name.startswith("db.")
                 and s.name != Tracepoint.DB_LOCK_WAIT] == [
-                    Tracepoint.DB_QUERY_IDS, Tracepoint.DB_READ]
+                    Tracepoint.DB_QUERY_IDS, Tracepoint.DB_READ_LOCKED,
+                    Tracepoint.DB_READ]
+        # the lock is held under db.read.locked, which stands in db.read;
+        # the wait for it stands before db.read, not in it
+        (locked,) = [s for s in spans if s.name == Tracepoint.DB_READ_LOCKED]
+        assert locked.parent_id == reads[0].span_id
+        assert (locked.tags["n"], locked.tags["streams"]) == (N, 0)
+        assert reads[0].span_id not in {s.parent_id for s in waits}
+        assert waits[-1].end_ns <= reads[0].start_ns
         db.close()
 
     def test_a_fileset_source_is_columnar(self, tmp_path):
@@ -411,6 +485,25 @@ class TestMechanismEngages:
         DatabaseStorage(db).fetch_raw(b"m", (), T0 + BLOCK, T0 + BLOCK + MIN)
         span = tracer.finished(Tracepoint.DB_READ)[-1]
         assert span.tags["n"] == N and span.tags["columnar"] == N
+        db.close()
+
+    def test_every_fetched_series_is_merged_after_the_release(self,
+                                                              tmp_path):
+        from m3_tpu.instrument import Registry
+
+        reg = Registry()
+        db = _db(tmp_path, 4, instrument=reg.scope("m3tpu"))
+        start, end = cold_overflow(db)
+        st = DatabaseStorage(db)
+        st.fetch_raw(b"m", (), start, end)
+        st.fetch_raw(b"m", (), T0 + BLOCK, T0 + BLOCK + MIN)
+        db.read_batch("default", [d.id for d in _docs()], start, end)
+        snap = reg.snapshot()
+        fetched = {k.rsplit(".", 1)[-1]: v for k, v in snap.items()
+                   if ".db.fetch_series" in k}
+        assert fetched == {"fetch_series": 2 * N,
+                           "fetch_series_columnar": 2 * N,
+                           "fetch_series_unlocked": 2 * N}
         db.close()
 
     def test_metrics_count_asked_and_columnar(self, tmp_path):

@@ -150,8 +150,17 @@ def test_flagged_rows_take_the_scalar_iterator_and_are_counted(sealed):
                      "fileset_decode_points": P * len(ids)}
     children = {s.name for s in tracer.finished()
                 if s.parent_id == span.span_id}
-    assert children == {"db.read.fileset.segments", "device.decode",
-                        "db.read.fileset.to_host"}
+    assert children == {"device.decode", "db.read.fileset.to_host"}
+    # the segments were read under the lock, before the decode
+    (read,) = [s for s in tracer.finished(Tracepoint.DB_READ)
+               if s.span_id == span.parent_id]
+    (locked,) = [s for s in tracer.finished(Tracepoint.DB_READ_LOCKED)
+                 if s.parent_id == read.span_id]
+    assert len([s for s in tracer.finished(
+        Tracepoint.DB_READ_FILESET_SEGMENTS)
+        if s.parent_id == locked.span_id]) == 1
+    assert locked.tags["streams"] == len(ids)
+    assert locked.end_ns <= span.start_ns
 
 
 def test_a_warm_flush_stands_under_db_tick_as_encode_and_write(sealed):
@@ -371,8 +380,9 @@ def test_the_cells_per_layer_entries_are_well_formed():
     bench = json.loads((repo / "BENCHMARK.json").read_text())
     cell = "prom.dashboard_flushed"
     mine = [m for m in bench["per_layer"] if m["name"].endswith(".flushed")
-            # PR 35's two are held by tests/test_node_spans.py
-            and not m["name"].startswith("gil_")]
+            # PR 35's two and PR 36's one are held by
+            # tests/test_node_spans.py
+            and not m["name"].startswith(("gil_", "read_locked_"))]
     assert len(mine) == 22
     (e2e,) = [m for m in bench["end_to_end"] if m["name"] == "queries_per_s"]
     assert cell in e2e["workloads"]
@@ -390,3 +400,49 @@ def test_the_cells_per_layer_entries_are_well_formed():
     assert file["reduced"]["series"]["here"] == (
         file["dataset"]["histograms"] * len(file["dataset"]["le"])
         + file["dataset"]["gauges"])
+
+
+def test_the_lock_is_free_while_a_fetch_decodes(tmp_path, monkeypatch):
+    """A fetch holds `Database._mu` only while it reads its plan: held
+    inside the decode, it lets a second thread's write complete and the
+    lock be taken, and answers what it would have answered alone."""
+    import threading
+
+    db = _db(tmp_path)
+    rows = _series()["counters"]
+    _write(db, rows)
+    db.tick(T0 + BLOCK + 11 * MIN)
+    ids = [sid for sid, _ in rows]
+    want = db.read_columns("default", ids, T0, T0 + BLOCK)
+    inside, go = threading.Event(), threading.Event()
+    real = dbmod._decode_streams
+
+    def held(*args):
+        inside.set()
+        assert go.wait(60)
+        return real(*args)
+
+    monkeypatch.setattr(dbmod, "_decode_streams", held)
+    got = []
+    fetch = threading.Thread(target=lambda: got.append(
+        db.read_columns("default", ids, T0, T0 + BLOCK)))
+    fetch.start()
+    try:
+        assert inside.wait(60)
+        write = threading.Thread(target=_write, args=(
+            db, [(ids[0], [(T0 + BLOCK + 10 * SEC, 1.0)])]),
+            kwargs={"now": T0 + BLOCK + 11 * MIN})
+        write.start()
+        write.join(30)
+        assert not write.is_alive()
+        assert db._mu.acquire(blocking=False)
+        db._mu.release()
+        assert fetch.is_alive()             # still inside the decode
+    finally:
+        go.set()
+        fetch.join(60)
+    (cols,) = got
+    assert np.array_equal(cols.ts, want.ts)
+    assert _bits(cols.values.ravel()) == _bits(want.values.ravel())
+    assert cols.counts.tolist() == want.counts.tolist() == [P] * len(ids)
+    db.close()

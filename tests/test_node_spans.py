@@ -1111,7 +1111,8 @@ def test_new_per_layer_entries_are_well_formed():
     new = [m for m in bench["per_layer"] if m["source"] == "program_span"
            and m["name"] != "maintain_ms_per_pass"
            and not m["name"].endswith((".agg", ".timer", ".flushed"))
-           and not m["name"].startswith("gil_")]   # PR 35's: below
+           # PR 35's and PR 36's: below
+           and not m["name"].startswith(("gil_", "read_locked_"))]
     assert len(new) == 29
     layers = {m["layer"] for m in bench["per_layer"]
               if m not in new}
@@ -1163,4 +1164,31 @@ def test_runtime_entries_are_well_formed():
         spec = json.loads((REPO / "benchmark" / "metrics"
                            / (m["name"] + ".json")).read_text())
         assert spec["reducer"] == "compile_log"
-    assert bench["per_layer"][-15:] == gil + under_setup
+    # together, after every earlier PR's; later PRs' entries follow
+    at = bench["per_layer"].index(gil[0])
+    assert bench["per_layer"][at:at + 15] == gil + under_setup
+    assert all(m["name"].startswith("read_locked_")
+               for m in bench["per_layer"][at + 15:])
+
+
+def test_read_locked_entries_are_well_formed():
+    """PR 36's two: the time a fetch holds Database._mu, one a dashboard
+    cell, read from the db.read.locked span whole, per query."""
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    mine = [m for m in bench["per_layer"]
+            if m["name"].startswith("read_locked_")]
+    assert [(m["name"], m["workloads"]) for m in mine] == [
+        ("read_locked_ms_per_query", ["prom.dashboard_live"]),
+        ("read_locked_ms_per_query.flushed", ["prom.dashboard_flushed"])]
+    assert bench["per_layer"][-2:] == mine
+    for m in mine:
+        assert (m["unit"], m["better"], m["source"], m["layer"], m["moves"]) \
+            == ("ms", "lower", "program_span", "HTTP front door + read path",
+                "queries_per_s")
+        assert m["workloads"][0] in e2e["queries_per_s"]["workloads"]
+        spec = json.loads((REPO / "benchmark" / "metrics"
+                           / (m["name"] + ".json")).read_text())
+        assert (spec["reducer"], spec["params"]) == ("node_span_ms", {
+            "spans": [Tracepoint.DB_READ_LOCKED], "per": "query",
+            "self": False})
