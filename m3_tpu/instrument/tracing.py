@@ -158,7 +158,20 @@ class Tracepoint:
     # tags op: the operator; one_program of n: the operator is one
     # jitted program a call (query/engine.Engine._eval)
     EVAL_AGGREGATION = "query.eval.aggregation"
+    # under query.eval.call, one per dispatched call of a range
+    # function's program (query/engine.py Engine._range_rows); tags rows
+    # (series), pad (empty rows that fill the last block), points,
+    # steps.  Below it `.to_device`: the block's columns to the device,
+    # tag bytes
+    EVAL_BLOCK = "query.eval.block"
+    EVAL_TO_DEVICE = "query.eval.to_device"
+    # under query.eval.aggregation, the host's grouping of the series
+    # by their labels (query/functions.group_series); tags n, groups
+    EVAL_GROUP_KEYS = "query.eval.group_keys"
     FETCH_COMPRESSED = "query.storage.fetchCompressed"
+    # under it, before db.read: the fetched ids' sort and their labels
+    # as the block's series (query/storage_adapter.py); tag n
+    STORAGE_METAS = "query.storage.metas"
     API_QUERY_RANGE = "api.queryRange"           # the whole read handler
     API_QUERY_RENDER = "api.queryRange.render"   # values loop + JSON
     API_WRITE = "api.write"                      # the whole write handler

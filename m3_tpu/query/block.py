@@ -41,7 +41,14 @@ class SeriesMeta:
         return SeriesMeta(tuple((n, v) for n, v in self.tags if n in names))
 
     def drop_name(self) -> "SeriesMeta":
-        return self.drop({b"__name__"})
+        # kept on the meta: a fetched series' meta lives as long as its
+        # index document (query/storage_adapter.py), and every range
+        # function over it asks again
+        m = self.__dict__.get("_no_name")
+        if m is None:
+            m = self.drop({b"__name__"})
+            object.__setattr__(self, "_no_name", m)
+        return m
 
 
 @dataclasses.dataclass
@@ -73,9 +80,50 @@ class Block:
         return Block(self.step_times, values,
                      series if series is not None else self.series)
 
+    def rows(self):
+        """The array a row gather reads: ``values``, or a padded array
+        whose first ``num_series`` rows they are (`PaddedBlock`)."""
+        return self.values
+
     def materialized(self) -> "Block":
         """Force values to host float64 (the query-boundary sync)."""
         return Block(self.step_times, np.asarray(self.values, np.float64),
+                     self.series)
+
+
+class PaddedBlock(Block):
+    """A range function's answer evaluated in fixed-shape row blocks
+    (`Engine._range_rows`): ``padded`` holds whole blocks of rows, the
+    first ``len(series)`` of them the series', the rest empty (NaN).
+    Consumers that gather rows by index (aggregations, histogram
+    quantiles) read ``padded`` through `rows`, so the fleet's exact
+    series count reaches no program's shape; ``values`` is cut from it
+    on first use."""
+
+    def __init__(self, step_times: np.ndarray, padded, series: list):
+        self.step_times, self.padded, self.series = step_times, padded, series
+        self._values = None
+
+    @property
+    def values(self):
+        if self._values is None:
+            self._values = self.padded[:len(self.series)]
+        return self._values
+
+    @property
+    def num_series(self) -> int:
+        return len(self.series)
+
+    @property
+    def num_steps(self) -> int:
+        return self.padded.shape[1]
+
+    def rows(self):
+        return self.padded
+
+    def materialized(self) -> Block:
+        return Block(self.step_times,
+                     np.asarray(self.padded, np.float64)[:len(self.series)],
                      self.series)
 
 

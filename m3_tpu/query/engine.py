@@ -22,7 +22,7 @@ from m3_tpu.instrument.tracing import NOOP_TRACER, Tracepoint
 from m3_tpu.query import functions as fn
 from m3_tpu.query import temporal as tp
 from m3_tpu.x import deadline as xdeadline
-from m3_tpu.query.block import Block, RawBlock, SeriesMeta
+from m3_tpu.query.block import Block, PaddedBlock, RawBlock, SeriesMeta
 from m3_tpu.query.promql import (
     Subquery,
     Aggregation, BinaryOp, Call, Expr, LabelMatcher, NumberLiteral,
@@ -30,6 +30,15 @@ from m3_tpu.query.promql import (
 )
 
 LOOKBACK_NANOS = 5 * 60 * 10**9  # Prometheus default lookback delta
+
+# Rows a range function's program takes in one call.  A selector that
+# fetches more series is evaluated as ceil(S / R) calls of one (R, P, T)
+# program, the last block filled with empty rows (reference
+# functions/temporal/base.go batchProcess: series in batches), so a
+# fleet whose series count moves compiles nothing new until its block
+# count does.  Chosen by a sweep on a v5e at the fleet's shape
+# (PERF.md section 6): 4,096 rows beat 8,192 and 16,384 there.
+_RANGE_BLOCK_ROWS = 4096
 
 _TEMPORAL_SUM = {"sum_over_time", "count_over_time", "avg_over_time",
                  "stddev_over_time", "stdvar_over_time"}
@@ -250,11 +259,15 @@ class Engine:
             else:
                 raw, eval_steps = self._fetch(sel_arg, steps,
                                               sel_arg.range_nanos)
-            if len(raw.series) == 0 and f != "absent_over_time":
-                # No matched series: an empty instant vector
+            if len(raw.series) == 0:
+                # No matched series: an empty instant vector, or for
+                # absent_over_time a single empty-labelled series of 1s
                 # (Prometheus semantics).  Must short-circuit BEFORE
                 # the jitted stencils — a 0-row window gather cannot
                 # even shape its reshape.
+                if f == "absent_over_time":
+                    return Block(steps, np.ones((1, len(steps))),
+                                 [SeriesMeta(())])
                 return Block(steps, np.empty((0, len(steps)),
                                              np.float64), [])
             if f == "last_over_time":
@@ -268,7 +281,6 @@ class Engine:
             from m3_tpu.query import precision
 
             narrow = precision.compute_dtype() == np.float32
-            ts_j = jnp.asarray(raw.ts)
             # The policy dtype rides the value array: jitted stencils
             # follow vals.dtype, so f32 selection re-specializes every
             # kernel without any static plumbing (query/precision.py).
@@ -276,23 +288,29 @@ class Engine:
             # cumulative counters in f64 and narrows internally via its
             # static `narrow` flag — as is regression (f64-pinned).
             narrow_vals = f not in _TEMPORAL_RATE and f not in _TEMPORAL_REG
-            vals_j = jnp.asarray(
-                np.nan_to_num(raw.values),
-                precision.compute_dtype() if narrow_vals else np.float64)
+            vals_dtype = precision.compute_dtype() if narrow_vals else np.float64
             st_j = jnp.asarray(eval_steps)
             rng = sel_arg.range_nanos
             if f in _TEMPORAL_SUM:
-                out = tp.sum_count_family(ts_j, vals_j, st_j, rng, f)
+                def family(ts_j, vals_j):
+                    return tp.sum_count_family(ts_j, vals_j, st_j, rng, f)
             elif f in _TEMPORAL_MINMAXQ:
                 W = tp.window_pad_for(raw.counts, raw.ts, rng)
-                out = tp.minmax_quantile_family(ts_j, vals_j, st_j, rng, f, W, q)
+
+                def family(ts_j, vals_j):
+                    return tp.minmax_quantile_family(ts_j, vals_j, st_j, rng,
+                                                     f, W, q)
             elif f in _TEMPORAL_RATE:
-                out = tp.rate_family(ts_j, vals_j, st_j, rng, f,
-                                     narrow=narrow)
+                def family(ts_j, vals_j):
+                    return tp.rate_family(ts_j, vals_j, st_j, rng, f,
+                                          narrow=narrow)
             elif f in _TEMPORAL_REG:
-                out = tp.regression_family(ts_j, vals_j, st_j, rng, f, extra)
+                def family(ts_j, vals_j):
+                    return tp.regression_family(ts_j, vals_j, st_j, rng, f,
+                                                extra)
             elif f in _TEMPORAL_TRANS:
-                out = tp.transitions_family(ts_j, vals_j, st_j, rng, f)
+                def family(ts_j, vals_j):
+                    return tp.transitions_family(ts_j, vals_j, st_j, rng, f)
             elif f == "holt_winters":
                 sfv = float(self._scalar_arg(call.args[1], steps))
                 tfv = float(self._scalar_arg(call.args[2], steps))
@@ -301,26 +319,27 @@ class Engine:
                     raise ValueError(
                         "holt_winters smoothing factor must be in (0, 1) "
                         "and trend factor in (0, 1]")
-                W = tp.window_pad_for(raw.counts, raw.ts, rng)
-                out = tp.holt_winters(ts_j, vals_j, st_j, rng, max(W, 2),
-                                      sfv, tfv)
-            elif f == "absent_over_time":
+                W = max(tp.window_pad_for(raw.counts, raw.ts, rng), 2)
+
+                def family(ts_j, vals_j):
+                    return tp.holt_winters(ts_j, vals_j, st_j, rng, W, sfv,
+                                           tfv)
+            else:  # absent_over_time, present_over_time: counts
+                def family(ts_j, vals_j):
+                    return tp.sum_count_family(ts_j, vals_j, st_j, rng,
+                                               "count_over_time")
+            out = self._range_rows(raw, vals_dtype, family)
+            if f == "absent_over_time":
                 # 1 for every step where NO matched series has samples
-                # in the window; when nothing matched at all, a single
-                # empty-labelled series of 1s (Prometheus semantics).
-                if len(raw.series) == 0:
-                    return Block(steps, np.ones((1, len(steps))),
-                                 [SeriesMeta(())])
-                cnt = np.asarray(tp.sum_count_family(
-                    ts_j, vals_j, st_j, rng, "count_over_time"))
+                # in the window (padding rows count nothing)
+                cnt = np.asarray(out)
                 any_present = (~np.isnan(cnt) & (cnt > 0)).any(axis=0)
                 vals_out = np.where(any_present, np.nan, 1.0)[None, :]
                 if vals_out.shape[1] != len(steps):  # @-pinned
                     vals_out = np.broadcast_to(
                         vals_out, (1, len(steps))).copy()
                 return Block(steps, vals_out, [SeriesMeta(())])
-            else:  # present_over_time
-                out = tp.sum_count_family(ts_j, vals_j, st_j, rng, "count_over_time")
+            if f == "present_over_time":
                 out = jnp.where(jnp.isnan(out), out, jnp.minimum(out, 1.0))
             metas = [m.drop_name() for m in raw.series]
             # Blocks stay f64 whatever the compute policy — downstream
@@ -332,6 +351,8 @@ class Engine:
             if out.ndim == 2 and out.shape[1] != len(steps):
                 # @-pinned: one computed column broadcast across steps
                 out = jnp.broadcast_to(out, (out.shape[0], len(steps)))
+            if out.shape[0] > len(metas):
+                return PaddedBlock(steps, out, metas)
             return Block(steps, out, metas)
 
         if f == "histogram_quantile":
@@ -419,6 +440,43 @@ class Engine:
             return Block(steps, b.values[order],
                          [b.series[i] for i in order])
         raise ValueError(f"unsupported function {f!r}")
+
+    def _range_rows(self, raw: RawBlock, vals_dtype, family):
+        """``family(ts, vals)`` over the fetched rows, device-resident:
+        one call as they are where they fit one block of
+        ``_RANGE_BLOCK_ROWS``, else one call a block of exactly that
+        many rows, dispatched in turn (the transfer of the next block
+        runs while the device works on the last) and joined on the
+        device, the last block filled with empty rows (no points: every
+        family answers NaN there).  Every family is row-wise, so a row
+        reads the same bits whichever way it was dispatched."""
+        ts, vals = raw.ts, raw.values
+        S, P = ts.shape
+        R = S if S <= _RANGE_BLOCK_ROWS else _RANGE_BLOCK_ROWS
+        outs = []
+        for lo in range(0, S, R):
+            n = min(R, S - lo)
+            ts_b = ts[lo:lo + n]
+            vals_b = np.nan_to_num(vals[lo:lo + n])
+            if n < R:
+                ts_b = np.concatenate(
+                    [ts_b, np.full((R - n, P), np.iinfo(np.int64).max,
+                                   np.int64)])
+                vals_b = np.concatenate([vals_b, np.zeros((R - n, P))])
+            with self.tracer.start_span(Tracepoint.EVAL_BLOCK) as sp:
+                with self.tracer.start_span(Tracepoint.EVAL_TO_DEVICE) as tx:
+                    ts_j = jnp.asarray(ts_b)
+                    vals_j = jnp.asarray(vals_b, vals_dtype)
+                    if tx.recording:
+                        tx.set_tag("bytes", ts_j.nbytes + vals_j.nbytes)
+                out = family(ts_j, vals_j)
+                if sp.recording:
+                    sp.set_tag("rows", n)
+                    sp.set_tag("pad", R - n)
+                    sp.set_tag("points", P)
+                    sp.set_tag("steps", out.shape[-1])
+            outs.append(out)
+        return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
 
     def _label_replace(self, call: Call, steps: np.ndarray) -> Block:
         import re as _re

@@ -27,6 +27,8 @@ from collections import defaultdict
 import jax.numpy as jnp
 import numpy as np
 
+from m3_tpu.instrument import tracing
+from m3_tpu.instrument.tracing import Tracepoint
 from m3_tpu.query.block import Block, SeriesMeta
 
 NAN = float("nan")
@@ -42,23 +44,38 @@ def group_series(series: list[SeriesMeta], by: set[bytes] | None,
     """Group assignment per series row + the output group metas.
 
     by=None, without=None → one global group (Prometheus `sum(x)`).
+    A series' group key is kept on its meta: the metas of fetched
+    series live as long as their index documents, so a dashboard's
+    grouping costs a dict lookup a series after its first query.
     """
-    groups: dict[tuple, int] = {}
-    metas: list[SeriesMeta] = []
-    gids = np.zeros(len(series), np.int32)
-    for i, m in enumerate(series):
-        if by is not None:
-            key_meta = m.keep(by)
-        elif without is not None:
-            key_meta = m.drop(without | {b"__name__"})
+    with tracing.span(Tracepoint.EVAL_GROUP_KEYS) as sp:
+        if by is None and without is None:
+            gids = np.zeros(len(series), np.int32)
+            metas = [SeriesMeta(())] if series else []
         else:
-            key_meta = SeriesMeta(())
-        k = key_meta.tags
-        g = groups.get(k)
-        if g is None:
-            g = groups[k] = len(metas)
-            metas.append(key_meta)
-        gids[i] = g
+            how = ("by", frozenset(by)) if by is not None else (
+                "without", frozenset(without | {b"__name__"}))
+            groups: dict[tuple, int] = {}
+            metas = []
+            ids = []
+            for m in series:
+                keys = m.__dict__.get("_group_keys")
+                if keys is None:
+                    keys = {}
+                    object.__setattr__(m, "_group_keys", keys)
+                key_meta = keys.get(how)
+                if key_meta is None:
+                    key_meta = keys[how] = (m.keep(how[1]) if how[0] == "by"
+                                            else m.drop(how[1]))
+                g = groups.get(key_meta.tags)
+                if g is None:
+                    g = groups[key_meta.tags] = len(metas)
+                    metas.append(key_meta)
+                ids.append(g)
+            gids = np.asarray(ids, np.int32)
+        if sp.recording:
+            sp.set_tag("n", len(series))
+            sp.set_tag("groups", len(metas))
     return gids, metas
 
 
@@ -77,7 +94,13 @@ def _segment_reduce(values: np.ndarray, gids: np.ndarray, num_groups: int,
 def aggregate(block: Block, func: str, by: set[bytes] | None = None,
               without: set[bytes] | None = None, param: float = 0.0) -> Block:
     gids, metas = group_series(block.series, by, without)
-    vals = _segment_reduce(block.values, gids, len(metas), func, param)
+    vals = block.rows()
+    if vals.shape[0] > len(gids):
+        # a padded block's empty rows: a group past the last, which no
+        # program reads, so the block's real row count shapes nothing
+        gids = np.concatenate([gids, np.full(vals.shape[0] - len(gids),
+                                             len(metas), np.int32)])
+    vals = _segment_reduce(vals, gids, len(metas), func, param)
     return Block(block.step_times, vals, metas)
 
 
@@ -140,7 +163,7 @@ def histogram_quantile(block: Block, q: float) -> Block:
     if group_rows:
         # Stays device-resident — iterating rows here would sync each
         # of the G rows separately (Block contract: one boundary sync).
-        vals = histogram_quantile_groups(block.values, group_rows,
+        vals = histogram_quantile_groups(block.rows(), group_rows,
                                          group_ubs, q)
     metas += nan_metas
     if vals is None and not nan_metas:

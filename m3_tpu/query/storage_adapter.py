@@ -75,15 +75,31 @@ class DatabaseStorage:
     def _fetch_raw(self, name, matchers, start_nanos, end_nanos) -> RawBlock:
         q = matchers_to_query(name, matchers)
         docs = self.db.query_ids(self.namespace, q, start_nanos, end_nanos)
-        docs.sort(key=lambda d: d.id)
+        with tracing.span(Tracepoint.STORAGE_METAS) as sp:
+            docs.sort(key=lambda d: d.id)
+            ids = [d.id for d in docs]
+            metas = [series_meta(d) for d in docs]
+            if sp.recording:
+                sp.set_tag("n", len(metas))
         # cancellable before the batch; the database checks between shards
         xdeadline.check_current("fetch series")
-        cols = self.db.read_columns(
-            self.namespace, [d.id for d in docs], start_nanos, end_nanos)
-        # rows are the series of the shards this node owns
-        metas = [SeriesMeta(tuple(sorted(docs[i].tags().items())))
-                 for i in cols.index.tolist()]
+        cols = self.db.read_columns(self.namespace, ids, start_nanos,
+                                    end_nanos)
+        if len(cols.index) != len(metas):
+            # rows are the series of the shards this node owns
+            metas = [metas[i] for i in cols.index.tolist()]
         return RawBlock(cols.ts, cols.values, cols.counts, metas)
+
+
+def series_meta(doc) -> SeriesMeta:
+    """A document's labels as a query block's series meta, built once
+    and kept on the index's document: a dashboard over the whole fleet
+    asks for every series' meta on every refresh."""
+    m = doc.__dict__.get("_series_meta")
+    if m is None:
+        m = SeriesMeta(tuple(sorted(doc.tags().items())))
+        object.__setattr__(doc, "_series_meta", m)
+    return m
 
 
 class SessionStorage:

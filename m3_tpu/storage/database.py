@@ -673,7 +673,8 @@ class Shard:
         if not chunks:
             return (np.empty(0, np.int64), np.empty(0, np.int64),
                     np.empty(0), tupled)
-        rows, ts, vals = (np.concatenate(c) for c in zip(*chunks))
+        rows, ts, vals = (chunks[0] if len(chunks) == 1 else
+                          (np.concatenate(c) for c in zip(*chunks)))
         if len(chunks) > 1 or not windows_only:
             # one open window's runs are sorted and deduped as they
             # come; anything else goes through the one merge: a stable
@@ -993,18 +994,24 @@ class Namespace:
         parts = []
         for idxs, (rows, ts, vals, tupled) in self._read_shards(plan):
             columnar -= int(tupled.sum())
-            # a shard's rows are runs in the order asked: the rank in
-            # the run is the column
             n = np.bincount(rows, minlength=len(idxs))
             out_rows = row_of[np.asarray(idxs, np.int64)]
             counts[out_rows] = n
-            parts.append((np.repeat(out_rows, n),
-                          np.arange(len(rows)) - np.repeat(np.cumsum(n) - n, n),
-                          ts, vals))
+            parts.append((out_rows, n, ts, vals))
         width = max(int(counts.max(initial=0)), 1)
         ts_out = np.full((len(index), width), np.iinfo(np.int64).max, np.int64)
         vals_out = np.full((len(index), width), np.nan)
-        for rows, col, ts, vals in parts:
+        for out_rows, n, ts, vals in parts:
+            if len(n) and (n == n[0]).all():
+                # every run as long (a fleet scraped together): the
+                # shard's columns are its rows as they stand
+                ts_out[out_rows, :n[0]] = ts.reshape(len(n), -1)
+                vals_out[out_rows, :n[0]] = vals.reshape(len(n), -1)
+                continue
+            # a shard's rows are runs in the order asked: the rank in
+            # the run is the column
+            rows = np.repeat(out_rows, n)
+            col = np.arange(len(rows)) - np.repeat(np.cumsum(n) - n, n)
             ts_out[rows, col] = ts
             vals_out[rows, col] = vals
         return SeriesColumns(ts_out, vals_out, counts, index, columnar)

@@ -21,12 +21,14 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import m3_tpu  # noqa: F401 — x64 on
+from m3_tpu.query.engine import _RANGE_BLOCK_ROWS
 
 # The node's widths: T = 240-point scrapes in 2 h blocks; the flush
 # encodes one shard's series per call (chip_smoke: ~26K of 104K over 4
 # shards; 8192 rows is the per-call width ISSUE 22 names) into
 # out_words = max(16, T * 40 // 64 + 8) u64 words (storage/database.py).
 S, T = 8192, 240
+_BLOCK = _RANGE_BLOCK_ROWS   # the engine's rows a range call
 OW = max(16, T * 40 // 64 + 8)
 
 
@@ -292,6 +294,7 @@ class TestQueryPrograms:
         (395, 256, T),        # a panel of prom.dashboard_live
         (390, 720, T),        # a panel of prom.dashboard_flushed
         (6250, 256, T),       # every bucket series of the fleet at once
+        (_BLOCK, 256, T),     # one row block of prom.fleet_quantile
     ])
     def test_rate(self, one_chip, series, points, steps):
         """Window ends by comparison (PR 34): no binary search (a
@@ -321,7 +324,10 @@ class TestQueryPrograms:
         figure PERF.md cites)."""
         from m3_tpu.query import device_fns
 
-        for rows, groups in ((400, 10), (S, 1024)):
+        # ... and prom.fleet_quantile's: 100,000 bucket series in row
+        # blocks (the padding a group of its own) summed by (job, le)
+        for rows, groups in ((400, 10), (S, 1024),
+                             (-(-100_000 // _BLOCK) * _BLOCK, 160)):
             c = _compile(device_fns._segment_reduce_kernel, one_chip,
                          A((rows, T), jnp.float64), A((rows,), jnp.int32),
                          A((rows,), jnp.bool_), A((groups,), jnp.int32),

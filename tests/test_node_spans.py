@@ -384,6 +384,52 @@ class TestSpanSites:
         assert all(root.start_ns <= s.start_ns and s.end_ns <= root.end_ns
                    for s in spans if s.trace_id == root.trace_id)
 
+    @pytest.mark.parametrize("rows", [2, 4, 64])
+    def test_fleet_query_spans_and_one_block_a_call(self, node, session,
+                                                    monkeypatch, rows):
+        """query.eval.block a dispatched range call (ceil(S / R) of them)
+        with .to_device under it, query.storage.metas beside db.read and
+        query.eval.group_keys under the aggregation, with their tags."""
+        from m3_tpu.query import engine
+
+        monkeypatch.setattr(engine, "_RANGE_BLOCK_ROWS", rows)
+        _write(node.port)
+        _query(node.port)       # compiles
+        session.start()
+        _query(node.port)
+        session.stop()
+        spans = node.tracer.finished()
+        by_id = {s.span_id: s for s in spans}
+
+        def parents(name):
+            return [by_id[s.parent_id].name for s in spans if s.name == name]
+
+        blocks = [s for s in spans if s.name == "query.eval.block"]
+        assert len(blocks) == -(-6 // rows)
+        assert set(parents("query.eval.block")) == {"query.eval.call"}
+        assert sum(b.tags["rows"] for b in blocks) == 6
+        assert sum(b.tags["pad"] for b in blocks) == (
+            (-6) % rows if rows < 6 else 0)
+        assert {(b.tags["points"], b.tags["steps"]) for b in blocks} == {
+            (1, 12)}
+        moves = [s for s in spans if s.name == "query.eval.to_device"]
+        assert parents("query.eval.to_device") == ["query.eval.block"] * len(
+            blocks)
+        # a block's (rows, 1 point) i64 timestamps and f64 values
+        assert [m.tags["bytes"] for m in moves] == [16 * min(rows, 6)] * len(
+            blocks)
+        (metas,) = [s for s in spans if s.name == "query.storage.metas"]
+        assert parents("query.storage.metas") == [
+            "query.storage.fetchCompressed"]
+        assert metas.tags["n"] == 6
+        (keys,) = [s for s in spans if s.name == "query.eval.group_keys"]
+        assert parents("query.eval.group_keys") == ["query.eval.aggregation"]
+        assert (keys.tags["n"], keys.tags["groups"]) == (6, 1)
+        # nothing new under db.read, and db.read after the metas
+        assert not {"query.storage.metas", "query.eval.block"} & set(
+            s.name for s in spans if s.parent_id is not None
+            and by_id[s.parent_id].name.startswith("db.read"))
+
     def test_mediator_pass_names_its_stages(self, tmp_path):
         from benchmark import harness
         from m3_tpu.server.assembly import run_node
@@ -1107,10 +1153,12 @@ def test_new_per_layer_entries_are_well_formed():
     # PR 25's, PR 26's, PR 30's and PR 32's entries (PR 27's `.agg` entries are
     # held by tests/test_aggregator_service.py, PR 31's `.timer` entries
     # by tests/test_aggregator_timer_service.py, PR 33's `.flushed`
-    # entries by tests/test_flushed_read.py)
+    # entries by tests/test_flushed_read.py, the `.fleet` entries by
+    # test_fleet_entries_are_well_formed)
     new = [m for m in bench["per_layer"] if m["source"] == "program_span"
            and m["name"] != "maintain_ms_per_pass"
-           and not m["name"].endswith((".agg", ".timer", ".flushed"))
+           and not m["name"].endswith((".agg", ".timer", ".flushed",
+                                       ".fleet"))
            # PR 35's and PR 36's: below
            and not m["name"].startswith(("gil_", "read_locked_"))]
     assert len(new) == 29
@@ -1123,7 +1171,9 @@ def test_new_per_layer_entries_are_well_formed():
                                           "group_one_program_pct"))
         assert m["layer"] in layers
         assert m["better"] == ("higher" if hit_share else "lower")
-        (cell,) = m["workloads"]
+        # the fleet cell reads the dashboard cell's readers too
+        cell, *more = m["workloads"]
+        assert more in ([], ["prom.fleet_quantile"])
         assert cell in e2e[m["moves"]]["workloads"]
         spec = json.loads((REPO / "benchmark" / "metrics"
                            / (m["name"] + ".json")).read_text())
@@ -1168,7 +1218,7 @@ def test_runtime_entries_are_well_formed():
     at = bench["per_layer"].index(gil[0])
     assert bench["per_layer"][at:at + 15] == gil + under_setup
     assert all(m["name"].startswith("read_locked_")
-               for m in bench["per_layer"][at + 15:])
+               for m in bench["per_layer"][at + 15:at + 17])
 
 
 def test_read_locked_entries_are_well_formed():
@@ -1179,9 +1229,11 @@ def test_read_locked_entries_are_well_formed():
     mine = [m for m in bench["per_layer"]
             if m["name"].startswith("read_locked_")]
     assert [(m["name"], m["workloads"]) for m in mine] == [
-        ("read_locked_ms_per_query", ["prom.dashboard_live"]),
+        ("read_locked_ms_per_query",
+         ["prom.dashboard_live", "prom.fleet_quantile"]),
         ("read_locked_ms_per_query.flushed", ["prom.dashboard_flushed"])]
-    assert bench["per_layer"][-2:] == mine
+    # the last of the per-layer list but the fleet cell's three
+    assert bench["per_layer"][-5:-3] == mine
     for m in mine:
         assert (m["unit"], m["better"], m["source"], m["layer"], m["moves"]) \
             == ("ms", "lower", "program_span", "HTTP front door + read path",
@@ -1192,3 +1244,101 @@ def test_read_locked_entries_are_well_formed():
         assert (spec["reducer"], spec["params"]) == ("node_span_ms", {
             "spans": [Tracepoint.DB_READ_LOCKED], "per": "query",
             "self": False})
+
+
+# the fleet cell's own metrics, each with its reader and layer
+_FLEET_NEW = {
+    "row_blocks_per_query.fleet": ("node_span_count", "query compute"),
+    "labels_ms_per_query.fleet": ("node_span_ms",
+                                  "HTTP front door + read path"),
+    "rate_family_roofline.fleet": ("trace_roofline_counts", "kernels"),
+}
+# the dashboard cell's readers that the fleet cell reports too (none of
+# them reads a panel's shape)
+_FLEET_SHARED = (
+    "query_req_p50_ms", "query_device_ms_per_query",
+    "series_read_ms_per_query", "read_locked_ms_per_query",
+    "lock_wait_ms_per_query", "index_query_ms_per_query",
+    "eval_ms_per_query", "render_ms_per_query", "query_unnamed_pct",
+    "device_idle_pct.query", "idle_unnamed_pct.query",
+    "window_compiles.query", "gc_pause_pct.query", "gil_contended_pct.query",
+    "gil_wait_ms.query")
+
+
+def test_fleet_entries_are_well_formed():
+    """The fleet cell's three metrics, last in the list, move
+    queries_per_s in that cell alone; it is appended to the workloads of
+    the dashboard cell's shape-free readers and of the set-up metrics,
+    and not to the panel-shaped rate_family_roofline."""
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    assert [m["name"] for m in bench["per_layer"][-3:]] == list(_FLEET_NEW)
+    assert len(bench["per_layer"]) <= 128
+    assert "prom.fleet_quantile" in e2e["queries_per_s"]["workloads"]
+    metrics = REPO / "benchmark" / "metrics"
+    for name, (reducer, layer) in _FLEET_NEW.items():
+        m = by_name[name]
+        assert (m["moves"], m["workloads"], m["layer"]) == (
+            "queries_per_s", ["prom.fleet_quantile"], layer)
+        spec = json.loads((metrics / (name + ".json")).read_text())
+        assert spec["reducer"] == reducer
+    reading = {m["name"] for m in bench["per_layer"]
+               if "prom.fleet_quantile" in m.get("workloads", ())}
+    assert reading == set(_FLEET_NEW) | set(_FLEET_SHARED) | {
+        "setup_compile_s", "setup_programs", "setup_cache_hit_pct"}
+    for name in _FLEET_SHARED:
+        assert by_name[name]["workloads"] == [
+            "prom.dashboard_live", "prom.fleet_quantile"]
+    # not the panel-shaped roofline: its bytes are calls x one panel's shape
+    assert "prom.fleet_quantile" not in by_name["rate_family_roofline"][
+        "workloads"]
+
+
+def _block_cell(blocks, slice_=(0.0, 30.0), queries=2):
+    """A traced cell whose ring holds `queries` query roots, each with
+    the given query.eval.block spans' tags under its eval call."""
+    spans = []
+    sid = 0
+    for q in range(queries):
+        t = q * 10.0
+        root, call = sid + 1, sid + 2
+        spans.append((root, None, "api.queryRange", t + 1.0, t + 9.0, {}))
+        spans.append((call, root, "query.eval.call", t + 2.0, t + 8.0, {}))
+        sid += 2
+        for i, tags in enumerate(blocks):
+            sid += 1
+            spans.append((sid, call, "query.eval.block", t + 2.0 + i * 0.1,
+                          t + 2.05 + i * 0.1, dict(tags)))
+    return _cell(_FakeTracer(node_spans.build(spans)), slice_=slice_)
+
+
+class TestFleetReducers:
+    def test_blocks_per_query(self):
+        from benchmark.reducers import node_span_count
+
+        tags = [{"rows": 64, "pad": 0, "points": 40, "steps": 23}] * 15 + [
+            {"rows": 40, "pad": 24, "points": 40, "steps": 23}]
+        cell = _block_cell(tags, queries=2)
+        assert node_span_count.read(cell, {
+            "spans": ["query.eval.block"], "per": "query"}) == 16
+        # a program without the spans or tags reads nothing
+        bare = _block_cell([], queries=2)
+        assert node_span_count.read(bare, {
+            "spans": ["query.eval.block"], "per": "query"}) is None
+
+    def test_roofline_bytes_count_real_rows_whatever_the_blocks(self):
+        """One call of 1,000 rows or 16 calls of 64 padded rows: the same
+        bytes over all calls."""
+        from benchmark import roofline_fleet
+
+        one = _block_cell([{"rows": 1000, "pad": 0, "points": 40,
+                            "steps": 23}], queries=1)
+        many = _block_cell([{"rows": 64, "pad": 0, "points": 40,
+                             "steps": 23}] * 15 + [
+            {"rows": 40, "pad": 24, "points": 40, "steps": 23}], queries=1)
+        want = 1000 * (16.0 * 40 + 8.0 * 23)
+        assert roofline_fleet.rate_family_bytes(one, 1) == want
+        assert abs(roofline_fleet.rate_family_bytes(many, 16) - want) < 1e-6
+        assert roofline_fleet.rate_family_bytes(_block_cell([], queries=1),
+                                                1) == 0.0
