@@ -917,7 +917,11 @@ class TimerArena(_TimerLanesMixin):
         self.sample_capacity = new_cap
         self.grows += 1
 
-    def consume(self, window: int):
+    # the moments are scatter accumulators here, read at no cost: a
+    # drain never skips them, and `moments` is ignored
+    moments_skipped = 0
+
+    def consume(self, window: int, moments: bool = True):
         return _guarded_consume(lambda: timer_consume(
             self.state, jnp.int32(window), self.capacity, self.quantiles,
             self.packed32,

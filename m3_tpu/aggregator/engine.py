@@ -54,6 +54,13 @@ _SUPPORTED_TAIL = frozenset({
     TransformationType.RESET,
 })
 
+# The timer lanes computed from the window's moments (packed
+# timer_consume's segment sums): a drain runs them only if some timer
+# slot's mask has one of these bits.
+_TIMER_MOMENT_BITS = np.uint64(sum(1 << int(t) for t in (
+    AggregationType.MEAN, AggregationType.SUM, AggregationType.SUM_SQ,
+    AggregationType.STDEV)))
+
 
 @dataclasses.dataclass(frozen=True)
 class ForwardSpec:
@@ -817,7 +824,14 @@ class MetricList:
         arena = self._arena(mt)
         name = f"{Tracepoint.AGG_DRAIN}.{mt.name.lower()}"
         with tracing.span(name) as span:
-            lanes, counts = arena.consume(w)
+            if mt is MetricType.TIMER:
+                # the union over every allocated slot: a superset of the
+                # window's, so no slot that asks for a moment is missed
+                moments = bool(np.bitwise_or.reduce(
+                    self.maps[mt].agg_mask) & _TIMER_MOMENT_BITS)
+                lanes, counts = arena.consume(w, moments=moments)
+            else:
+                lanes, counts = arena.consume(w)
             # a program's outputs are ready together: bringing the small
             # one over waits for the consume program, so `.to_host`
             # times the copy of the finished lanes alone
@@ -830,6 +844,7 @@ class MetricList:
                 span.set_tag("bytes", lanes.nbytes + counts.nbytes)
                 if mt is MetricType.TIMER:
                     span.set_tag("samples", arena.samples_buffered(w))
+                    span.set_tag("moments", int(moments))
             for flushed in self._emit(mt, arena, lanes, counts, ts):
                 results.append(flushed)
                 if flush_handler is not None:
@@ -1277,6 +1292,9 @@ class Aggregator:
             # past it (each a new shape: a compile on the ingest path)
             "timer_samples_buffered": 0,
             "timer_buffer_grows": 0,
+            # timer drains of a non-empty window whose slots asked for
+            # no moment, so that ran without the segment sums
+            "timer_moments_skipped": 0,
         }
         for sh in self.shards:
             for ml in sh.lists.values():
@@ -1284,6 +1302,7 @@ class Aggregator:
                     out["timer_samples_buffered"],
                     ml.timers.samples_buffered())
                 out["timer_buffer_grows"] += ml.timers.grows
+                out["timer_moments_skipped"] += ml.timers.moments_skipped
                 out["drops"] += ml.drops
                 out["forward_errors"] += ml.forward_errors
                 out["timed_rejects_too_early"] += (

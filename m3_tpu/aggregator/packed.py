@@ -858,13 +858,20 @@ def timer_ingest(
 def timer_consume(
     state: PackedTimerState,
     window: jnp.ndarray,
+    moments: jnp.ndarray,
     capacity: int,
     quantiles: tuple,
 ):
     """Drain one window: sort the packed words (slot asc, value asc in
-    f32 order), then counts from boundaries, sum/sum_sq from a sorted
-    segment sum of the decoded values (f32 value precision — the
-    packed32 1e-6 envelope), min/max/quantiles from rank positions."""
+    f32 order), then counts from boundaries, min/max/quantiles from rank
+    positions, and sum/sum_sq from a sorted segment sum of the decoded
+    values (f32 value precision — the packed32 1e-6 envelope).
+
+    The segment sums run only where the window buffered something and
+    ``moments`` (a traced bool[]: the drain's caller says whether any
+    slot asks for MEAN, SUM, SUM_SQ or STDEV) is set; otherwise those
+    four lanes are zeros.  Traced, not static: a map whose masks change
+    between drains runs the same program."""
     num_w, scap = state.sample.shape
     words = jax.lax.dynamic_index_in_dim(state.sample, window,
                                          keepdims=False)
@@ -898,8 +905,9 @@ def timer_consume(
     # - a window that buffered nothing skips them: the scatters cost
     #   every word of the buffer, sentinels included (39 ms at 2^18),
     #   and the coordinator's downsampler drains an empty timer buffer
-    #   in every pass.
-    def moments(keys):
+    #   in every pass.  So does a drain whose slots ask for no moment
+    #   (a P50/P95/P99 deployment): ~640 of 777 ms at 2^22 words.
+    def segment_moments(keys):
         slot = (keys >> jnp.uint64(32)).astype(jnp.int32)
         val = decode_orderable_f32(keys & jnp.uint64(0xFFFFFFFF))
         v = jnp.where(slot < capacity, val, 0.0)
@@ -911,9 +919,9 @@ def timer_consume(
 
     zeros = jnp.zeros(capacity, jnp.float64)
     s, ssq = jax.lax.cond(
-        jax.lax.dynamic_index_in_dim(state.sample_n, window,
-                                     keepdims=False) > 0,
-        moments, lambda keys: (zeros, zeros), keys)
+        (jax.lax.dynamic_index_in_dim(state.sample_n, window,
+                                      keepdims=False) > 0) & moments,
+        segment_moments, lambda keys: (zeros, zeros), keys)
     cntf = seg_n.astype(jnp.float64)
     mean = jnp.where(empty, 0.0, s / jnp.where(empty, 1.0, cntf))
 
@@ -1123,6 +1131,8 @@ class PackedTimerArena(_TimerLanesMixin):
         self.state = timer_init(num_windows, capacity, sample_capacity)
         self._sample_n_host = np.zeros(num_windows, np.int64)
         self.grows = 0  # times _grow padded the buffer (a new shape)
+        # drains of a non-empty window that ran without the moments
+        self.moments_skipped = 0
 
     def ingest(self, windows, slots, values, times):
         windows_np = np.asarray(windows)
@@ -1162,9 +1172,15 @@ class PackedTimerArena(_TimerLanesMixin):
         self.sample_capacity = new_cap
         self.grows += 1
 
-    def consume(self, window: int):
-        return _guarded_consume(lambda: timer_consume(
-            self.state, jnp.int32(window), self.capacity, self.quantiles))
+    def consume(self, window: int, moments: bool = True):
+        """``moments=False``: MEAN, SUM, SUM_SQ and STDEV come back as
+        zeros (no slot asks for them), the other lanes as ever."""
+        out = _guarded_consume(lambda: timer_consume(
+            self.state, jnp.int32(window), np.bool_(moments),
+            self.capacity, self.quantiles))
+        if not moments and self._sample_n_host[window] > 0:
+            self.moments_skipped += 1
+        return out
 
     def reset_window(self, window: int):
         self.state = _guarded_state_op(lambda: timer_reset_window(self.state, jnp.int32(window),

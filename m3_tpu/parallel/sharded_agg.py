@@ -176,7 +176,7 @@ def sharded_ingest_consume(
             g_sum, g_n = g_lanes[:, 2], g_lanes[:, 1]
             k_min, k_max = g_cnt[:, 2], g_cnt[:, 3]
             t_lanes, t_cnt = _raw(_packed.timer_consume)(
-                timers, window, capacity, quantiles)
+                timers, window, True, capacity, quantiles)
             counters = _raw(_packed.counter_reset_window)(
                 counters, window, num_windows, capacity)
             gauges = _raw(_packed.gauge_reset_window)(

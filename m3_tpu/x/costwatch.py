@@ -390,7 +390,7 @@ def _build_arena_packed(kind: str, op: str):
                 capacity=C)
         else:
             lowered = packed.timer_consume.lower(
-                st, a["window"], capacity=C,
+                st, a["window"], True, capacity=C,
                 quantiles=CANONICAL["QUANTILES"])
     dp = CANONICAL["N"] if op == "ingest" else (
         SCAP if kind == "timer" else C)

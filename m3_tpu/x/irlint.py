@@ -546,7 +546,7 @@ def _drain_crossings() -> List[Crossing]:
          lambda st, w: packed.gauge_consume(st, w, capacity=C)),
         ("timer", lambda: packed.timer_init(W, C, 1 << 24),
          lambda st, w: packed.timer_consume(
-             st, w, capacity=C, quantiles=tuple(PIPE["quantiles"]))),
+             st, w, True, capacity=C, quantiles=tuple(PIPE["quantiles"]))),
     )
     out: List[Crossing] = []
     for kind, init, consume in emitters:

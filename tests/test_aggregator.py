@@ -457,6 +457,160 @@ class TestEngine:
         np.testing.assert_allclose(got[AggregationType.MEAN], vals.mean())
 
 
+def _bits(x) -> int:
+    return int(np.float64(x).view(np.int64))
+
+
+class TestTimerMoments:
+    """The packed timer drain runs its moments' segment sums only when
+    some timer slot's mask asks for MEAN, SUM, SUM_SQ or STDEV (the
+    union of the map's masks, read at each drain)."""
+
+    POLICY = StoragePolicy.parse("10s:2d")
+    QS = AggregationID.compress([AggregationType.P50, AggregationType.P95,
+                                 AggregationType.P99])
+    MOMENTS = (AggregationType.MEAN, AggregationType.SUM,
+               AggregationType.SUM_SQ, AggregationType.STDEV)
+
+    def _agg(self, capacity=64):
+        return Aggregator(num_shards=1, opts=AggregatorOptions(
+            capacity=capacity, num_windows=2, timer_sample_capacity=1 << 10,
+            storage_policies=(self.POLICY,)))
+
+    def _timers(self, agg, ids, vals, t, agg_id):
+        agg.add_untimed_batch(MetricType.TIMER, ids, np.asarray(vals),
+                              np.full(len(ids), t, np.int64), agg_id=agg_id)
+
+    def _drain(self, agg, target):
+        """consume under an installed tracer -> (rows by (id, type),
+        the `moments` tag of each timer drain span)"""
+        from m3_tpu.instrument import tracing
+        from m3_tpu.instrument.tracing import Tracer
+
+        tr = Tracer(enabled=True)
+        tracing.install(tr)
+        try:
+            flushed = agg.consume(target)
+        finally:
+            tracing.uninstall(tr)
+        ml = agg.shards[0].lists[self.POLICY]
+        rows = {}
+        for f in flushed:
+            if f.metric_type is MetricType.TIMER:
+                for s, t, v in zip(f.slots, f.types, f.values):
+                    mid = ml.maps[MetricType.TIMER].id_of(int(s))
+                    rows[(mid, AggregationType(int(t)))] = v
+        tags = [s.tags["moments"]
+                for s in tr.finished("aggregator.drain.timer")]
+        return rows, tags
+
+    def _expect(self, agg, window):
+        """Every (id, type) the masks ask for, from the same window
+        drained with the moments on (the program as it ran before)."""
+        ml = agg.shards[0].lists[self.POLICY]
+        lanes, counts = map(np.asarray, ml.timers.consume(window,
+                                                          moments=True))
+        m = ml.maps[MetricType.TIMER]
+        out = {}
+        for slot in np.nonzero(counts)[0]:
+            mask = int(m.agg_mask[slot])
+            for t in AggregationType:
+                if t.is_valid() and mask >> int(t) & 1:
+                    out[(m.id_of(int(slot)), t)] = lanes[
+                        slot, ml.timers.lane_for_type(t)]
+        return out
+
+    def test_quantile_only_map_skips_the_moments(self):
+        agg = self._agg()
+        rng = np.random.default_rng(5)
+        ids = [b"t%d" % (i % 9) for i in range(300)]
+        self._timers(agg, ids, rng.gamma(2.0, 40.0, 300), R + 5, self.QS)
+        want = self._expect(agg, 1)
+        rows, tags = self._drain(agg, 2 * R + 1)
+        assert agg.counters()["timer_moments_skipped"] == 1
+        assert tags == [0]
+        assert {t for _, t in rows} == {AggregationType.P50,
+                                        AggregationType.P95,
+                                        AggregationType.P99}
+        assert rows.keys() == want.keys()
+        for k, v in rows.items():
+            assert _bits(v) == _bits(want[k]), k
+
+    @pytest.mark.parametrize("asked", MOMENTS, ids=lambda t: t.name)
+    def test_a_slot_that_asks_for_a_moment_runs_them(self, asked):
+        agg = self._agg()
+        rng = np.random.default_rng(int(asked))
+        ids = [b"t%d" % (i % 9) for i in range(300)]
+        self._timers(agg, ids, rng.gamma(2.0, 40.0, 300), R + 5, self.QS)
+        self._timers(agg, [b"x"] * 7, rng.gamma(2.0, 40.0, 7), R + 6,
+                     AggregationID.compress([asked]))
+        want = self._expect(agg, 1)
+        rows, tags = self._drain(agg, 2 * R + 1)
+        assert agg.counters()["timer_moments_skipped"] == 0
+        assert tags == [1]
+        assert rows[(b"x", asked)] != 0.0
+        assert rows.keys() == want.keys()
+        for k, v in rows.items():
+            assert _bits(v) == _bits(want[k]), k
+
+    def test_the_union_follows_an_expired_slot_in_one_program(self):
+        """The SUM slot goes idle and is released: the next drain runs
+        without the moments, and both drains are one compiled program (a
+        capacity no other test uses, so its one compile is counted)."""
+        from m3_tpu.x import tracewatch
+
+        agg = self._agg(capacity=37)
+        ml = agg.shards[0].lists[self.POLICY]
+        summed = [1.5, 2.25, 3.0, 4.5, 8.0]
+        self._timers(agg, [b"sum"] * 5, summed, R + 1,
+                     AggregationID.compress([AggregationType.SUM]))
+        self._timers(agg, [b"q"] * 5, [5.0, 1.0, 4.0, 2.0, 3.0], R + 2,
+                     self.QS)
+        was_installed = tracewatch.installed()
+        tracewatch.install(raise_on_violation=False)
+        try:
+            before = dict(tracewatch.compiles())
+            first, tags1 = self._drain(agg, 2 * R + 1)
+            self._timers(agg, [b"q"] * 5, [9.0, 7.0, 6.0, 8.0, 10.0],
+                         2 * R + 1, self.QS)
+            assert ml.expire(now_nanos=2 * R + 5, ttl_nanos=R) == 1
+            second, tags2 = self._drain(agg, 3 * R + 1)
+            new = tracewatch.compiles().get("timer_consume", 0) \
+                - before.get("timer_consume", 0)
+        finally:
+            if not was_installed:
+                tracewatch.uninstall()
+        assert (tags1, tags2) == ([1], [0])
+        assert new == 1
+        assert agg.counters()["timer_moments_skipped"] == 1
+        assert first[(b"sum", AggregationType.SUM)] == sum(summed)
+        assert first[(b"q", AggregationType.P50)] == 3.0
+        assert first[(b"q", AggregationType.P99)] == 5.0
+        assert second == {(b"q", AggregationType.P50): 8.0,
+                          (b"q", AggregationType.P95): 10.0,
+                          (b"q", AggregationType.P99): 10.0}
+
+    @pytest.mark.parametrize("asked", [AggregationType.P50,
+                                       AggregationType.SUM],
+                             ids=lambda t: t.name)
+    def test_an_empty_window_skips_whatever_the_masks_say(self, asked):
+        agg = self._agg()
+        ml = agg.shards[0].lists[self.POLICY]
+        # the drained window holds a counter; the timer slot exists, but
+        # its sample lands in the next window
+        agg.add_untimed_batch(MetricType.COUNTER, [b"c"],
+                              np.array([1], np.int64),
+                              np.array([R + 1], np.int64))
+        self._timers(agg, [b"x"], [4.0], 2 * R + 1,
+                     AggregationID.compress([asked]))
+        lanes, counts = map(np.asarray, ml.timers.consume(1, moments=True))
+        assert not counts.any() and not np.nan_to_num(lanes, nan=0.0).any()
+        rows, tags = self._drain(agg, 2 * R + 1)
+        assert rows == {}
+        assert tags == [int(asked is AggregationType.SUM)]
+        assert agg.counters()["timer_moments_skipped"] == 0
+
+
 class TestNativeIdMapParity:
     """The native batch resolver (native/idmap.cc) must be
     observationally identical to the Python dict path: same find-or-

@@ -204,6 +204,8 @@ class TestServedTimerQuantiles:
             timeout=10).read().decode()
         assert "m3tpu_aggregator_timer_buffer_grows 1" in text
         assert "m3tpu_aggregator_timer_samples_buffered 0" in text
+        # P50/P95/P99 alone: every minute drained without the moments
+        assert f"m3tpu_aggregator_timer_moments_skipped {MINUTES}" in text
         assert f"m3tpu_aggregator_flush_values {len(served['want'])}" in text
 
     def test_inside_cm_streams_eps_band(self, served):

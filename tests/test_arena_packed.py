@@ -39,6 +39,22 @@ def _batches(rng, n_batches, n, W, C, nonfinite=False):
         yield windows, slots, cvals, gvals, times
 
 
+@functools.partial(jax.jit, static_argnames=("capacity",))
+def _moments_before(words, capacity):
+    """A window's (sum, sum_sq) as packed.timer_consume computed them
+    before it took the `moments` operand: two sorted segment sums over
+    the decoded sorted words, sentinels in the spare row."""
+    keys = jax.lax.sort(words)
+    slot = (keys >> jnp.uint64(32)).astype(jnp.int32)
+    val = packed.decode_orderable_f32(keys & jnp.uint64(0xFFFFFFFF))
+    v = jnp.where(slot < capacity, val, 0.0)
+    seg = jnp.minimum(slot, capacity)
+    return tuple(
+        jax.ops.segment_sum(x, seg, num_segments=capacity + 1,
+                            indices_are_sorted=True)[:capacity]
+        for x in (v, v * v))
+
+
 def _assert_counter_parity(f64_arena, packed_arena, W):
     for w in range(W):
         cl, cc = map(np.asarray, f64_arena.consume(w))
@@ -397,6 +413,44 @@ class TestPackedTimer:
         nz = np.abs(tl[:, 8:]) > 0
         rel = np.abs(tl[:, 8:] - pl[:, 8:]) / np.where(nz, np.abs(tl[:, 8:]), 1)
         assert float(rel[nz].max()) < 1e-6
+
+    @pytest.mark.parametrize("filled", [True, False],
+                             ids=["filled", "empty"])
+    def test_moments_operand(self, filled):
+        """moments=False zeroes MEAN, SUM, SUM_SQ and STDEV and leaves
+        every other lane equal by bits; moments=True sums them as the
+        program did before it had the operand; a window that buffered
+        nothing skips them whatever the operand says, and only a
+        non-empty drain without them counts as skipped."""
+        W, C = 2, 41
+        rng = np.random.default_rng(31)
+        pta = packed.PackedTimerArena(W, C, 2048)
+        n = 900
+        slots = rng.integers(0, C, n).astype(np.int32)
+        vals = rng.gamma(2.0, 50.0, n)
+        pta.ingest(np.full(n, 0 if filled else 1, np.int32), slots, vals,
+                   np.full(n, T0, np.int64))
+        on_l, on_c = map(np.asarray, pta.consume(0, moments=True))
+        assert pta.moments_skipped == 0
+        off_l, off_c = map(np.asarray, pta.consume(0, moments=False))
+        assert pta.moments_skipped == int(filled)
+        np.testing.assert_array_equal(on_c, off_c)
+        kept = [0, 1, 2, 4] + list(range(8, on_l.shape[1]))
+        np.testing.assert_array_equal(on_l[:, kept].view(np.int64),
+                                      off_l[:, kept].view(np.int64))
+        assert not off_l[:, [3, 5, 6, 7]].any()
+
+        s, ssq = map(np.asarray, _moments_before(
+            pta.state.sample[0], capacity=C))
+        cnt = on_c.astype(np.float64)
+        assert (on_c > 0).any() == filled
+        with np.errstate(invalid="ignore"):
+            mean = np.where(cnt == 0, 0.0, s / cnt)
+        # the compiler's own arithmetic (it may fuse a multiply-add)
+        stdev = np.asarray(jax.jit(arena._stdev)(cnt, ssq, s))
+        for lane, want in ((3, mean), (5, s), (6, ssq), (7, stdev)):
+            np.testing.assert_array_equal(on_l[:, lane].view(np.int64),
+                                          want.view(np.int64))
 
     def test_timer_grow_and_clear(self):
         pta = packed.PackedTimerArena(1, 8, 4)

@@ -263,6 +263,11 @@ class TestTimerDrain:
     W, S, C = 2, 1 << 14, 1 << 12
 
     def test_searches_gather_from_fast_memory(self, one_chip):
+        """Also with the `moments` predicate (a drain whose slots ask for
+        no moment skips them too): the empty drain keeps the layout that
+        made it fast, so it is no slower, and one program serves both
+        values of the predicate, a parameter of the entry, not a
+        constant folded into it."""
         import re
 
         from m3_tpu.aggregator import packed
@@ -271,8 +276,8 @@ class TestTimerDrain:
             lambda: packed.timer_init(self.W, self.C, self.S))
         state = jax.tree_util.tree_map(lambda a: A(a.shape, a.dtype), shapes)
         compiled = _compile(packed.timer_consume, one_chip, state,
-                            A((), jnp.int32), capacity=self.C,
-                            quantiles=(0.5, 0.95, 0.99))
+                            A((), jnp.int32), A((), jnp.bool_),
+                            capacity=self.C, quantiles=(0.5, 0.95, 0.99))
         text = compiled.as_text()
         entry = text[text.index("ENTRY"):]
         loops = [line.split(" while(")[0] for line in entry.splitlines()
@@ -281,7 +286,9 @@ class TestTimerDrain:
         for carried in loops:
             (column,) = re.findall(r"s32\[%d\][^,]*" % self.S, carried)
             assert "S(1)" in column, column
-        assert " conditional(" in entry   # an empty window skips the moments
+        # an empty window, or one whose slots ask for no moment, skips them
+        assert entry.count(" conditional(") == 1
+        assert re.search(r"pred\[\][^\n]* parameter\(", entry)
 
 
 class TestQueryPrograms:
