@@ -2,7 +2,9 @@
 (self time, or the whole span with `params.self` false; a whole span
 nested in another that is counted is not counted twice), over the work
 the slice's own roots counted (`params.per`: thousands of samples of the
-`api.write` roots, `api.queryRange` roots, or `mediator.runOnce` roots).
+`api.write` roots, `api.queryRange` roots, or `mediator.runOnce` roots),
+the spans taken only below the roots that count that work: a wait that
+opens under writes, queries and passes alike reads each path's own.
 `params.clock`: `wall` (the default; what a wait costs) or `cpu` (the
 span's thread's CPU clock: what Python work costs, free of the wait for
 the GIL that the wall time of every span of a busy node includes).
@@ -15,8 +17,9 @@ def read(cell, params):
     spans = node_spans.load(cell)
     if spans is None:
         return None
-    work = spans.work(params["per"])
-    found = [n for n in spans.under_roots()
+    per = params["per"]
+    work = spans.work(per)
+    found = [n for n in spans.under_roots({node_spans.ROOT_OF[per]})
              if node_spans.matches(n.name, params["spans"])]
     if not work or not found:
         return None

@@ -9,6 +9,12 @@ spans' own roots (a root = a span with no parent: one ``api.write``,
 that lie wholly inside the slice: a request in flight when the slice
 opened carries no spans, one in flight when it closed has no end yet.
 
+A per-unit reader counts only below the roots that count its unit
+(``ROOT_OF``): a span that opens under every kind of root, as
+``db.lock.wait`` does, is a write's wait per ksample, a query's per
+query and a pass's per pass, and never the one path's spans over the
+other's work.
+
 ``load`` gives None — and every reducer built on it then reports
 nothing — where the program has no such tracer (the parent of the PR
 that added it), the run was not traced, or the ring pushed out spans
@@ -50,6 +56,12 @@ class Node:
         return max(0.0, self.cpu - sum(c.cpu for c in self.children))
 
 
+# the root whose work a per-unit metric divides by: `api.write` counts
+# samples (tag `n`), the others one a root
+ROOT_OF = {"ksample": "api.write", "query": "api.queryRange",
+           "pass": "mediator.runOnce"}
+
+
 def matches(name: str, patterns) -> bool:
     """A pattern is a span's name, or a prefix ending in ``*``."""
     return any(name == p or (p.endswith("*") and name.startswith(p[:-1]))
@@ -77,9 +89,12 @@ class Spans:
         self.roots = [n for n in nodes
                       if n.parent is None and n.t0 >= t0 and n.t1 <= t1]
 
-    def under_roots(self) -> list[Node]:
-        """The roots that lie inside the slice, and all below them."""
-        out, todo = [], list(self.roots)
+    def under_roots(self, names=None) -> list[Node]:
+        """The roots that lie inside the slice, and all below them; only
+        the roots named in `names` and what lies below them, where it is
+        given."""
+        out = []
+        todo = [r for r in self.roots if names is None or r.name in names]
         while todo:
             n = todo.pop()
             out.append(n)
@@ -102,11 +117,10 @@ class Spans:
     def work(self, per: str) -> float:
         """What the slice's own roots counted, in the unit a per-unit
         metric divides by."""
+        roots = [r for r in self.roots if r.name == ROOT_OF[per]]
         if per == "ksample":
-            return sum(r.tags.get("n", 0) for r in self.roots
-                       if r.name == "api.write") / 1e3
-        name = {"query": "api.queryRange", "pass": "mediator.runOnce"}[per]
-        return sum(1 for r in self.roots if r.name == name)
+            return sum(r.tags.get("n", 0) for r in roots) / 1e3
+        return len(roots)
 
 
 def load(cell) -> Spans | None:

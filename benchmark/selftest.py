@@ -7,7 +7,9 @@
 - the trace reduction on the small recorded trace kept beside this file
   (``data/sample_trace.json``, cut from a chip run);
 - every ``metrics/*.json`` and ``traffic/*.json`` loads and names things
-  that exist;
+  that exist, every metric file is some entry's, and the per-layer list
+  holds no copy of a reading but those it is known to hold;
+- a per-unit span reader counts only below the roots that count its unit;
 - the measurement path refuses to run without a TPU and prints no result;
 - every cell of BENCHMARK.json reads ``correct: true`` on a sound run and
   ``correct: false`` with each control its traffic file lists
@@ -29,12 +31,14 @@ import subprocess
 import sys
 import time
 from pathlib import Path
+from types import SimpleNamespace
 
 HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE.parent))
 
 from benchmark import harness, stats  # noqa: E402
-from benchmark.reducers import tracefile  # noqa: E402
+from benchmark.reducers import (  # noqa: E402
+    node_span_count, node_span_ms, node_spans, tracefile)
 
 
 # -- arithmetic ---------------------------------------------------------------
@@ -133,9 +137,11 @@ def test_files_name_things_that_exist():
         assert set(m.get("workloads", [])) <= cells, m["name"]
         if "moves" in m:
             assert m["moves"] in e2e, m["name"]
+    named = {m["name"] for m in bench["end_to_end"] + bench["per_layer"]}
     for p in (HERE / "metrics").glob("*.json"):
         spec = json.loads(p.read_text())
         importlib.import_module("benchmark.reducers." + spec["reducer"])
+        assert p.stem in named, p.name         # no orphaned reader
     for p in (HERE / "traffic").glob("*.json"):
         spec = json.loads(p.read_text())
         importlib.import_module("benchmark.generators." + spec["generator"])
@@ -148,6 +154,116 @@ def test_files_name_things_that_exist():
         cfg = json.loads((HERE.parent / c["file"]).read_text())
         assert sorted(cfg["reduced"]) == sorted(c["reduced"]), c["name"]
         assert cfg["source"] == c["source"]
+
+
+# -- one reader per meaning ----------------------------------------------------
+
+# The later entries of each group of per-layer entries that read the same
+# thing (see `_copies`): they stand until the CPU tests that count entries
+# by suffix (tests/test_node_spans.py, test_aggregator_service.py,
+# test_aggregator_timer_service.py, test_flushed_read.py) select them by
+# name; then each joins the `workloads` of its group's first entry and
+# this set empties.  No entry may join it.
+_NOT_YET_FOLDED = frozenset({
+    "query_p90_ms.flushed", "query_req_p50_ms.flushed",
+    "query_device_ms_per_query.flushed", "rate_family_roofline.flushed",
+    "index_query_ms_per_query.flushed", "series_read_ms_per_query.flushed",
+    "eval_ms_per_query.flushed", "render_ms_per_query.flushed",
+    "lock_wait_ms_per_query.flushed", "query_unnamed_pct.flushed",
+    "read_columnar_pct.flushed", "read_locked_ms_per_query.flushed",
+    "frame_decode_ms_per_ksample.timer", "resolve_ms_per_ksample.timer",
+    "add_ms_per_ksample.timer", "lock_wait_ms_per_ksample.timer",
+    "dispatch_ms_per_ksample.timer", "flush_emit_ms_per_pass.timer",
+    "arena_calls_per_ksample.timer", "frame_unnamed_pct.timer",
+    "consume_ms_per_pass.timer",
+    *(f"{kind}.{suffix}" for kind in (
+        "device_idle_pct", "idle_unnamed_pct", "gc_pause_pct",
+        "window_compiles", "gil_contended_pct", "gil_wait_ms")
+      for suffix in ("flushed", "agg", "timer")),
+})
+
+
+def _spec(name: str) -> dict:
+    return harness.load_json("metrics", name + ".json")
+
+
+def _copies(per_layer: list[dict], spec_of=_spec) -> set:
+    """Names of the entries that read what an earlier entry reads: the
+    same reducer and params (the file without its `what`), `moves`,
+    `layer`, `unit`, `better` and `source`."""
+    first, later = {}, set()
+    for m in per_layer:
+        spec = spec_of(m["name"])
+        spec.pop("what", None)
+        key = json.dumps([spec] + [m[k] for k in (
+            "moves", "layer", "unit", "better", "source")], sort_keys=True)
+        if key in first:
+            later.add(m["name"])
+        first.setdefault(key, m["name"])
+    return later
+
+
+def test_per_layer_list_holds_no_new_copy():
+    with open(HERE.parent / "BENCHMARK.json") as f:
+        per_layer = json.load(f)["per_layer"]
+    assert len(per_layer) <= 128
+    assert _copies(per_layer) == _NOT_YET_FOLDED
+    # the guard sees a copy: an entry equal to one before it, renamed
+    first = per_layer[0]["name"]
+    assert "x" in _copies(per_layer + [dict(per_layer[0], name="x")],
+                          lambda n: _spec(first if n == "x" else n))
+
+
+# -- span readers count a path's own spans ---------------------------------------
+
+
+class _Ring:
+    """A tracer's ring as node_spans.load reads it, from (id, parent,
+    name, t0_s, t1_s, tags) rows."""
+
+    def __init__(self, rows):
+        self.dropped = self.dropped_until_ns = 0
+        self._spans = [SimpleNamespace(
+            span_id=i, parent_id=p, name=n, start_ns=int(t0 * 1e9),
+            end_ns=int(t1 * 1e9), tags=tags) for i, p, n, t0, t1, tags in rows]
+
+    def finished(self):
+        return self._spans
+
+
+def test_span_readers_count_only_under_their_units_roots():
+    # one write of 2,000 samples, one query, one maintenance pass, each
+    # waiting for the database lock; the decode opens under writes alone
+    rows = [(1, None, "api.write", 0.0, 5.0, {"n": 2000}),
+            (2, 1, "db.lock.wait", 1.0, 2.0, {}),
+            (3, 1, "api.write.decode", 2.0, 2.5, {}),
+            (4, None, "api.queryRange", 6.0, 10.0, {}),
+            (5, 4, "db.lock.wait", 6.0, 8.0, {}),
+            (6, 4, "query.eval.block", 8.0, 8.1, {}),
+            (7, None, "mediator.runOnce", 11.0, 16.0, {}),
+            (8, 7, "db.lock.wait", 11.0, 15.0, {}),
+            (9, 7, "query.eval.block", 15.0, 15.1, {})]
+    cell = SimpleNamespace(asm=SimpleNamespace(tracer=_Ring(rows)),
+                           trace=True, slice=(0.0, 30.0))
+    wait = {"spans": ["db.lock.wait"]}
+    assert abs(node_span_ms.read(cell, {**wait, "per": "ksample"})
+               - 1000.0 / 2) < 1e-6              # the write's 1 s alone
+    assert abs(node_span_ms.read(cell, {**wait, "per": "query"})
+               - 2000.0) < 1e-6                  # the query's 2 s alone
+    assert abs(node_span_ms.read(cell, {**wait, "per": "pass"})
+               - 4000.0) < 1e-6                  # the pass's 4 s alone
+    assert node_span_count.read(
+        cell, {"spans": ["query.eval.block"], "per": "query"}) == 1
+    # a span that opens under one kind of root reads as over every root
+    spans = node_spans.load(cell)
+    whole = sum(n.self_seconds for n in spans.under_roots()
+                if n.name == "api.write.decode")
+    assert abs(node_span_ms.read(
+        cell, {"spans": ["api.write.decode"], "per": "ksample"})
+        - whole * 1e3 / 2) < 1e-6
+    # nothing of the unit's own: nothing to read
+    assert node_span_ms.read(
+        cell, {"spans": ["api.write.decode"], "per": "pass"}) is None
 
 
 # -- no TPU, no result ----------------------------------------------------------
