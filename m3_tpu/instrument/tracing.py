@@ -138,6 +138,12 @@ class Tracepoint:
     # bloom, index walk, checksums), slots and buffer snapshots; tags n
     # (series asked), streams (segments read)
     DB_READ_LOCKED = "db.read.locked"
+    # the device sort of an open window whose sorted snapshot missed
+    # (storage/buffer.py ShardBuffer._sorted_window): under
+    # db.read.locked in a fetch; tags points (the window's entries),
+    # stale (1: a write or drain invalidated an existing snapshot of
+    # this window; 0: there was none).  A hit opens no span
+    DB_BUFFER_SNAPSHOT = "db.buffer.snapshot"
     # under db.read, after the release, the sealed part of a batch
     # fetch: one span a fetch and flushed block (Namespace._decode_block);
     # tags n (series asked), device / scalar (series the device decoded

@@ -53,6 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from m3_tpu.instrument import tracing
+from m3_tpu.instrument.tracing import Tracepoint
 from m3_tpu.x import devguard, membudget
 
 
@@ -191,7 +193,8 @@ class ShardBuffer:
     """Host wrapper owning one shard's buffer ring + overflow lists."""
 
     def __init__(self, block_size_nanos: int, num_windows: int,
-                 sample_capacity: int, slot_capacity: int):
+                 sample_capacity: int, slot_capacity: int,
+                 snapshot_counters=None):
         self.block_size = block_size_nanos
         self.num_windows = num_windows
         self.sample_capacity = sample_capacity
@@ -223,6 +226,9 @@ class ShardBuffer:
         # between two writes pay ONE drain + K binary searches.
         self._version = 0
         self._snap: dict[int, tuple] = {}  # block_start -> (version, s, t, v)
+        # (hits, misses, stale) of the snapshot cache on /metrics (the
+        # Database's `db` scope: buffer_snapshot_*), or None
+        self._snap_counters = snapshot_counters
 
     def _row_for(self, block_start: int) -> int:
         return (block_start // self.block_size) % self.num_windows
@@ -396,11 +402,22 @@ class ShardBuffer:
         if row is None:
             return None
         hit = self._snap.get(block_start)
+        counters = self._snap_counters
         if hit is not None and hit[0] == self._version:
+            if counters is not None:
+                counters[0].inc()
             return hit[1:]
-        s_slot, s_ts, s_val, first = self._drain_row(row)
-        keep = first & (s_slot < self.slot_capacity)
-        out = (s_slot[keep], s_ts[keep], s_val[keep])
+        # a miss: a snapshot of this window at an older version means a
+        # mutation since (a write to any window, a drain) made it stale
+        stale = int(hit is not None)
+        if counters is not None:
+            counters[1].inc()
+            counters[2].inc(stale)
+        with tracing.span(Tracepoint.DB_BUFFER_SNAPSHOT,
+                          {"points": int(self._n_host[row]), "stale": stale}):
+            s_slot, s_ts, s_val, first = self._drain_row(row)
+            keep = first & (s_slot < self.slot_capacity)
+            out = (s_slot[keep], s_ts[keep], s_val[keep])
         # one snapshot per OPEN window (reads alternate between open
         # blocks per series — a single-entry cache would thrash back to
         # O(window) per read); closed windows' entries are pruned here

@@ -250,7 +250,8 @@ def _gather_runs(keys: np.ndarray, ts: np.ndarray, vals: np.ndarray,
 
 class Shard:
     def __init__(self, namespace: str, shard_id: int, opts: NamespaceOptions, root: str,
-                 block_cache=None, new_series_limiter=None, corruption_cb=None):
+                 block_cache=None, new_series_limiter=None, corruption_cb=None,
+                 snapshot_counters=None):
         self.namespace = namespace
         self.shard_id = shard_id
         self.opts = opts
@@ -272,7 +273,7 @@ class Shard:
         num_windows = max(2, span // opts.block_size_nanos + 2)
         self.buffer = ShardBuffer(
             opts.block_size_nanos, int(num_windows), opts.sample_capacity,
-            opts.slot_capacity,
+            opts.slot_capacity, snapshot_counters=snapshot_counters,
         )
         self.flushed_blocks: set[int] = set()
         for bs, _vol in list_filesets(root, namespace, shard_id):
@@ -710,10 +711,18 @@ class Namespace:
             scope.counter("fileset_" + c) for c in (
                 "series_device_decoded", "series_scalar_decoded",
                 "decode_points"))
+        # the open windows' sorted-snapshot cache, every shard's buffer
+        # (storage/buffer.py ShardBuffer._sorted_window): reads it
+        # served, reads that re-sorted a window, and of those the ones
+        # a mutation since the last sort forced
+        self.snapshot_counters = None if scope is None else tuple(
+            scope.counter("buffer_snapshot_" + c)
+            for c in ("hits", "misses", "stale"))
         self.shards = [
             Shard(name, i, opts, root, block_cache,
                   new_series_limiter=new_series_limiter,
-                  corruption_cb=corruption_cb)
+                  corruption_cb=corruption_cb,
+                  snapshot_counters=self.snapshot_counters)
             for i in range(opts.num_shards)
         ]
         # Placement-driven ownership: None = own every shard (the
@@ -1200,6 +1209,7 @@ class Database:
                 self.block_cache,
                 new_series_limiter=self.new_series_limiter,
                 corruption_cb=self._note_corruption,
+                snapshot_counters=ns.snapshot_counters,
             )
             _LOG.info("dropped shard ns=%s shard=%d (%d fileset volumes)",
                       namespace, shard_id, removed)

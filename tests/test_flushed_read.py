@@ -20,6 +20,7 @@ from m3_tpu.storage import database as dbmod
 from m3_tpu.storage.database import (
     Database, DatabaseOptions, NamespaceOptions, shard_for_id,
 )
+from tests.per_layer_entries import check_workloads
 
 SEC = 10**9
 MIN = 60 * SEC
@@ -369,6 +370,19 @@ def test_a_range_that_ends_at_a_block_start_does_not_visit_that_block(
     db.close()
 
 
+# the cell's twenty-two per-layer entries
+_FLUSHED = [n + ".flushed" for n in (
+    "fileset_read_ms_per_query", "segments_ms_per_query",
+    "decode_to_host_ms_per_query", "decode_calls_per_query",
+    "decode_device_ms_per_query", "decode_roofline", "device_decode_pct",
+    "query_req_p50_ms", "query_p90_ms", "query_device_ms_per_query",
+    "rate_family_roofline", "eval_ms_per_query", "series_read_ms_per_query",
+    "lock_wait_ms_per_query", "index_query_ms_per_query",
+    "render_ms_per_query", "query_unnamed_pct", "read_columnar_pct",
+    "device_idle_pct", "idle_unnamed_pct", "gc_pause_pct",
+    "window_compiles")]
+
+
 def test_the_cells_per_layer_entries_are_well_formed():
     """PR 33's `.flushed` entries of BENCHMARK.json: one cell, the
     end-to-end metric that cell reports, a reader that exists."""
@@ -379,19 +393,19 @@ def test_the_cells_per_layer_entries_are_well_formed():
     repo = Path(__file__).resolve().parent.parent
     bench = json.loads((repo / "BENCHMARK.json").read_text())
     cell = "prom.dashboard_flushed"
-    mine = [m for m in bench["per_layer"] if m["name"].endswith(".flushed")
-            # PR 35's two and PR 36's one are held by
-            # tests/test_node_spans.py
-            and not m["name"].startswith(("gil_", "read_locked_"))]
-    assert len(mine) == 22
+    # by name (the two `gil_` entries and the `read_locked_` one are held by
+    # tests/test_node_spans.py); a copy of a dashboard reader or a
+    # `.query` guard may stand folded into that entry
+    assert len(_FLUSHED) == len(set(_FLUSHED)) == 22
     (e2e,) = [m for m in bench["end_to_end"] if m["name"] == "queries_per_s"]
     assert cell in e2e["workloads"]
-    for m in mine:
-        assert m["workloads"] == [cell] and m["moves"] == "queries_per_s"
+    for name in _FLUSHED:
+        m = check_workloads(bench, name, [cell])
+        assert m["moves"] == "queries_per_s"
         spec = json.loads((repo / "benchmark" / "metrics"
                            / (m["name"] + ".json")).read_text())
         importlib.import_module("benchmark.reducers." + spec["reducer"])
-        if m["name"].endswith("_roofline.flushed"):
+        if name.endswith("_roofline.flushed"):
             assert (m["unit"], m["better"]) == ("%", "higher")
     (cfg,) = [c for c in bench["configs"] if c["name"] == "m3tsz_flushed_blocks"]
     file = json.loads((repo / cfg["file"]).read_text())
@@ -446,3 +460,89 @@ def test_the_lock_is_free_while_a_fetch_decodes(tmp_path, monkeypatch):
     assert _bits(cols.values.ravel()) == _bits(want.values.ravel())
     assert cols.counts.tolist() == want.counts.tolist() == [P] * len(ids)
     db.close()
+
+
+# -- the open window's sorted snapshot: its span and its counters ------------
+
+
+def _open_db(root, tracer):
+    """One shard, so that one fetch asks one buffer for one snapshot."""
+    reg = Registry()
+    db = Database(
+        DatabaseOptions(root=str(root), commitlog_enabled=False),
+        namespaces={"default": NamespaceOptions(
+            num_shards=1, slot_capacity=64, sample_capacity=64 * 128)},
+        instrument=reg.scope("m3tpu"), tracer=tracer)
+    return db, reg
+
+
+def _snapshot_counts(reg) -> dict:
+    return {k.rsplit("_", 1)[-1]: v for k, v in reg.snapshot().items()
+            if ".db.buffer_snapshot_" in k}
+
+
+def _read_write_read(db, then_read_again=False):
+    """Two fetches of the open block T0 with one write into it between
+    them (and a third fetch after the second, with none) -> their
+    columns."""
+    rows = _series()["counters"][:3]
+    _write(db, rows)
+    ids = [sid for sid, _ in rows]
+    end = T0 + BLOCK
+    out = [db.read_columns("default", ids, T0, end)]
+    # a point of the same open window, after the others of its series
+    _write(db, [(ids[0], [(T0 + BLOCK - 5 * SEC, 7.0)])])
+    out.append(db.read_columns("default", ids, T0, end))
+    if then_read_again:
+        out.append(db.read_columns("default", ids, T0, end))
+    return out
+
+
+def test_a_write_between_two_reads_re_sorts_the_window_under_a_span(
+        tmp_path):
+    tracer = Tracer(enabled=True)
+    db, reg = _open_db(tmp_path, tracer)
+    tracing.install(tracer)
+    try:
+        first, second = _read_write_read(db)
+        assert _snapshot_counts(reg) == {"hits": 0, "misses": 2, "stale": 1}
+        spans = tracer.finished(Tracepoint.DB_BUFFER_SNAPSHOT)
+        assert [s.tags["stale"] for s in spans] == [0, 1]
+        assert [s.tags["points"] for s in spans] == [3 * P, 3 * P + 1]
+        locked = {s.span_id for s in tracer.finished(
+            Tracepoint.DB_READ_LOCKED)}
+        assert len(locked) == 2 and {s.parent_id for s in spans} == locked
+        assert second.counts.tolist() == [P + 1, P, P]
+        # a read with no write since: the snapshot serves it, no span
+        db.read_columns("default", [b"counter-0"], T0, T0 + BLOCK)
+        assert _snapshot_counts(reg) == {"hits": 1, "misses": 2, "stale": 1}
+        assert len(tracer.finished(Tracepoint.DB_BUFFER_SNAPSHOT)) == 2
+        text = reg.render_prometheus()
+        for name, v in (("hits", 1), ("misses", 2), ("stale", 1)):
+            assert f"m3tpu_db_buffer_snapshot_{name} {v}" in text
+    finally:
+        tracing.uninstall(tracer)
+        db.close()
+
+
+def test_the_snapshot_span_costs_nothing_where_nothing_records(tmp_path):
+    """With recording off, no span, and every answer bit for bit what a
+    recording node answers; the counters count all the same."""
+    answers = {}
+    for on in (True, False):
+        tracer = Tracer(enabled=on)
+        db, reg = _open_db(tmp_path / str(on), tracer)
+        tracing.install(tracer)
+        try:
+            answers[on] = _read_write_read(db, then_read_again=True)
+            assert len(tracer.finished(
+                Tracepoint.DB_BUFFER_SNAPSHOT)) == (2 if on else 0)
+            assert _snapshot_counts(reg) == {
+                "hits": 1, "misses": 2, "stale": 1}
+        finally:
+            tracing.uninstall(tracer)
+            db.close()
+    for a, b in zip(answers[True], answers[False]):
+        assert np.array_equal(a.ts, b.ts)
+        assert _bits(a.values.ravel()) == _bits(b.values.ravel())
+        assert a.counts.tolist() == b.counts.tolist()

@@ -34,6 +34,7 @@ REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
 from benchmark import selftest, stats  # noqa: E402
+from tests.per_layer_entries import check_workloads, entry  # noqa: E402
 from benchmark.reducers import (  # noqa: E402
     compile_log, node_span_ms, node_span_slice_pct, node_span_tag_mean,
     node_span_tag_pct, node_span_unnamed_pct, node_spans,
@@ -1148,38 +1149,83 @@ def test_benchmark_selftest_passes_whole():
     assert "FAIL" not in p.stdout
 
 
+# The program-span entries of the write, load and dashboard cells, each
+# with the cells that read it (the `.agg` entries are held by
+# tests/test_aggregator_service.py, the `.timer` ones by
+# tests/test_aggregator_timer_service.py, the `.flushed` ones by
+# tests/test_flushed_read.py, the runtime, read-locked and `.fleet` ones
+# below): the fleet cell reads the dashboard cell's shape-free readers,
+# and prom.mixed those of prom.remote_write
+_LIVE, _FLEET, _MIXED = "prom.dashboard_live", "prom.fleet_quantile", "prom.mixed"
+_WRITE = "prom.remote_write"
+_SPAN_ENTRIES = {
+    "decode_ms_per_ksample": [_WRITE, _MIXED],
+    "decode_ms_per_ksample.load": ["tsbs.load"],
+    "match_ms_per_ksample": [_WRITE, _MIXED],
+    "lock_wait_ms_per_ksample": [_WRITE, _MIXED],
+    "lock_wait_ms_per_ksample.load": ["tsbs.load"],
+    "index_ms_per_ksample": [_WRITE, _MIXED],
+    "index_ms_per_ksample.load": ["tsbs.load"],
+    "commitlog_ms_per_ksample": [_WRITE, _MIXED],
+    "commitlog_ms_per_ksample.load": ["tsbs.load"],
+    "dispatch_ms_per_ksample": [_WRITE, _MIXED],
+    "flush_writeback_ms_per_pass": [_WRITE, _MIXED],
+    "write_unnamed_pct": [_WRITE, _MIXED],
+    "write_unnamed_pct.load": ["tsbs.load"],
+    "index_query_ms_per_query": [_LIVE, _FLEET],
+    "series_read_ms_per_query": [_LIVE, _FLEET],
+    "eval_ms_per_query": [_LIVE, _FLEET],
+    "render_ms_per_query": [_LIVE, _FLEET],
+    "lock_wait_ms_per_query": [_LIVE, _FLEET],
+    "query_unnamed_pct": [_LIVE, _FLEET],
+    "gc_pause_pct.write": [_WRITE, _MIXED],
+    "gc_pause_pct.load": ["tsbs.load"],
+    "gc_pause_pct.query": [_LIVE, _FLEET],
+    "idle_unnamed_pct.write": [_WRITE, _MIXED],
+    "idle_unnamed_pct.load": ["tsbs.load"],
+    "idle_unnamed_pct.query": [_LIVE, _FLEET],
+    "decode_cache_hit_pct": [_WRITE, _MIXED],
+    "decode_cache_hit_pct.load": ["tsbs.load"],
+    "read_columnar_pct": [_LIVE],
+    "group_one_program_pct": [_LIVE],
+}
+
+
 def test_new_per_layer_entries_are_well_formed():
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    # PR 25's, PR 26's, PR 30's and PR 32's entries (PR 27's `.agg` entries are
-    # held by tests/test_aggregator_service.py, PR 31's `.timer` entries
-    # by tests/test_aggregator_timer_service.py, PR 33's `.flushed`
-    # entries by tests/test_flushed_read.py, the `.fleet` entries by
-    # test_fleet_entries_are_well_formed)
-    new = [m for m in bench["per_layer"] if m["source"] == "program_span"
-           and m["name"] != "maintain_ms_per_pass"
-           and not m["name"].endswith((".agg", ".timer", ".flushed",
-                                       ".fleet"))
-           # PR 35's and PR 36's: below
-           and not m["name"].startswith(("gil_", "read_locked_"))]
-    assert len(new) == 29
+    assert len(_SPAN_ENTRIES) == 29
     layers = {m["layer"] for m in bench["per_layer"]
-              if m not in new}
+              if m["name"] not in _SPAN_ENTRIES}
     e2e = {m["name"]: m for m in bench["end_to_end"]}
-    for m in new:
-        hit_share = m["name"].startswith(("decode_cache_hit_pct",
-                                          "read_columnar_pct",
-                                          "group_one_program_pct"))
+    for name, cells in _SPAN_ENTRIES.items():
+        m = check_workloads(bench, name, cells)
+        assert m["source"] == "program_span"
+        hit_share = name.startswith(("decode_cache_hit_pct",
+                                     "read_columnar_pct",
+                                     "group_one_program_pct"))
         assert m["layer"] in layers
         assert m["better"] == ("higher" if hit_share else "lower")
-        # the fleet cell reads the dashboard cell's readers too
-        cell, *more = m["workloads"]
-        assert more in ([], ["prom.fleet_quantile"])
-        assert cell in e2e[m["moves"]]["workloads"]
+        for cell in cells:
+            assert cell in e2e[m["moves"]]["workloads"], (name, cell)
         spec = json.loads((REPO / "benchmark" / "metrics"
                            / (m["name"] + ".json")).read_text())
         assert spec["reducer"] in ("node_span_ms", "node_span_unnamed_pct",
                                    "node_span_slice_pct", "node_span_tag_pct",
                                    "trace_idle_unnamed_pct")
+
+
+def _consecutive(names: list, wanted: list) -> list:
+    """Positions of the `wanted` names that stand in the list, asserted
+    to follow one another in that order."""
+    at = [names.index(n) for n in wanted if n in names]
+    assert at == list(range(at[0], at[0] + len(at))), wanted
+    return at
+
+
+_SUFFIXES = ("write", "load", "query", "agg", "timer", "flushed")
+_GIL = [f"{kind}.{suffix}" for kind in ("gil_contended_pct", "gil_wait_ms")
+        for suffix in _SUFFIXES]
+_UNDER_SETUP = ["setup_compile_s", "setup_programs", "setup_cache_hit_pct"]
 
 
 def test_runtime_entries_are_well_formed():
@@ -1188,12 +1234,12 @@ def test_runtime_entries_are_well_formed():
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     cells = [w["name"] for w in bench["workloads"]]
     e2e = {m["name"]: m for m in bench["end_to_end"]}
-    by_name = {m["name"]: m for m in bench["per_layer"]}
-    gil = [m for m in bench["per_layer"] if m["name"].startswith("gil_")]
-    assert len(gil) == 12
-    for m in gil:
-        kind, suffix = m["name"].split(".")
-        gc_twin = by_name["gc_pause_pct." + suffix]
+    assert len(_GIL) == 12
+    gil = {}
+    for name in _GIL:
+        kind, suffix = name.split(".")
+        m = entry(bench, name)
+        gc_twin = entry(bench, "gc_pause_pct." + suffix)
         assert (m["layer"], m["moves"], m["workloads"], m["source"]) == (
             "guards", gc_twin["moves"], gc_twin["workloads"], "program_span")
         assert m["better"] == "lower"
@@ -1203,10 +1249,12 @@ def test_runtime_entries_are_well_formed():
         assert spec["reducer"] == {"gil_contended_pct": "node_span_tag_pct",
                                    "gil_wait_ms": "node_span_tag_mean"}[kind]
         assert spec["params"]["spans"] == [Tracepoint.RUNTIME_GIL_PROBE]
-    assert sorted(c for m in gil for c in m["workloads"]) == sorted(cells * 2)
+        gil[m["name"]] = m
+    # every cell in one entry of each reading
+    assert sorted(c for m in gil.values() for c in m["workloads"]) == sorted(
+        cells * 2)
     under_setup = [m for m in bench["per_layer"] if m["moves"] == "setup_s"]
-    assert [m["name"] for m in under_setup] == [
-        "setup_compile_s", "setup_programs", "setup_cache_hit_pct"]
+    assert [m["name"] for m in under_setup] == _UNDER_SETUP
     assert "workloads" not in e2e["setup_s"]
     for m in under_setup:
         assert (m["layer"], m["source"], m["workloads"]) == (
@@ -1214,11 +1262,13 @@ def test_runtime_entries_are_well_formed():
         spec = json.loads((REPO / "benchmark" / "metrics"
                            / (m["name"] + ".json")).read_text())
         assert spec["reducer"] == "compile_log"
-    # together, after every earlier PR's; later PRs' entries follow
-    at = bench["per_layer"].index(gil[0])
-    assert bench["per_layer"][at:at + 15] == gil + under_setup
-    assert all(m["name"].startswith("read_locked_")
-               for m in bench["per_layer"][at + 15:at + 17])
+    # together, and the read-locked entries right after them
+    names = [m["name"] for m in bench["per_layer"]]
+    at = _consecutive(names, _GIL + _UNDER_SETUP + _READ_LOCKED)
+    assert len(at) == len(gil) + 3 + sum(n in names for n in _READ_LOCKED)
+
+
+_READ_LOCKED = ["read_locked_ms_per_query", "read_locked_ms_per_query.flushed"]
 
 
 def test_read_locked_entries_are_well_formed():
@@ -1226,14 +1276,14 @@ def test_read_locked_entries_are_well_formed():
     cell, read from the db.read.locked span whole, per query."""
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     e2e = {m["name"]: m for m in bench["end_to_end"]}
-    mine = [m for m in bench["per_layer"]
-            if m["name"].startswith("read_locked_")]
-    assert [(m["name"], m["workloads"]) for m in mine] == [
-        ("read_locked_ms_per_query",
-         ["prom.dashboard_live", "prom.fleet_quantile"]),
-        ("read_locked_ms_per_query.flushed", ["prom.dashboard_flushed"])]
-    # the last of the per-layer list but the fleet cell's three
-    assert bench["per_layer"][-5:-3] == mine
+    mine = [check_workloads(bench, "read_locked_ms_per_query",
+                            [_LIVE, _FLEET]),
+            check_workloads(bench, "read_locked_ms_per_query.flushed",
+                            ["prom.dashboard_flushed"])]
+    # just before the fleet cell's three
+    names = [m["name"] for m in bench["per_layer"]]
+    at = _consecutive(names, _READ_LOCKED + list(_FLEET_NEW))
+    assert len(at) == 3 + sum(n in names for n in _READ_LOCKED)
     for m in mine:
         assert (m["unit"], m["better"], m["source"], m["layer"], m["moves"]) \
             == ("ms", "lower", "program_span", "HTTP front door + read path",
@@ -1266,14 +1316,15 @@ _FLEET_SHARED = (
 
 
 def test_fleet_entries_are_well_formed():
-    """The fleet cell's three metrics, last in the list, move
-    queries_per_s in that cell alone; it is appended to the workloads of
-    the dashboard cell's shape-free readers and of the set-up metrics,
-    and not to the panel-shaped rate_family_roofline."""
+    """The fleet cell's three metrics, consecutive, move queries_per_s
+    in that cell alone; it is appended to the workloads of the dashboard
+    cell's shape-free readers and of the set-up metrics, and not to the
+    panel-shaped rate_family_roofline."""
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     by_name = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"][-3:]] == list(_FLEET_NEW)
+    names = [m["name"] for m in bench["per_layer"]]
+    assert len(_consecutive(names, list(_FLEET_NEW))) == 3
     assert len(bench["per_layer"]) <= 128
     assert "prom.fleet_quantile" in e2e["queries_per_s"]["workloads"]
     metrics = REPO / "benchmark" / "metrics"
@@ -1288,8 +1339,7 @@ def test_fleet_entries_are_well_formed():
     assert reading == set(_FLEET_NEW) | set(_FLEET_SHARED) | {
         "setup_compile_s", "setup_programs", "setup_cache_hit_pct"}
     for name in _FLEET_SHARED:
-        assert by_name[name]["workloads"] == [
-            "prom.dashboard_live", "prom.fleet_quantile"]
+        check_workloads(bench, name, [_LIVE, _FLEET])
     # not the panel-shaped roofline: its bytes are calls x one panel's shape
     assert "prom.fleet_quantile" not in by_name["rate_family_roofline"][
         "workloads"]
