@@ -971,13 +971,31 @@ def _peek(words, cursor, n):
 # costing ~5x the single tiny gather it avoided.  The padded stream
 # array keeps >= 4 zero words past the longest stream, so the gather
 # never clips in range.
+#
+# How the four words are read is the ``regfile`` seam, resolved on the
+# host by backend (``resolved_regfile``), as ``chains`` is:
+#
+# ``gather``  one ``take_along_axis``: a few ns a lane on XLA-CPU, but
+#             on a TPU a per-element gather of ~9.7 ns an element, and
+#             with each u64 split into two u32 halves this is 8 of the
+#             9 gathered elements a row pays per step.
+# ``select``  a select against the word index under an OR over W:
+#             O(W) fused vector work a lane, no gather.  Exactly one
+#             column matches each k (the padding keeps ``w0i + 3`` in
+#             bounds), so the OR is that word, by bits.
 
 _PAD_WORDS = 16          # zero padding after the longest stream (words)
 
 
-def _regfile4(words, w0i):
-    """Gather the 4 consecutive u64 stream words starting at per-lane
-    word index ``w0i`` from the padded (S, W) array."""
+def _regfile4(words, w0i, regfile: str = "gather"):
+    """The 4 consecutive u64 stream words starting at per-lane word
+    index ``w0i`` of the padded (S, W) array."""
+    if regfile == "select":
+        d = jnp.arange(words.shape[1], dtype=I32)[None, :] - w0i[:, None]
+        return tuple(
+            lax.reduce(jnp.where(d == _c(k, I32), words, _c(0)), _c(0),
+                       lax.bitwise_or, (1,))
+            for k in range(4))
     idx = w0i[:, None] + jnp.arange(4, dtype=I32)[None, :]
     R = jnp.take_along_axis(words, idx, axis=1, mode="promise_in_bounds")
     return R[:, 0], R[:, 1], R[:, 2], R[:, 3]
@@ -1132,7 +1150,7 @@ _CTRL_TBL_LOCK = threading.Lock()
 
 
 def _decode_step(carry, _, words, nbits, unit0, ctrl_tbl,
-                 emit_chains: bool = False):
+                 emit_chains: bool = False, regfile: str = "gather"):
     """Phase 1 of the two-phase decode: ONE datapoint slot for every
     series at once ((S,) array ops), resolving ONLY the data-dependent
     minimum — control bits, field widths and the bit cursor — and
@@ -1145,7 +1163,7 @@ def _decode_step(carry, _, words, nbits, unit0, ctrl_tbl,
 
     ``words`` is the padded (S, W) stream array (closure, not carry);
     ``nbits`` the per-series stream bit lengths.  All bit reads come
-    from a 4-word register file gathered once per step (``_regfile4``).
+    from a 4-word register file read once per step (``_regfile4``).
     The body is deliberately ONE branch-free straight line — no
     ``lax.cond`` anywhere (round-6 profiling: every cond is a thunk
     boundary on XLA-CPU, and the buffer round-trips at those boundaries
@@ -1170,7 +1188,7 @@ def _decode_step(carry, _, words, nbits, unit0, ctrl_tbl,
     unit_eff = jnp.where(need_start, unit0, unit_idx)
     first = first_val  # value-mode branch key (first value still pending)
 
-    # ---- register file: ONE 4-word gather at the word index below
+    # ---- register file: ONE 4-word read at the word index below
     # `cur` covers every read this step makes (see _regfile4).  The
     # 64-bit funnel W0 at `cur` serves the marker peek (11), the
     # annotation varint bytes (<= 43 bits in), the time-unit byte
@@ -1180,7 +1198,7 @@ def _decode_step(carry, _, words, nbits, unit0, ctrl_tbl,
     # full 3-word funnel ``rd3``. ----
     c0 = cur
     w0i = c0 >> _c(6, I32)
-    r0, r1, r2, r3 = _regfile4(words, w0i)
+    r0, r1, r2, r3 = _regfile4(words, w0i, regfile)
     rf_base = w0i << _c(6, I32)
 
     # All shifts below are UNGUARDED (no _shl/_shr >=64 clamps): every
@@ -1654,6 +1672,24 @@ def fallback_chains(chains: str) -> str:
     return "fused" if chains != "fused" else "gather"
 
 
+def resolved_regfile() -> str:
+    """The register-file formulation for this process' backend (see
+    ``_regfile4``): ``select`` on a TPU, where a gather costs ~9.7 ns
+    an element, ``gather`` elsewhere.  Read on the host, never under a
+    tracer; no option sets it."""
+    return "select" if jax.default_backend() == "tpu" else "gather"
+
+
+_REGFILE_RAN = threading.local()
+
+
+def last_regfile() -> str | None:
+    """The register-file formulation of the calling thread's last
+    ``decode_batch_device`` call: what ran, a devguard fallback
+    (always ``gather``) included; None before its first call."""
+    return getattr(_REGFILE_RAN, "impl", None)
+
+
 def _resolved_extract(chains: str) -> str:
     """The phase-2 field-extraction impl for a chains tail, resolved on
     the host: only the gather tail runs the extraction pass, so the
@@ -1691,6 +1727,12 @@ def decode_batch_device(words, nbits, max_points: int, default_unit: int = 1,
                 M3_DECODE_CHAINS.  Both tails are bit-identical — pinned
                 by the corpus sha256 + fuzz parity tests.
 
+    The boundary scan's register file is the ``regfile`` seam
+    (``_regfile4``), resolved here by backend (``resolved_regfile``)
+    with no override: ``select`` on a TPU, ``gather`` elsewhere.  The
+    devguard fallback runs ``gather``, the proven formulation, with
+    the other chains tail; ``last_regfile`` names what ran.
+
     Returns (ts (S, max_points) int64, payload (S, max_points) uint64,
     meta (S, max_points) uint8, err (S,), prec (S,), ann (S,)).
     meta: bit4 = valid, bit3 = is_float, bits0-2 = multiplier.
@@ -1707,7 +1749,7 @@ def decode_batch_device(words, nbits, max_points: int, default_unit: int = 1,
     views instead, and in-jit callers compose the decode so XLA folds
     the layout change into their own downstream ops.
 
-    This is the HOST wrapper: the chains/extract seams resolve here
+    This is the HOST wrapper: the chains/extract/regfile seams resolve here
     (env + backend reads are host state — under the tracer they would
     freeze into the first compile and the env override would silently
     stop responding), and the value-control table is fetched as a
@@ -1723,11 +1765,13 @@ def decode_batch_device(words, nbits, max_points: int, default_unit: int = 1,
                          f"{_CHAIN_IMPLS + ('auto',)}")
     S, Wp = words.shape
 
-    def _run(ch: str):
+    def _run(ch: str, regfile: str):
+        _REGFILE_RAN.impl = regfile
         return _decode_batch_device(
             words, nbits, value_ctrl_table(), max_points=max_points,
             default_unit=default_unit, chains=ch,
-            scan_major=scan_major, extract=_resolved_extract(ch))
+            scan_major=scan_major, extract=_resolved_extract(ch),
+            regfile=regfile)
 
     # device-guard seam: the fallback rides the OTHER chains tail as a
     # static argument (the fused tail also pins extract="jnp", so a
@@ -1744,18 +1788,20 @@ def decode_batch_device(words, nbits, max_points: int, default_unit: int = 1,
         membudget.decode_lane_bytes(S, Wp, max_points, chains=fb,
                                     extract=_resolved_extract(fb)))
     with membudget.transient("decode.lanes", lane_bytes):
-        return devguard.run_guarded("decode", lambda: _run(chains),
-                                    lambda: _run(fallback_chains(chains)))
+        return devguard.run_guarded(
+            "decode", lambda: _run(chains, resolved_regfile()),
+            lambda: _run(fb, "gather"))
 
 
 @functools.partial(jax.jit,
                    static_argnames=("max_points", "default_unit", "chains",
-                                    "scan_major", "extract"))
+                                    "scan_major", "extract", "regfile"))
 def _decode_batch_device(words, nbits, ctrl_tbl, max_points: int,
                          default_unit: int = 1, chains: str = "fused",
-                         scan_major: bool = False, extract: str = "jnp"):
+                         scan_major: bool = False, extract: str = "jnp",
+                         regfile: str = "gather"):
     S, Wp = words.shape
-    # Pad the stream with zero words so the phase-1 register-file gather
+    # Pad the stream with zero words so the phase-1 register file
     # (4 words at the cursor) and phase 2's 3-word funnels never read
     # out of bounds.
     wpad = jnp.pad(words, ((0, 0), (0, _PAD_WORDS)))
@@ -1773,7 +1819,7 @@ def _decode_batch_device(words, nbits, ctrl_tbl, max_points: int,
     carry0 = _decode_carry0(S, base_time if fused else None)
     step = functools.partial(_decode_step, words=wpad, nbits=nbits32,
                              unit0=unit0, ctrl_tbl=ctrl_tbl,
-                             emit_chains=fused)
+                             emit_chains=fused, regfile=regfile)
 
     # Decode k datapoints per loop iteration.  Unrolling chains k step
     # bodies inside one iteration, so the narrow carry stays fused
@@ -1843,9 +1889,18 @@ def decode_batch(streams: list[bytes], max_points: int,
     series matters.
     """
     words, nbits = pack_streams(streams)
-    ts, payload, meta, err, prec, ann = decode_batch_device(
-        jnp.asarray(words), jnp.asarray(nbits), max_points=max_points,
-        default_unit=int(default_unit), chains=chains, scan_major=True)
+    return _decoded_to_host(
+        decode_batch_device(jnp.asarray(words), jnp.asarray(nbits),
+                            max_points=max_points,
+                            default_unit=int(default_unit), chains=chains,
+                            scan_major=True),
+        annotations_fallback)
+
+
+def _decoded_to_host(decoded, annotations_fallback: bool):
+    """``decode_batch``'s host half: a scan-major device decode's six
+    outputs -> (timestamps, values, counts, fallback)."""
+    ts, payload, meta, err, prec, ann = decoded
     # Scan-major on device (the terminal transposes were 30% of decode
     # wall-time on CPU); the value reconstruction (payload_value_bits)
     # is elementwise (layout-blind), so it runs on the contiguous

@@ -147,8 +147,9 @@ class Tracepoint:
     # under db.read, after the release, the sealed part of a batch
     # fetch: one span a fetch and flushed block (Namespace._decode_block);
     # tags n (series asked), device / scalar (series the device decoded
-    # / rows through the scalar iterator), words, points, and the
-    # decode's padded shape: rows, steps.  Below it the guarded
+    # / rows through the scalar iterator), regfile (select | gather:
+    # how the boundary scan read its register file), words, points,
+    # and the decode's padded shape: rows, steps.  Below it the guarded
     # device.decode, `.to_host` (the copy and the values' bits)
     DB_READ_FILESET = "db.read.fileset"
     DB_READ_FILESET_SEGMENTS = "db.read.fileset.segments"

@@ -45,7 +45,8 @@ from m3_tpu.index.namespace_index import NamespaceIndex
 from m3_tpu.index.search import Query
 from m3_tpu.encoding.m3tsz import decode_series, encode_series
 from m3_tpu.encoding.m3tsz_jax import (
-    decode_batch_device, encode_batch, pack_streams, payload_value_bits,
+    decode_batch_device, encode_batch, last_regfile, pack_streams,
+    payload_value_bits,
 )
 from m3_tpu.persist.commitlog import (
     CommitLogEntry, CommitLogWriter, commitlog_seq, list_commitlogs,
@@ -912,6 +913,9 @@ class Namespace:
                     n_scalar += 1
                     n_points += len(pts)
             sp.set_tag("device", len(streams) - n_scalar)
+            # the boundary scan's register file as it ran (select |
+            # gather, `m3tsz_jax._regfile4`)
+            sp.set_tag("regfile", last_regfile())
             sp.set_tag("scalar", n_scalar)
             sp.set_tag("words", n_words)
             sp.set_tag("points", n_points)

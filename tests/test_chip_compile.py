@@ -13,6 +13,8 @@ worker imports this file), and all of it lives in this one file so one
 worker owns the library.
 """
 
+import collections
+import re
 import time
 
 import jax
@@ -79,6 +81,37 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
+_NAME = r"%([\w.\-]+)"
+
+
+def _loop_gathers(compiled) -> collections.Counter:
+    """The gathers inside the compiled program's while loops (bodies,
+    conditions and whatever they call), counted by their operand's
+    type as the HLO text states it, e.g. ``u32[512,144]``."""
+    comps = {}
+    for block in re.split(r"\n(?=\S)", compiled.as_text()):
+        m = re.match(r"(?:ENTRY )?" + _NAME, block)
+        if m:
+            comps[m.group(1)] = block
+    todo = [b for blk in comps.values()
+            for b in re.findall(r"\bbody=" + _NAME, blk)]
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        todo += re.findall(r"(?:calls|body|condition|to_apply)=" + _NAME,
+                           comps[name])
+    found = collections.Counter()
+    for name in seen:
+        blk = comps[name]
+        types = dict(re.findall(_NAME + r" = (\w+\[[\d,]*\])", blk))
+        for op in re.findall(r"= \S+ gather\(" + _NAME, blk):
+            found[types.get(op, "?")] += 1
+    return found
+
+
 class TestPallasKernels:
     """Every Pallas kernel `auto` can select on a TPU, compiled by
     Mosaic (interpret=False) at the node's widths."""
@@ -136,14 +169,19 @@ class TestCodecPrograms:
                      scan_major=True, extract=extract)
         assert _has_kernel(c) == (extract == "pallas")
 
-    @pytest.mark.parametrize("words", [128, 192])
-    def test_decode_a_fetch_of_a_sealed_block(self, one_chip, words,
-                                              monkeypatch):
+    @pytest.mark.parametrize("regfile,words", [
+        pytest.param(rf, w, id=str(w) if rf == "gather" else f"{rf}-{w}")
+        for rf in ("gather", "select") for w in (128, 192, 768)])
+    def test_decode_a_fetch_of_a_sealed_block(self, one_chip, regfile,
+                                              words, monkeypatch):
         """The served read path's decode (storage/database.py
         `_decode_streams`) at `prom.dashboard_flushed`'s shape: 390
         series a panel in a row bucket of 512, a full 2 h block of 720
-        points, counter streams in a word bucket of 128 or 192, the
-        tail `chains="auto"` resolves to on a TPU."""
+        points, counter streams in a word bucket of 128 or 192 (768:
+        the extreme-value family's), the tail `chains="auto"` resolves
+        to on a TPU, under both register-file formulations.  `select`
+        (what a TPU resolves) leaves no gather of the stream words in
+        the scan and no more temporaries than `gather`."""
         from m3_tpu.encoding import m3tsz_jax as mj
         from m3_tpu.parallel import pallas_decode
         from m3_tpu.storage import database
@@ -152,12 +190,26 @@ class TestCodecPrograms:
         assert 512 % database._ROW_BUCKET == 0
         assert words % database._WORD_BUCKET == 0
         assert 720 % database._POINT_BUCKET == 0
-        c = _compile(mj._decode_batch_device, one_chip,
-                     A((512, words), jnp.uint64), A((512,), jnp.int64),
-                     A((1 << 18,), jnp.uint32),
-                     max_points=720, default_unit=1, chains="gather",
-                     scan_major=True, extract="pallas")
+
+        def compiled(rf):
+            return _compile(mj._decode_batch_device, one_chip,
+                            A((512, words), jnp.uint64), A((512,), jnp.int64),
+                            A((1 << 18,), jnp.uint32),
+                            max_points=720, default_unit=1, chains="gather",
+                            scan_major=True, extract="pallas", regfile=rf)
+
+        c = compiled(regfile)
         assert _has_kernel(c)
+        # the padded stream words, split into u32 halves on the chip
+        words_op = f"u32[512,{words + mj._PAD_WORDS}]"
+        in_loop = _loop_gathers(c)
+        if regfile == "gather":
+            assert in_loop[words_op] > 0, in_loop
+        else:
+            assert in_loop[words_op] == 0, in_loop
+            temp = c.memory_analysis().temp_size_in_bytes
+            assert temp <= compiled("gather").memory_analysis() \
+                .temp_size_in_bytes
 
 
 class TestStoragePrograms:

@@ -12,7 +12,14 @@ Three layers of bit-identity evidence for the two-phase rewrite
   BOTH chains tails, exact (timestamp, value-bits) equality vs
   decode_series.
 * properties — targeted edges: every dod bucket width, XOR
-  contained/uncontained flips, int<->float mode churn.
+  contained/uncontained flips, int<->float mode churn, a cursor that
+  reads into the padding, an annotation jump, the served path's
+  smallest word bucket.
+
+Each runs under every formulation in ``FORMS``: both chains tails as
+the host wrapper resolves them on this backend (the ``gather``
+register file off a TPU), and both tails with the ``select`` register
+file, the TPU's, by calling the jitted program with the static.
 
 Timestamp equality is on int64s; value equality is on the raw float64
 BIT PATTERNS (``.view(uint64)``) — the decoder's contract is
@@ -32,11 +39,35 @@ jnp = pytest.importorskip("jax.numpy")
 from tests.conftest import DATA_DIR  # noqa: E402
 from m3_tpu.core.xtime import Unit  # noqa: E402
 from m3_tpu.encoding.m3tsz import Datapoint, Encoder, decode_series  # noqa: E402
+from m3_tpu.encoding import m3tsz_jax as mj  # noqa: E402
 from m3_tpu.encoding.m3tsz_jax import decode_batch, encode_batch  # noqa: E402
 
 START = 1_600_000_000 * 10**9
 SEC = 10**9
-CHAINS = ("fused", "gather")
+# (chains, regfile): regfile None = the host wrapper's own resolution
+FORMS = [pytest.param("fused", None, id="fused"),
+         pytest.param("gather", None, id="gather"),
+         pytest.param("fused", "select", id="fused-select"),
+         pytest.param("gather", "select", id="gather-select")]
+_FORM_VALUES = [p.values for p in FORMS]
+
+
+def decode_as(streams, max_points, chains, regfile=None, pad_words=0,
+              annotations_fallback=False):
+    """``decode_batch`` under one formulation: through the host wrapper
+    where ``regfile`` is None, else the jitted program called with the
+    ``regfile`` static (the wrapper resolves it by backend alone)."""
+    if regfile is None and not pad_words:
+        return decode_batch(streams, max_points=max_points,
+                            annotations_fallback=annotations_fallback,
+                            chains=chains)
+    words, nbits = mj.pack_streams(streams, pad_words=pad_words)
+    return mj._decoded_to_host(mj._decode_batch_device(
+        jnp.asarray(words), jnp.asarray(nbits), mj.value_ctrl_table(),
+        max_points=max_points, default_unit=int(Unit.SECOND),
+        chains=chains, scan_major=True,
+        extract=mj._resolved_extract(chains),
+        regfile=regfile or "gather"), annotations_fallback)
 
 
 def _digest(ts_list, bits_list):
@@ -55,11 +86,12 @@ def _scalar_ts_bits(stream):
             np.array([p.value for p in pts], np.float64).view(np.uint64))
 
 
-def _assert_batched_matches_scalar(streams, max_points, chains):
-    ts, vals, counts, fb = decode_batch(streams, max_points=max_points,
-                                        annotations_fallback=False,
-                                        chains=chains)
-    assert not fb.any(), f"unexpected fallback under chains={chains}"
+def _assert_batched_matches_scalar(streams, max_points, chains,
+                                   regfile=None, pad_words=0, pad_rows=0):
+    ts, vals, counts, fb = decode_as(streams + [b""] * pad_rows, max_points,
+                                     chains, regfile, pad_words)
+    assert not fb[:len(streams)].any(), (f"unexpected fallback under chains={chains} "
+                          f"regfile={regfile}")
     for i, s in enumerate(streams):
         want_ts, want_bits = _scalar_ts_bits(s)
         n = int(counts[i])
@@ -87,12 +119,11 @@ class TestPinnedCorpus:
         ts_list, bits_list = zip(*(_scalar_ts_bits(s) for s in streams))
         assert _digest(ts_list, bits_list) == doc["sha256"]
 
-    @pytest.mark.parametrize("chains", CHAINS)
-    def test_batched_decode_matches_pin(self, corpus, chains):
+    @pytest.mark.parametrize("chains,regfile", FORMS)
+    def test_batched_decode_matches_pin(self, corpus, chains, regfile):
         doc, streams = corpus
-        ts, vals, counts, fb = decode_batch(
-            streams, max_points=doc["max_points"],
-            annotations_fallback=False, chains=chains)
+        ts, vals, counts, fb = decode_as(streams, doc["max_points"], chains,
+                                         regfile)
         assert not fb.any()
         ts_list = [ts[i, :int(n)] for i, n in enumerate(counts)]
         bits_list = [vals[i, :int(n)].copy().view(np.uint64)
@@ -149,9 +180,9 @@ class TestFuzzRoundtrip:
         ts, vals, starts = _fuzz_batch(seed, S, T)
         streams, fb = encode_batch(ts, vals, starts, out_words=256)
         assert not fb.any()
-        for chains in CHAINS:
+        for chains, regfile in _FORM_VALUES:
             _assert_batched_matches_scalar(
-                [bytes(s) for s in streams], T + 1, chains)
+                [bytes(s) for s in streams], T + 1, chains, regfile)
 
     @pytest.mark.slow
     @pytest.mark.parametrize("seed", range(4, 16))
@@ -160,9 +191,9 @@ class TestFuzzRoundtrip:
         ts, vals, starts = _fuzz_batch(seed, S, T)
         streams, fb = encode_batch(ts, vals, starts, out_words=256)
         assert not fb.any()
-        for chains in CHAINS:
+        for chains, regfile in _FORM_VALUES:
             _assert_batched_matches_scalar(
-                [bytes(s) for s in streams], T + 1, chains)
+                [bytes(s) for s in streams], T + 1, chains, regfile)
 
 
 class TestDecodeProperties:
@@ -181,8 +212,9 @@ class TestDecodeProperties:
             t += d * SEC
             pts.append(Datapoint(t, float(i)))
         streams = [self._encode_scalar(pts)]
-        for chains in CHAINS:
-            _assert_batched_matches_scalar(streams, len(pts) + 1, chains)
+        for chains, regfile in _FORM_VALUES:
+            _assert_batched_matches_scalar(streams, len(pts) + 1, chains,
+                                           regfile)
 
     def test_xor_contained_uncontained_flips(self):
         """Value sequence engineered to flip between contained and
@@ -192,8 +224,9 @@ class TestDecodeProperties:
         pts = [Datapoint(START + (i + 1) * SEC, v)
                for i, v in enumerate(vs)]
         streams = [self._encode_scalar(pts)]
-        for chains in CHAINS:
-            _assert_batched_matches_scalar(streams, len(pts) + 1, chains)
+        for chains, regfile in _FORM_VALUES:
+            _assert_batched_matches_scalar(streams, len(pts) + 1, chains,
+                                           regfile)
 
     def test_int_float_mode_churn(self):
         """int -> float -> int transitions exercise the to-float /
@@ -202,8 +235,9 @@ class TestDecodeProperties:
         pts = [Datapoint(START + (i + 1) * SEC, v)
                for i, v in enumerate(vs)]
         streams = [self._encode_scalar(pts)]
-        for chains in CHAINS:
-            _assert_batched_matches_scalar(streams, len(pts) + 1, chains)
+        for chains, regfile in _FORM_VALUES:
+            _assert_batched_matches_scalar(streams, len(pts) + 1, chains,
+                                           regfile)
 
     def test_single_point_and_two_point_streams(self):
         streams = [
@@ -211,5 +245,92 @@ class TestDecodeProperties:
             self._encode_scalar([Datapoint(START + SEC, np.nan),
                                  Datapoint(START + 2 * SEC, np.nan)]),
         ]
-        for chains in CHAINS:
-            _assert_batched_matches_scalar(streams, 4, chains)
+        for chains, regfile in _FORM_VALUES:
+            _assert_batched_matches_scalar(streams, 4, chains, regfile)
+
+    @pytest.mark.parametrize("chains,regfile", FORMS)
+    def test_cursor_reads_last_word_and_pad(self, chains, regfile):
+        """Streams that end on their array's last real word, packed with
+        no slack word: the final steps' register file reaches past it
+        into the decoder's own zero padding."""
+        rng = np.random.default_rng(7)
+        streams = []
+        for n in (5, 17, 40, 63):
+            vals = rng.normal(0, 1, n)      # raw floats: long steps
+            pts = [Datapoint(START + (i + 1) * SEC, float(v))
+                   for i, v in enumerate(vals)]
+            streams.append(self._encode_scalar(pts))
+        width = max(-(-len(s) // 8) for s in streams)
+        _assert_batched_matches_scalar(streams, 64, chains, regfile,
+                                       pad_words=width)
+
+    @pytest.mark.parametrize("chains,regfile", FORMS)
+    def test_annotation_jump(self, chains, regfile):
+        """An annotation of 6,144 bits mid-stream: one step moves the
+        cursor across many words at once."""
+        enc = Encoder(START)
+        enc.encode(Datapoint(START + SEC, 1.5, Unit.SECOND, b"schema-v1"))
+        enc.encode(Datapoint(START + 2 * SEC, 2.5, Unit.SECOND))
+        enc.encode(Datapoint(START + 3 * SEC, 3.5, Unit.SECOND,
+                             bytes(range(256)) * 3))
+        for k in range(4, 30):
+            enc.encode(Datapoint(START + k * SEC, k * 0.25, Unit.SECOND))
+        _assert_batched_matches_scalar([enc.stream()], 40, chains, regfile)
+
+    @pytest.mark.parametrize("chains,regfile", FORMS)
+    def test_min_word_bucket_of_64(self, chains, regfile):
+        """The served read's smallest shape (storage/database.py
+        `_decode_streams`): 128 rows, 64 words, empty padding rows."""
+        rng = np.random.default_rng(11)
+        streams = []
+        for i in range(100):
+            n = int(rng.integers(1, 120))
+            vals = np.cumsum(rng.integers(0, 9, n)).astype(float)
+            pts = [Datapoint(START + (k + 1) * 15 * SEC, float(v))
+                   for k, v in enumerate(vals)]
+            streams.append(self._encode_scalar(pts))
+        assert max(len(s) for s in streams) // 8 + 2 <= 64
+        _assert_batched_matches_scalar(streams, 128, chains, regfile,
+                                       pad_words=64, pad_rows=28)
+
+
+class TestRegfileResolution:
+    """The host wrapper picks the register file by backend alone
+    (``select`` on a TPU, ``gather`` elsewhere), says what ran, and its
+    devguard fallback steps down to ``gather``."""
+
+    @pytest.fixture
+    def clean_guard(self):
+        from m3_tpu.x import devguard, fault
+        from m3_tpu.x.breaker import reset_registry
+
+        def reset():
+            fault.disarm()
+            devguard.reset_stages()
+            reset_registry()
+
+        reset()
+        yield
+        reset()
+
+    @pytest.mark.parametrize("backend,want", [("cpu", "gather"),
+                                              ("tpu", "select")])
+    def test_wrapper_resolves_by_backend(self, monkeypatch, clean_guard,
+                                         backend, want):
+        from m3_tpu.parallel import pallas_decode
+        from m3_tpu.x import devguard, fault
+
+        ts, vals, starts = _fuzz_batch(5, 6, 40)
+        streams, fb = encode_batch(ts, vals, starts, out_words=128)
+        assert not fb.any()
+        streams = [bytes(s) for s in streams]
+        monkeypatch.setattr(mj.jax, "default_backend", lambda: backend)
+        # the Pallas extraction a "tpu" resolves runs interpreted here
+        monkeypatch.setattr(pallas_decode, "auto_interpret", lambda: True)
+        assert mj.resolved_regfile() == want
+        _assert_batched_matches_scalar(streams, 41, "auto")
+        assert mj.last_regfile() == want
+        with fault.armed("device.dispatch", "error", n=1):
+            _assert_batched_matches_scalar(streams, 41, "auto")
+        assert devguard.counters()["device.decode.fallback_calls"] == 1
+        assert mj.last_regfile() == "gather"

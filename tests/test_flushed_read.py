@@ -164,6 +164,33 @@ def test_flagged_rows_take_the_scalar_iterator_and_are_counted(sealed):
     assert locked.end_ns <= span.start_ns
 
 
+@pytest.mark.parametrize("backend,regfile", [("cpu", "gather"),
+                                              ("tpu", "select")])
+def test_the_fileset_span_names_the_register_file_that_ran(
+        sealed, monkeypatch, backend, regfile):
+    """`db.read.fileset`'s tag `regfile` is the boundary scan's
+    register file as the decode ran it: `select` where the backend is a
+    TPU, `gather` elsewhere; the answer is the same by bits."""
+    from m3_tpu.encoding import m3tsz_jax as mj
+    from m3_tpu.parallel import pallas_decode
+
+    db, series, _, tracer = sealed
+    ids = [sid for sid, _ in series["counters"] + series["gauges"]]
+    want = [db.read("default", sid, T0, T0 + BLOCK) for sid in ids]
+    monkeypatch.setattr(mj.jax, "default_backend", lambda: backend)
+    # the Pallas extraction a "tpu" resolves runs interpreted here
+    monkeypatch.setattr(pallas_decode, "auto_interpret", lambda: True)
+    n_spans = len(tracer.finished(Tracepoint.DB_READ_FILESET))
+    cols = db.read_columns("default", ids, T0, T0 + BLOCK)
+    (span,) = tracer.finished(Tracepoint.DB_READ_FILESET)[n_spans:]
+    assert span.tags["regfile"] == regfile
+    assert span.tags["device"] == len(ids)
+    for i, pts in enumerate(want):
+        n = int(cols.counts[i])
+        assert cols.ts[i, :n].tolist() == [t for t, _ in pts]
+        assert _bits(cols.values[i, :n]) == _bits([v for _, v in pts])
+
+
 def test_a_warm_flush_stands_under_db_tick_as_encode_and_write(sealed):
     _, series, _, tracer = sealed
     (tick,) = tracer.finished(Tracepoint.DB_TICK)

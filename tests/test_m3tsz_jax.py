@@ -10,6 +10,7 @@ import pytest
 from tests.conftest import DATA_DIR
 from m3_tpu.encoding.m3tsz import decode_series, encode_series
 from m3_tpu.encoding.m3tsz_jax import decode_batch, encode_batch
+from tests.test_decode_fuzz import FORMS, decode_as
 
 START = 1_600_000_000 * 10**9
 
@@ -144,6 +145,29 @@ def test_roundtrip_batched():
     assert (counts == 150).all()
     assert (ts2[:, :150] == ts).all()
     assert np.allclose(vals2[:, :150], vals, rtol=0, atol=0, equal_nan=True)
+
+
+@pytest.mark.parametrize("chains,regfile", FORMS)
+def test_decode_formulations_golden_and_roundtrip(chains, regfile):
+    """The reference encoder's golden streams and a batch of the
+    batched encoder's, under each chains tail x register-file
+    formulation: bit-identical to the scalar decoder."""
+    with open(DATA_DIR / "m3tsz_sample_series.json") as f:
+        golden = [base64.b64decode(s) for s in json.load(f)]
+    ts, vals, starts = _mk_batch(T=150, seed=5)
+    batch, fb = encode_batch(ts, vals, starts, out_words=400)
+    assert not fb.any()
+    streams = golden + [bytes(s) for s in batch]
+    ts2, vals2, counts, fb2 = decode_as(streams, 1500, chains, regfile)
+    assert not fb2.any()
+    for i, s in enumerate(streams):
+        want = decode_series(s)
+        n = int(counts[i])
+        assert n == len(want)
+        assert ts2[i][:n].tolist() == [d.timestamp for d in want]
+        np.testing.assert_array_equal(
+            vals2[i][:n].view(np.uint64),
+            np.array([d.value for d in want], np.float64).view(np.uint64))
 
 
 def test_decode_annotation_stream_default_flags_fallback():
